@@ -33,13 +33,8 @@ def build_all(cfg: Config, split: str = "train", devices=None,
     passes ABSTRACT topology devices to AOT-compile the exact train step a
     real run of this config would execute. ``fault_nan_step`` compiles the
     ``nan:K`` gradient-poison fault into the train step (train.py)."""
-    from .utils.compat import enable_compile_cache
-
-    # Before any compile this config triggers: every subcommand funnels
-    # through build_all, so train/eval/benchmark/generate all warm-start.
     from .precision import check_precision_composition
 
-    enable_compile_cache(cfg.train.compile_cache_dir)
     # Resolve + fence the mixed-precision policy BEFORE the model build so
     # an illegal policy/optimizer pair fails by name in milliseconds.
     policy = check_precision_composition(
@@ -313,6 +308,24 @@ def cmd_generate(cfg: Config, prompts: list[str], max_new_tokens: int,
     return 0
 
 
+def serving_model_and_state(cfg: Config, model, trainer, dataset):
+    """What ``cmd_serve`` hands the engine, from ``build_all``'s outputs: the
+    restored (or freshly initialized) state, and the model cloned onto the
+    xla attention core on one program (the engine re-fences this; mirrors
+    cmd_generate). Shared with ``chip_smoke.py``, which serves token ids at
+    the published vocabulary that ``cmd_serve``'s byte-tokenizer fence
+    refuses."""
+    state = _restore_or_init(cfg, trainer, dataset.batch(0), "serving from")
+    updates = {}
+    if hasattr(model, "attn_impl"):
+        updates["attn_impl"] = "xla"
+    if hasattr(model, "mesh") and model.mesh is not None:
+        updates["mesh"] = None
+    if updates:
+        model = model.clone(**updates)
+    return model, state
+
+
 def cmd_serve(cfg: Config, prompts: list[str], max_new_tokens: int,
               temperature: float, seed: int, *, top_k: int = 0,
               top_p: float = 0.0) -> int:
@@ -354,16 +367,7 @@ def cmd_serve(cfg: Config, prompts: list[str], max_new_tokens: int,
             "completions decoded back (prepare_data --tokenizer byte). "
             "Use serving.ServingEngine directly for other tokenizers."
         )
-    state = _restore_or_init(cfg, trainer, dataset.batch(0), "serving from")
-    # Serving decodes through the xla core on one program (the engine
-    # re-fences this; clone here mirrors cmd_generate).
-    updates = {}
-    if hasattr(model, "attn_impl"):
-        updates["attn_impl"] = "xla"
-    if hasattr(model, "mesh") and model.mesh is not None:
-        updates["mesh"] = None
-    if updates:
-        model = model.clone(**updates)
+    model, state = serving_model_and_state(cfg, model, trainer, dataset)
     from .telemetry import Telemetry, resolve_dir
 
     requests = [
@@ -617,8 +621,10 @@ def cmd_supervise(args) -> int:
     if args.xla_perf_flags:
         cmd.append("--xla-perf-flags")
     clear = ()
-    if cfg.supervisor.clear_cache_on_crash and cfg.train.compile_cache_dir:
-        clear = (cfg.train.compile_cache_dir,)
+    if cfg.supervisor.clear_cache_on_crash:
+        from .utils.compat import compile_cache_dir
+
+        clear = (compile_cache_dir(),)
     # Telemetry seam: children write their attempt ledgers/flight records
     # into the SAME dir (the overrides above carry telemetry.* through);
     # the supervisor adds backoff records, hang/crash flight dumps, and
@@ -1130,6 +1136,11 @@ def main(argv=None) -> int:
     if args.cmd == "report":
         # Pure artifact reader — no backend, no config, no rendezvous.
         return cmd_report(args.dir)
+    # Before anything compiles; babysitter parents (supervise/launch/fleet)
+    # compile nothing, their children come through here themselves.
+    from .utils.compat import setup_compile_cache
+
+    setup_compile_cache()
     if getattr(args, "telemetry", None) is not None:
         # Desugar BEFORE the supervise/launch dispatch: both build their
         # child command line from args.override, so children inherit the

@@ -24,7 +24,6 @@ import functools
 import jax
 from jax import lax
 
-from .utils import compat
 
 AxisName = str | tuple[str, ...]
 
@@ -71,7 +70,7 @@ def axis_index(axis: str):
 
 def axis_size(axis: str) -> int:
     """Static size of a mesh axis, usable inside shard_map-traced code."""
-    return compat.axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def ring_shift(x, axis: str, *, shift: int = 1):
@@ -79,7 +78,7 @@ def ring_shift(x, axis: str, *, shift: int = 1):
     by member ``i - shift`` (mod N). The building block of ring attention and
     pipeline communication; on TPU each hop is one ICI-neighbor ``ppermute``.
     """
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm=perm)
 
@@ -125,7 +124,7 @@ def _psum_identity_bwd(axis: str, _, g):
     # re-vary the cotangent to type-match the input (a no-op on values;
     # also a no-op under check_vma=False bodies like the interleaved
     # engine, where pcast is accepted and vma isn't tracked).
-    return (compat.pcast_varying(g, axis),)
+    return (lax.pcast(g, (axis,), to="varying"),)
 
 
 psum_identity_bwd.defvjp(_psum_identity_fwd, _psum_identity_bwd)

@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.flatten_util import ravel_pytree
 
-from .utils import compat
 
 GRAD_COMM_MODES: tuple[str, ...] = ("fp32", "bf16", "int8")
 
@@ -111,7 +110,7 @@ def _pad_to(flat, multiple: int):
 
 def _ring_hop(payload, axis: str):
     """One neighbor hop: member i receives member i-1's payload tuple."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     return tuple(lax.ppermute(p, axis, perm=perm) for p in payload)
 
@@ -126,7 +125,7 @@ def _ring_reduce_phase(flat, axis: str, mode: str, block_size: int):
     ``(i - 1 - s) % n``, decompresses, and adds its own slice of that chunk
     in f32.
     """
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     chunks = flat.reshape(n, -1)
     partial = lax.dynamic_slice_in_dim(chunks, i, 1, axis=0)[0]
@@ -152,7 +151,7 @@ def quantized_all_reduce_flat(
     distributes each reduced chunk in compressed form and every member —
     including the chunk's own reducer — uses the decompressed value.
     """
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1 or mode == "fp32":
         return lax.psum(flat, axis)
     partial, _, n, i = _ring_reduce_phase(flat, axis, mode, block_size)
@@ -183,7 +182,7 @@ def quantized_reduce_scatter_flat(
     """``lax.psum_scatter`` semantics (member ``i`` gets chunk ``i`` of the
     sum, tiled) on compressed payloads. One extra compressed hop moves the
     ring-final chunk ``(i+1) % n`` from its reducer to its owner."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1 or mode == "fp32":
         return lax.psum_scatter(flat, axis, scatter_dimension=0, tiled=True)
     partial, _, n, _ = _ring_reduce_phase(flat, axis, mode, block_size)
@@ -245,7 +244,7 @@ def quantized_tree_all_reduce(
     flat, unravel = ravel_pytree(grads)
     flat = flat.astype(jnp.float32)
     m = flat.shape[0]
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     padded = _pad_to(flat, n * block_size)
     summed = quantized_all_reduce_flat(
         padded, axis, mode=mode, block_size=block_size

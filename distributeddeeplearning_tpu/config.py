@@ -206,11 +206,6 @@ class TrainConfig:
     precision: PrecisionConfig = dataclasses.field(
         default_factory=PrecisionConfig
     )
-    # Persistent XLA compilation cache (jax_compilation_cache_dir): real
-    # runs warm-start their compiles across restarts/resumes — previously
-    # only the test harness set this (tests/conftest.py). Applied by
-    # cli.build_all via compat.enable_compile_cache; empty = off.
-    compile_cache_dir: str = ""
     log_dir: str = ""  # TensorBoard scalars + profiler traces
     profile_steps: str = ""  # "a:b" -> jax.profiler trace window
     # Debug/fault tooling (SURVEY §5): the XLA-world equivalents of the
@@ -281,12 +276,15 @@ class SupervisorConfig:
     preempt_grace_s: float = 60.0
     heartbeat_file: str = ""  # "" -> auto (a temp path per supervisor run)
     # After a CRASH/HANG exit (not clean/preempted/injected-fault), clear
-    # the child's persistent XLA compile cache before restarting: a child
-    # that died abnormally may have truncated a cache entry mid-write, and
-    # a cached executable can itself be what the child keeps dying on —
-    # recompiling cold is the only restart that makes progress then. Costs
-    # one compile per abnormal restart; disable to keep the cache warm.
-    clear_cache_on_crash: bool = True
+    # the persistent XLA compile cache before restarting: a child that died
+    # abnormally may have truncated a cache entry mid-write, and a cached
+    # executable can itself be what the child keeps dying on — recompiling
+    # cold is the only restart that makes progress then. Off by default:
+    # the cache is ONE directory shared by every config, tool, test and
+    # serving engine of the checkout (or the machine, where
+    # JAX_COMPILATION_CACHE_DIR is set), and the clear wipes all of it.
+    # Turn it on together with a cache directory of the run's own.
+    clear_cache_on_crash: bool = False
 
 
 @dataclasses.dataclass(frozen=True)

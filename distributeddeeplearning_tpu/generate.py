@@ -286,6 +286,59 @@ def _decode_jit(model, params, buf, cache, rng, temperature, top_k, top_p, *,
     )
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+def full_forward_logits(model, params, tokens):
+    """The no-cache reference: ONE full forward over ``tokens`` [B, L] ->
+    f32 logits [B, L, V]. Attention is causal, so right-padding never
+    reaches an earlier position and one buffer length serves every row."""
+    return _logits_of(model.apply({"params": params}, tokens)).astype(
+        jnp.float32
+    )
+
+
+def greedy_gaps(logits, prompt_len: int, generated):
+    """How far each greedily ``generated`` token is from the reference's
+    argmax: ``logits`` [L, V] are :func:`full_forward_logits` of the row
+    ``prompt + generated`` (teacher-forced on the tokens under test, so the
+    comparison stays valid past a flipped token), and entry ``i`` is
+    ``max(logits[pos_i]) - logits[pos_i, generated[i]]`` at the position
+    that predicts token ``i``. 0 everywhere means token-for-token equality
+    with a full-forward greedy decode; a positive entry is a token the
+    decode path ranked within that margin of the reference's choice."""
+    import numpy as np
+
+    generated = np.asarray(generated)
+    rows = np.asarray(logits)[
+        prompt_len - 1: prompt_len - 1 + len(generated)
+    ]
+    return rows.max(axis=-1) - rows[np.arange(len(generated)), generated]
+
+
+def greedy_agreement(model, params, prompts, generated) -> dict:
+    """Hold greedy decodes to the full-forward reference on the same params
+    and device: one :func:`full_forward_logits` pass per request (every row
+    right-padded to one buffer length, hence one compile), reduced by
+    :func:`greedy_gaps`. Returns the token count, how many are exactly the
+    reference's argmax, and the worst logit gap — the caller states the
+    tolerance (0 in exact arithmetic; a few bf16 ulps of the logit scale on
+    the chip, where near-ties flip)."""
+    import numpy as np
+
+    longest = max(len(p) + len(g) for p, g in zip(prompts, generated))
+    buf_len = min(int(model.max_len), -(-longest // 128) * 128)
+    exact = total = 0
+    worst = 0.0
+    for p, g in zip(prompts, generated):
+        row = np.zeros((1, buf_len), np.int32)
+        row[0, : len(p) + len(g)] = list(p) + list(g)
+        logits = np.asarray(full_forward_logits(model, params, row))[0]
+        gaps = greedy_gaps(logits, len(p), g)
+        exact += int((gaps == 0).sum())
+        total += len(g)
+        worst = max(worst, float(gaps.max()))
+    return {"tokens": total, "exact": exact, "worst_logit_gap": worst}
+
+
 def uses_bulk_prefill(model) -> bool:
     """THE gate deciding bulk vs one-token prefill (shared with callers
     that report per-step stats, e.g. ``cli generate --bench``): capacity-

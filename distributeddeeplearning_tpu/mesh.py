@@ -190,8 +190,8 @@ def init_distributed(
         # otherwise a pod launch would silently train as N independent
         # single-process jobs. Plain single-host runs skip rendezvous.
         multi_host = (
-            # >1 worker in the TPU pod metadata (a single name — as the
-            # local PJRT plugin sets — is not a cluster).
+            # >1 worker in the TPU pod metadata (a single name is not a
+            # cluster).
             len(os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")) > 1
             or "MEGASCALE_COORDINATOR_ADDRESS" in os.environ
             or "SLURM_JOB_ID" in os.environ

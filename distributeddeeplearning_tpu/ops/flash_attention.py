@@ -36,7 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ..mesh import BATCH_AXES
-from ..utils import compat
 
 _NEG_INF = -1e30  # finite: exp(_NEG_INF - m) == 0 exactly, no inf-inf NaNs
 _LANES = 128
@@ -480,7 +479,7 @@ def flash_attention(
             # check_vma=False: same jax-0.9.0 pallas-in-shard_map typing
             # limitation as ring_attention_pallas.py — no collectives exist
             # in the body, each shard is independent.
-            return compat.shard_map(
+            return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(spec, spec, spec, vl_spec), out_specs=spec,
                 check_vma=False,

@@ -11,25 +11,33 @@ scalar-prefetch operand, so each grid step's BlockSpec index_map resolves
 block — no gathered copy exists at any point.
 
 Layout (see pallas_guide.md and ops/flash_attention.py, the idiom seed):
-- grid is ``(batch, kv_heads, pages_per_seq)`` — pages innermost, which
-  is sequential on TPU, so the online-softmax carries (m, l, acc) live in
+- grid is ``(batch, pages_per_seq)`` — pages innermost, which is
+  sequential on TPU, so the online-softmax carries (m, l, acc) live in
   VMEM scratch across a row's pages;
 - ``pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2)``: the page
   table and the per-row cursors are scalar operands available to BOTH the
   index_maps (physical block selection) and the kernel body (causal
   masking at the row's cursor);
+- one grid step takes a WHOLE page, all kv heads: the block
+  ``(1, block_size, kv_heads, D)`` over the ``[NB, BS, G, D]`` pool has
+  its last two dims equal to the array's, which is what the TPU lowering
+  requires of a block narrower than the (8, 128) tile (a block of 1 on
+  the kv-head dim is refused by Mosaic at every width this repo serves);
+- the page stays in its ``(slot, head, D)`` VMEM layout and the math is
+  VPU broadcast-multiply + reduce, no per-head slicing and no MXU: a
+  one-token decode is an M=1 matmul per head, DMA-bound either way;
 - GQA: q arrives group-major (query head ``g*num_rep + r`` reads kv
-  group ``g``, matching ``transformer._cache_attend``) and is reshaped to
-  ``[B, kv_heads, num_rep, D]`` — each grid step attends its group's
-  ``num_rep`` query heads against ONE un-repeated kv block, so the pool
-  is never repeated to the query head count;
+  group ``g``, matching ``transformer._cache_attend``) and is handed to
+  the kernel as ``[B, num_rep, kv_heads, D]`` — rep ``r``'s ``(G, D)``
+  slab lines up with the page's head dim, so the pool is never repeated
+  to the query head count;
 - pages entirely beyond a row's cursor are skipped with ``pl.when`` (no
-  MXU work, no DMA wait on the accumulate path); the cursor page is
-  masked per-column with ``broadcasted_iota``;
-- all accumulation is fp32 (``preferred_element_type``) regardless of
-  pool dtype; on CPU backends the kernel runs in interpret mode, which is
-  how the parity tests exercise it without a TPU (native compilation is
-  covered under the ``tpu_only`` gate).
+  VPU work on the accumulate path); the cursor page is masked per slot
+  with ``broadcasted_iota``;
+- all accumulation is fp32 regardless of pool dtype; on CPU backends the
+  kernel runs in interpret mode, which is how the parity tests exercise
+  it without a TPU (the TPU lowering is compiled devicelessly in
+  ``tests/test_tpu_compile.py`` and run by ``chip_smoke.py``).
 
 Semantics match the reference gather exactly: the caller has already
 scattered this step's k/v into the pool at position ``seq_lens[b]``, and
@@ -41,9 +49,9 @@ Quantized pools (``serving.kv_quant='int8'``): the pool arrives as int8
 with one f32 scale per (page slot, kv head) D-vector in parallel scale
 pools ``[num_blocks, block_size, kv_heads]`` (written at scatter time by
 ``transformer.paged_decode_attention``). The quantized kernel variant
-adds two BlockSpec operands whose index_maps follow the SAME
-``page_table[b, j]`` indirection — the per-page DMA pulls the int8 page
-AND its scale rows into VMEM together, and the dequant
+adds two ``(1, block_size, kv_heads)`` BlockSpec operands whose index_maps
+follow the SAME ``page_table[b, j]`` indirection — the per-page DMA pulls
+the int8 page AND its scale rows into VMEM together, and the dequant
 (``values.astype(f32) * scale``, the ``comms_quant`` codec inverse) is
 fused inline before the online-softmax dot. The fp32 carries (m, l, acc)
 are unchanged, so the only numerics delta vs the fp kernel is the
@@ -69,105 +77,62 @@ def _default_interpret() -> bool:
 
 
 def _decode_kernel(
-    table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, block_size, num_pages,
+    table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
+    sm_scale, block_size, num_pages, quantized,
 ):
+    """One (row, page) grid step. ``q_ref`` (1, R, G, D); ``k_ref`` /
+    ``v_ref`` (1, BS, G, D); quantized pools add ``sk_ref`` / ``sv_ref``
+    (1, BS, G) — the page's scale rows, fetched by the same ``tbl[b, j]``
+    index_map as the page and applied in VMEM (``q.astype(f32) * scale``)
+    right after the DMA. Carries per rep: m, l (G, LANES), acc (G, D)."""
+    if quantized:
+        sk_ref, sv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    num_rep, kv_heads = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     pos = lens_ref[b]  # this row's query position (cursor, pre-advance)
 
     # Pages strictly beyond the cursor hold no visible columns — skip.
     @pl.when(j * block_size <= pos)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (num_rep, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (block_size, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (num_rep, block_size)
+        k = k_ref[0].astype(jnp.float32)  # (BS, G, D)
+        v = v_ref[0].astype(jnp.float32)
+        if quantized:
+            k = k * sk_ref[0][:, :, None]
+            v = v * sv_ref[0][:, :, None]
         col = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
+            jnp.int32, (block_size, kv_heads, 1), 0
         )
-        s = jnp.where(col <= pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]  # (num_rep, 1)
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p, v_ref[0, :, 0].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        for r in range(num_rep):
+            q = q_ref[0, r].astype(jnp.float32) * sm_scale  # (G, D)
+            # keepdims: scores stay in the page's (slot, head, lane)
+            # layout, so nothing is relaid out between the two reduces.
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (BS, G, 1)
+            s = jnp.where(col <= pos, s, _NEG_INF)
+            m_prev = m_scr[r][:, :1]  # (G, 1)
+            l_prev = l_scr[r][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=0)
+            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
+            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(j == num_pages - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-
-
-def _decode_kernel_q8(
-    table_ref, lens_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref,
-    m_scr, l_scr, acc_scr, *, sm_scale, block_size, num_pages,
-):
-    """Quantized-pool variant of ``_decode_kernel``: identical online-
-    softmax carry, but the page's int8 k/v are dequantized in VMEM
-    (``q.astype(f32) * scale``) right after the DMA, before the dots.
-    ``sk_ref``/``sv_ref`` are the page's scale rows, one f32 per
-    (slot, group) D-vector, fetched by the same ``tbl[b, j]`` index_map
-    as the page itself."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = lens_ref[b]
-
-    @pl.when(j * block_size <= pos)
-    def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (num_rep, D)
-        # Inline dequant: block shapes are (1, block_size, 1, D) for the
-        # int8 page and (1, block_size, 1) for its scale row; sk_ref[0]
-        # is already 2D (block_size, 1) and broadcasts over D.
-        k = k_ref[0, :, 0].astype(jnp.float32) * sk_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (num_rep, block_size)
-        col = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(col <= pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32) * sv_ref[0]
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == num_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        for r in range(num_rep):
+            l = jnp.maximum(l_scr[r][:, :1], 1e-30)
+            o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
 
 
 def _check_scales(pool_k, scale_k, scale_v):
@@ -252,56 +217,52 @@ def paged_attention(
         interpret = _default_interpret()
     quantized = _check_scales(pool_k, scale_k, scale_v)
 
-    # Group-major head fold: head g*num_rep+r -> (group g, rep r).
-    q4 = q.reshape(B, kv_heads, num_rep, D)
+    # Group-major head fold: head g*num_rep+r -> (group g, rep r), then
+    # rep-major so each rep's (G, D) slab matches the page's head dim.
+    q4 = q.reshape(B, kv_heads, num_rep, D).transpose(0, 2, 1, 3)
     kernel = functools.partial(
-        _decode_kernel_q8 if quantized else _decode_kernel,
-        sm_scale=sm_scale, block_size=block_size, num_pages=num_pages,
+        _decode_kernel, sm_scale=sm_scale, block_size=block_size,
+        num_pages=num_pages, quantized=quantized,
+    )
+    q_spec = pl.BlockSpec(
+        (1, num_rep, kv_heads, D), lambda b, j, tbl, lens: (b, 0, 0, 0)
     )
     # The paged reads: physical block (and, quantized, its scale rows)
     # straight off the scalar-prefetched table.
     page_spec = pl.BlockSpec(
-        (1, block_size, 1, D),
-        lambda b, g, j, tbl, lens: (tbl[b, j], 0, g, 0),
+        (1, block_size, kv_heads, D),
+        lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0),
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, num_rep, D), lambda b, g, j, tbl, lens: (b, g, 0, 0)
-        ),
-        page_spec,
-        page_spec,
-    ]
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [q4, pool_k, pool_v]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, block_size, 1),
-            lambda b, g, j, tbl, lens: (tbl[b, j], 0, g),
+            (1, block_size, kv_heads),
+            lambda b, j, tbl, lens: (tbl[b, j], 0, 0),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [scale_k, scale_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, kv_heads, num_pages),
+        grid=(B, num_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, num_rep, D), lambda b, g, j, tbl, lens: (b, g, 0, 0)
-        ),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((num_rep, _LANES), jnp.float32),
-            pltpu.VMEM((num_rep, _LANES), jnp.float32),
-            pltpu.VMEM((num_rep, D), jnp.float32),
+            pltpu.VMEM((num_rep, kv_heads, _LANES), jnp.float32),
+            pltpu.VMEM((num_rep, kv_heads, _LANES), jnp.float32),
+            pltpu.VMEM((num_rep, kv_heads, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kv_heads, num_rep, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, num_rep, kv_heads, D), q.dtype),
         interpret=interpret,
     )(
         jnp.asarray(page_table, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
         *operands,
     )
-    return out.reshape(B, H, D)
+    return out.transpose(0, 2, 1, 3).reshape(B, H, D)
 
 
 def paged_attention_reference(q, pool_k, pool_v, page_table, seq_lens, *,

@@ -31,7 +31,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from ..mesh import BATCH_AXES
-from ..utils import compat
 
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool):
@@ -40,7 +39,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool):
     q, k, v: [batch, seq_local, heads, head_dim] — this device's blocks.
     Returns [batch, seq_local, heads, head_dim].
     """
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     scale = 1.0 / np.sqrt(d)
@@ -122,7 +121,7 @@ def ring_attention(
             f"ring: heads={q.shape[2]} not divisible by tp={mesh.shape['tp']}"
         )
     spec = P(BATCH_AXES, axis_name, "tp", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: _ring_attention_local(
             q, k, v, axis_name=axis_name, causal=causal
         ),

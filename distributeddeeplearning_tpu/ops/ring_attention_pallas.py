@@ -46,7 +46,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from ..mesh import BATCH_AXES
-from ..utils import compat
 from .flash_attention import _blk, _default_interpret
 
 _NEG_INF = -1e30
@@ -149,7 +148,7 @@ def _ring_local_pallas_fwd_impl(
 ):
     """Per-device forward (inside shard_map): scan ring steps, each step one
     fused kernel launch + one KV rotation."""
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     scale = 1.0 / np.sqrt(d)
@@ -349,7 +348,7 @@ def _ring_bwd_step(
 def _ring_local_pallas_bwd_impl(
     q, k, v, out, lse, g, *, axis_name, causal, block_q, block_k, interpret
 ):
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, lq, h, d = q.shape
     scale = 1.0 / np.sqrt(d)
@@ -470,7 +469,7 @@ def ring_attention_pallas(
     # correctness is unaffected — the ring's ppermutes are explicit — and
     # parity vs the shard_map oracle is asserted in
     # tests/test_context_parallel.py.
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: _ring_local_pallas(
             q, k, v, axis_name, causal, block_q, block_k, interpret
         ),

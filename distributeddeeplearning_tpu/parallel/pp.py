@@ -56,7 +56,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from ..mesh import BATCH_AXES
-from ..utils import compat
 
 
 def check_pipeline_shapes(
@@ -103,7 +102,7 @@ def _gpipe_local(
     (key-padding mask) handed to ``stage_fn(params, x, extra_mb)``.
     Returns the last stage's outputs for every microbatch, [local_batch, ...].
     """
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = num_microbatches
     params = jax.tree.map(lambda p: jnp.squeeze(p, 0), params)
@@ -116,8 +115,8 @@ def _gpipe_local(
     # rotating buffer + one output accumulator suffice. x is replicated over
     # pp but the loop makes them stage-varying — pcast the initial carries so
     # the scan carry type is stable.
-    buf0 = compat.pcast_varying(jnp.zeros_like(mb[0]), axis_name)
-    out0 = compat.pcast_varying(jnp.zeros_like(mb), axis_name)
+    buf0 = jax.lax.pcast(jnp.zeros_like(mb[0]), (axis_name,), to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(mb), (axis_name,), to="varying")
     # Stage s -> s+1 handoff; stage 0 receives nothing (gets zeros, unused).
     perm = [(i, i + 1) for i in range(S - 1)]
 
@@ -161,7 +160,7 @@ def _batch_sharded_call(local, mesh, param_specs, x_spec, stacked_params,
     it and the mask-less and masked arities go through the SAME call —
     review r5: the previous per-arity shard_map arms (four near-identical
     blocks across gpipe/one_f_one_b) could drift apart silently."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -177,7 +176,7 @@ def _pp_local_fwd(
 ):
     """GPipe forward tick loop that ALSO stashes each stage's per-microbatch
     input (the 1F1B backward residuals). Returns (outputs, stash)."""
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = num_microbatches
     params = jax.tree.map(lambda p: jnp.squeeze(p, 0), params)
@@ -186,9 +185,9 @@ def _pp_local_fwd(
         lambda t: _microbatch(t, M), extra
     )
 
-    buf0 = compat.pcast_varying(jnp.zeros_like(mb[0]), axis_name)
-    out0 = compat.pcast_varying(jnp.zeros_like(mb), axis_name)
-    stash0 = compat.pcast_varying(jnp.zeros_like(mb), axis_name)
+    buf0 = jax.lax.pcast(jnp.zeros_like(mb[0]), (axis_name,), to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(mb), (axis_name,), to="varying")
+    stash0 = jax.lax.pcast(jnp.zeros_like(mb), (axis_name,), to="varying")
     perm = [(i, i + 1) for i in range(S - 1)]
 
     def tick(carry, t):
@@ -227,7 +226,7 @@ def _pp_local_bwd(
     forward from the stashed input and handing the input-cotangent one hop
     backwards (``s+1 -> s``). Param grads accumulate locally per stage.
     Returns (dparams [1, ...] leaves, dx)."""
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = num_microbatches
     params_sq = jax.tree.map(lambda p: jnp.squeeze(p, 0), params)
@@ -582,7 +581,7 @@ def interleaved_1f1b(
     # be comms.psum_identity_bwd — under check_vma=False a RAW lax.psum's
     # transpose is psum, which double-counts every cotangent crossing it
     # (the identity transpose is the correct one for row-parallel outputs).
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, shared_specs, batch_specs),

@@ -1300,22 +1300,7 @@ class ServingEngine:
         self.num_compiles += 1
         t0 = time.perf_counter()
         jitted = jax.jit(fn, donate_argnums=donate_argnums)
-        if donate_argnums:
-            # Donated builds bypass the persistent compilation cache: an
-            # executable with input->output aliasing that round-trips
-            # through cache serialization can come back with broken alias
-            # bookkeeping on this jax version — a cache-HIT donated
-            # prefill returned stale input bytes (the injected seq_lens)
-            # as its sampled token. The engine compiles each program once
-            # per process anyway, so the cache bought nothing here.
-            prev = jax.config.jax_enable_compilation_cache
-            jax.config.update("jax_enable_compilation_cache", False)
-            try:
-                exe = jitted.lower(*args).compile()
-            finally:
-                jax.config.update("jax_enable_compilation_cache", prev)
-        else:
-            exe = jitted.lower(*args).compile()
+        exe = jitted.lower(*args).compile()
         if name is not None:
             # Device registry: compile wall time + memory_analysis(); a
             # second record under one name shows up as recompiles > 0 —

@@ -736,6 +736,9 @@ def main(argv=None) -> int:
                    "engine directly and print the token map (the fleet "
                    "bench's parity reference)")
     args = p.parse_args(argv)
+    from ..utils.compat import setup_compile_cache
+
+    setup_compile_cache()
 
     if bool(args.config) == bool(args.spec_json):
         p.error("exactly one of --config / --spec-json is required")

@@ -237,13 +237,19 @@ def validate_tree_shardings(abs_tree, mesh: Mesh, rules=DEFAULT_LOGICAL_RULES):
 
 def constrain(x, *logical_axes, rules=None):
     """Constrain an activation's sharding by logical axis names (no-op outside
-    any mesh context). Used inside model code between blocks.
+    any mesh context and inside ``shard_map``). Used inside model code
+    between blocks.
 
     Rules resolution: an ambient ``nn.logical_axis_rules(...)`` context (the
     Trainer installs its own rules around every model call) takes precedence;
     otherwise ``DEFAULT_LOGICAL_RULES``. This is what lets a rules preset like
     Megatron sequence parallelism reach activation constraints, not only
     parameter shardings."""
+    # Inside a shard_map body the mesh axes are Manual: the body already
+    # holds its per-device shard, and with_sharding_constraint refuses a
+    # spec that names a Manual axis.
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return x
     if rules is None:
         rules = nn.get_logical_axis_rules() or DEFAULT_LOGICAL_RULES
     mesh = _MESH_CTX.get()

@@ -635,7 +635,7 @@ def summarize_goodput(path: str) -> dict | None:
 def memory_analysis_dict(compiled) -> dict | None:
     """``compiled.memory_analysis()`` as plain ints, or None where the
     backend doesn't report (guarded: HBM telemetry must never be what
-    crashes a run — same discipline as ``benchmark.device_memory_stats``).
+    crashes a run).
     The CPU sim DOES report argument/output/temp bytes (generated-code
     bytes are legitimately 0 there)."""
     try:

@@ -44,7 +44,6 @@ from .sharding import (
     logical_to_mesh_sharding,
     validate_tree_shardings,
 )
-from .utils import compat
 from .utils.rng import fold_in_step
 
 
@@ -860,6 +859,24 @@ class Trainer:
             self.state_shardings,
         )
 
+    def lower_train_step(self, example_batch):
+        """``train_step`` lowered on abstract operands shaped and sharded
+        like ``example_batch`` — nothing is materialized (``setup`` is
+        eval_shape-only), so it also lowers for described, unattached
+        devices. ``.compile()`` it for the HLO text or the memory analysis
+        of the program a run of this trainer executes."""
+        self.setup(example_batch)
+        bsh = batch_sharding(self.mesh)
+        abs_batch = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                jnp.shape(x), jnp.result_type(x), sharding=bsh
+            ),
+            dict(example_batch),
+        )
+        return self.train_step.lower(
+            self.abstract_state_with_shardings(), abs_batch
+        )
+
     # -- steps --------------------------------------------------------------
 
     def _loss_and_updates(self, params, model_state, batch, rng, train: bool):
@@ -873,7 +890,14 @@ class Trainer:
             list(model_state.keys()) + ["losses", "metrics"] if train else []
         )
         inputs = self.task.input_fn(batch)
-        with nn.logical_axis_rules(self.rules):
+        # Inside a shard_map body (the manual data-parallel steps) every mesh
+        # axis is Manual and the rules have nothing to map onto. flax takes
+        # jax's Manual ambient mesh for a global mesh and, with rules set,
+        # constrains each boxed init it shape-checks — which raises. No rules
+        # make that a no-op (as models/pipeline.py does for its stages);
+        # ``sharding.constrain`` checks for Manual axes itself.
+        manual = bool(jax.sharding.get_abstract_mesh().manual_axes)
+        with nn.logical_axis_rules(() if manual else self.rules):
             if mutable:
                 out, updates = self.model.apply(
                     variables, *inputs, train=train, mutable=mutable,
@@ -946,7 +970,7 @@ class Trainer:
         # check_vma=False: pallas_call inside shard_map (jax 0.9.0 vma-typing
         # limitation, same as the ring/flash kernels); the body has no
         # collectives — every shard's update is independent.
-        return compat.shard_map(
+        return jax.shard_map(
             self.tx.update,
             mesh=self.mesh,
             in_specs=(mu_specs, state_specs, mu_specs),
@@ -1113,7 +1137,7 @@ class Trainer:
             new_residual = jax.tree.map(lambda r: r[None], new_residual)
             return grads, metrics, updates, new_residual
 
-        sync = compat.shard_map(
+        sync = jax.shard_map(
             sync_body,
             mesh=self.mesh,
             in_specs=(param_specs, mstate_specs, P(BATCH_AXES), P(), P("dp")),
@@ -1283,7 +1307,7 @@ class Trainer:
                 new_res = tuple(r[None] for r in new_res) if lossy else ()
                 return grads, metrics, updates, new_res
 
-            sync = compat.shard_map(
+            sync = jax.shard_map(
                 sync_body,
                 mesh=self.mesh,
                 in_specs=(
@@ -1366,7 +1390,7 @@ class Trainer:
             new_res = tuple(r[None] for r in new_res) if lossy else ()
             return new_params, metrics, updates, new_res, new_opt
 
-        sync = compat.shard_map(
+        sync = jax.shard_map(
             sync_body,
             mesh=self.mesh,
             in_specs=(

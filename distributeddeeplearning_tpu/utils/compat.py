@@ -1,118 +1,43 @@
-"""Version-compat shims for the jax APIs this codebase uses.
-
-The framework targets current jax (``jax.shard_map``, ``lax.axis_size``,
-``lax.pcast``, vma typing), but deployment containers pin older releases —
-this one ships jax 0.4.37, where ``shard_map`` still lives in
-``jax.experimental.shard_map`` with a ``check_rep`` kwarg, ``lax.axis_size``
-does not exist, and there is no vma machinery at all. Every call site goes
-through these wrappers so the same code runs on both; the shims resolve the
-new API first and only then fall back, so behavior on current jax is
-byte-identical to calling it directly.
-"""
+"""Process-environment seams for the one installed jax (0.9.0): how many
+CPU devices a child process simulates, and where the persistent compilation
+cache lives."""
 
 from __future__ import annotations
 
-import re as _re
+import os
 
-# jax is imported lazily inside each shim: ``set_cpu_device_env`` is used
-# by tools BEFORE they re-exec into a scrubbed CPU-only environment, and
-# importing jax at that point would be pure startup cost in the throwaway
-# parent process.
-
-_HOST_COUNT_FLAG = _re.compile(
-    r"--xla_force_host_platform_device_count=\d+"
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def set_cpu_device_env(env, n: int):
-    """Make ``env`` yield an ``n``-device CPU backend on every jax release.
-
-    Current jax honors ``JAX_NUM_CPU_DEVICES``; 0.4-era jax ignores it and
-    only reads the XLA_FLAGS host-platform-count flag at first backend
-    init. Both are set, and an EXISTING count flag (e.g. inherited from the
-    test harness's 8-device environment) is replaced, not appended — XLA
-    honors the first occurrence, so appending would silently lose ``n``.
-    Works on ``os.environ`` or a plain subprocess env dict.
-    """
+    """Make ``env`` yield an ``n``-device CPU backend: ``JAX_NUM_CPU_DEVICES``
+    wins over any ``--xla_force_host_platform_device_count`` in ``XLA_FLAGS``
+    (``jax._src.xla_bridge.make_cpu_client``). Works on ``os.environ`` or a
+    plain subprocess env dict; must happen before the backend initializes."""
     env["JAX_NUM_CPU_DEVICES"] = str(n)
-    flag = f"--xla_force_host_platform_device_count={n}"
-    flags = env.get("XLA_FLAGS", "")
-    if _HOST_COUNT_FLAG.search(flags):
-        flags = _HOST_COUNT_FLAG.sub(flag, flags)
-    else:
-        flags = (flags + " " + flag).strip()
-    env["XLA_FLAGS"] = flags
     return env
 
 
-def enable_compile_cache(path: str) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (no-op for "").
+def compile_cache_dir() -> str:
+    """The persistent compilation cache directory: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``<checkout>/.jax_cache``. The path is part of the cache
+    key's neighbourhood on disk — it never moves with a pid, a time or a
+    temporary name, so a second process finds what the first one compiled."""
+    return os.environ.get(CACHE_DIR_ENV) or os.path.join(_CHECKOUT, ".jax_cache")
 
-    Thresholds are set so even the small configs' steps persist (min compile
-    time 1s, no size floor — same values the test harness uses). The
-    threshold knobs are version-guarded: the cache-dir option itself exists
-    on every release this repo supports, the tuning knobs came later.
-    Returns whether a cache was enabled.
-    """
-    if not path:
-        return False
+
+def setup_compile_cache() -> str:
+    """Switch the persistent compilation cache on; called first by every
+    entry point that compiles. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax
+    reads it itself and no directory is set in code. Thresholds are lowered
+    so the small configs' steps persist too. Returns the directory in use."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
-    for name, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 1.0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(name, value)
-        except (AttributeError, ValueError, KeyError):
-            pass
-    return True
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    On older jax the ``check_vma`` flag maps onto ``check_rep`` — both guard
-    the same contract (out_specs claiming replication the body doesn't
-    establish); bodies written for ``check_vma=False`` ran under
-    ``check_rep=False`` semantics before the rename.
-    """
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def axis_size(axis) -> int:
-    """Static size of a mesh axis from inside traced code.
-
-    ``lax.psum`` of the literal ``1`` is evaluated statically on every jax
-    release (it never emits a collective), so the fallback returns the same
-    Python int ``lax.axis_size`` does.
-    """
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-def pcast_varying(x, axis):
-    """``lax.pcast(x, (axis,), to="varying")`` where vma typing exists;
-    identity elsewhere (pre-vma jax has no invariant/varying distinction, so
-    there is nothing to re-vary — the value is already correct)."""
-    from jax import lax
-
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis,), to="varying")
-    return x
+    if not os.environ.get(CACHE_DIR_ENV):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return compile_cache_dir()

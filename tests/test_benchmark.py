@@ -1,14 +1,19 @@
 """Benchmark harness as test (SURVEY §4 tier 6): the measurement machinery
 itself is CI-checked — throughput is positive, the no-recompilation guard
-holds, the record carries the driver-contract fields, and ``vs_baseline``
-is honest about missing baselines (``None``, never a flattering 1.0).
+holds, the record names its device, ``vs_baseline`` is null (never 1.0)
+where no baseline is committed, and the peak-rate table is keyed by exact
+``device_kind`` (an unknown TPU is an error, the CPU has no MFU).
 """
 
 import json
 
 import pytest
 
-from distributeddeeplearning_tpu.benchmark import run_benchmark, vs_baseline
+from distributeddeeplearning_tpu.benchmark import (
+    _peak_tflops,
+    run_benchmark,
+    vs_baseline,
+)
 from distributeddeeplearning_tpu.config import (
     Config,
     DataConfig,
@@ -39,10 +44,12 @@ def test_run_benchmark_record_contract():
     assert record["unit"] == "images/sec/chip"
     assert record["device_count"] >= 1
     assert record["platform"] == "cpu"  # the pytest harness is CPU-pinned
+    assert record["device_kind"] == "cpu"
+    assert "mfu" not in record  # no peak rate on the CPU, so no utilisation
     assert record["params"] > 1e6
     # HBM telemetry key is ALWAYS present (VERDICT r4 Weak #5); the CPU
     # backend doesn't implement memory_stats, so here it must be null —
-    # "plugin doesn't report", distinguishable from "not recorded".
+    # "backend does not report", distinguishable from "not recorded".
     assert "hbm_peak_bytes" in record
     assert record["hbm_peak_bytes"] is None
     # Per-step latency percentiles ride along by default (dispatch-overhead
@@ -132,9 +139,13 @@ def test_run_benchmark_fused_probe_fields():
     json.dumps(record)
 
 
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
 def test_vs_baseline_unknown_metric_is_null(tmp_path):
-    # Round-2 regression: an absent baseline reported 1.0, making a
-    # chip-down CPU fallback read as "on par".
+    # An absent baseline once reported 1.0, which read as "on par".
     assert vs_baseline("no_such_metric", 123.0, repo_root=str(tmp_path)) is None
 
 
@@ -149,3 +160,17 @@ def test_vs_baseline_record_establishes_baseline(tmp_path):
     assert table["m2"] == 40.0
     # and is read back on the next call
     assert vs_baseline("m2", 80.0, repo_root=str(tmp_path)) == pytest.approx(2.0)
+
+
+def test_peak_rate_is_keyed_by_exact_device_kind():
+    assert _peak_tflops(_Dev("tpu", "TPU v5 lite")) == 197.0
+
+
+def test_unknown_tpu_kind_is_an_error_not_a_default():
+    # A substring match would have priced this as a v5e.
+    with pytest.raises(ValueError, match="no peak rate recorded"):
+        _peak_tflops(_Dev("tpu", "TPU v5 lite pod"))
+
+
+def test_cpu_has_no_peak_rate():
+    assert _peak_tflops(_Dev("cpu", "cpu")) is None

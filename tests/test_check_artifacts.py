@@ -17,17 +17,6 @@ sys.path.insert(0, os.path.join(_DIR, "tools"))
 import check_artifacts  # noqa: E402
 
 
-# Tools whose --check validates ACCELERATOR-measured artifacts
-# (BASELINES.md / MFU attack logs) that stay "pending" until someone runs
-# them on real hardware — by design not part of the always-green
-# committed-artifact contract this gate enforces.
-_HARDWARE_PENDING = {
-    "tools/measure_tpu.py",
-    "tools/mfu_attack.py",
-    "tools/render_baseline.py",
-}
-
-
 def test_roster_covers_every_check_capable_tool():
     tools_dir = os.path.join(_DIR, "tools")
     check_capable = set()
@@ -37,9 +26,8 @@ def test_roster_covers_every_check_capable_tool():
         with open(os.path.join(tools_dir, name)) as f:
             if '"--check"' in f.read():
                 check_capable.add(f"tools/{name}")
-    # a new --check-capable tool must be rostered (or explicitly listed
-    # as hardware-pending) the PR it lands
-    assert check_capable - _HARDWARE_PENDING == set(check_artifacts.CHECKS)
+    # a new --check-capable tool must be rostered the PR it lands
+    assert check_capable == set(check_artifacts.CHECKS)
 
 
 def test_all_committed_artifact_validators_green():

@@ -18,7 +18,6 @@ from jax.sharding import PartitionSpec as P
 import helpers
 
 from distributeddeeplearning_tpu import comms_quant as cq
-from distributeddeeplearning_tpu.utils import compat
 
 N = 8  # conftest pins an 8-device CPU sim
 
@@ -26,7 +25,7 @@ N = 8  # conftest pins an 8-device CPU sim
 def _ring(fn, x, mesh):
     """Run ``fn(flat_shard)`` inside shard_map over dp=8; input/output carry
     a leading member dim so every member's result comes back stacked."""
-    shard = compat.shard_map(
+    shard = jax.shard_map(
         lambda s: fn(s[0])[None], mesh=mesh, in_specs=(P("dp"),),
         out_specs=P("dp"), check_vma=False,
     )
@@ -175,7 +174,7 @@ def test_tree_all_reduce_pads_odd_sizes_and_matches_psum_closely():
         )
         return summed["w"][None], summed["b"][None]
 
-    shard = compat.shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh, in_specs=(P("dp"), P("dp")),
         out_specs=(P("dp"), P("dp")), check_vma=False,
     )

@@ -135,11 +135,18 @@ def test_crash_and_resume_file_backed(tmp_path):
         np.testing.assert_allclose(loss, want[step], rtol=1e-5, err_msg=str(step))
 
 
+def _own_cache_env(tmp_path) -> dict:
+    """A compile cache of this test's own, placed from outside the way a
+    deployment does: these children crash and hang on purpose, and where
+    ``supervisor.clear_cache_on_crash`` is on the supervisor wipes the
+    resolved directory — which must not be the suite's shared one."""
+    return dict(os.environ, JAX_COMPILATION_CACHE_DIR=f"{tmp_path}/xla")
+
+
 def _supervise_cmd(tmp_path, extra):
     """The _train_cmd run under ``cli supervise`` with fast-test supervisor
     knobs (tiny backoff, tight poll)."""
     cmd = _train_cmd(tmp_path, [
-        "--override", f"train.compile_cache_dir={tmp_path}/xla",
         "--override", "supervisor.backoff_base_s=0.1",
         "--override", "supervisor.poll_interval_s=0.1",
         *extra,
@@ -157,7 +164,7 @@ def test_supervised_corrupt_recovery(tmp_path):
         _supervise_cmd(tmp_path, [
             "--override", "train.fault_injection=corrupt:6",
         ]),
-        capture_output=True, text=True, env=dict(os.environ), cwd=REPO,
+        capture_output=True, text=True, env=_own_cache_env(tmp_path), cwd=REPO,
         timeout=540,
     )
     assert run.returncode == 0, run.stderr[-3000:]
@@ -178,13 +185,20 @@ def test_supervised_hang_recovery(tmp_path):
             # Must exceed the first attempt's cold compile (the loop can't
             # touch the heartbeat while jit blocks the host).
             "--override", "supervisor.hang_timeout_s=120",
+            # Off by default (the cache is shared); on here, with a cache
+            # of the run's own: the clear must hit that resolved directory.
+            "--override", "supervisor.clear_cache_on_crash=True",
         ]),
-        capture_output=True, text=True, env=dict(os.environ), cwd=REPO,
+        capture_output=True, text=True, env=_own_cache_env(tmp_path), cwd=REPO,
         timeout=540,
     )
     assert run.returncode == 0, run.stderr[-3000:]
     assert '"event": "fault_hang"' in run.stdout
     assert '"event": "supervisor_hang_kill"' in run.stdout
+    assert (
+        f'"event": "supervisor_cache_clear", "after": "hang", '
+        f'"path": "{tmp_path}/xla"'
+    ) in run.stdout
     assert '"step": 8' in run.stdout
 
 
@@ -198,7 +212,7 @@ def test_supervised_nan_skip(tmp_path):
             "--override", "train.fault_injection=nan:5",
             "--override", "health.enabled=True",
         ]),
-        capture_output=True, text=True, env=dict(os.environ), cwd=REPO,
+        capture_output=True, text=True, env=_own_cache_env(tmp_path), cwd=REPO,
         timeout=540,
     )
     assert run.returncode == 0, run.stderr[-3000:]
@@ -226,14 +240,13 @@ def test_sigterm_preemption_save_and_resume(tmp_path):
 
     from distributeddeeplearning_tpu.supervisor import EXIT_PREEMPTED
 
-    env = dict(os.environ)
+    env = _own_cache_env(tmp_path)  # the relaunch warm-starts from it
     err_path = tmp_path / "preempt.err"
     with open(err_path, "w") as err_f:
         proc = subprocess.Popen(
             _train_cmd(tmp_path, [
                 "--override", "train.steps=2000",
                 "--override", "train.save_every=500",
-                "--override", f"train.compile_cache_dir={tmp_path}/xla",
             ]),
             stdout=subprocess.PIPE, stderr=err_f, text=True, env=env,
             cwd=REPO,
@@ -260,7 +273,6 @@ def test_sigterm_preemption_save_and_resume(tmp_path):
     resumed = subprocess.run(
         _train_cmd(tmp_path, [
             "--override", f"train.steps={n + 2}",
-            "--override", f"train.compile_cache_dir={tmp_path}/xla",
         ]),
         capture_output=True, text=True, env=env, cwd=REPO, timeout=540,
     )
@@ -336,22 +348,12 @@ def test_two_process_rendezvous():
     """2-process jax.distributed over localhost: the multi-host init path,
     global mesh construction, and the make_array_from_process_local_data
     branch of sharded_batches — without a cluster."""
-    import jax
-
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        # Workers rendezvous fine, but the first jitted computation dies
-        # with "Multiprocess computations aren't implemented on the CPU
-        # backend" — multiprocess CPU landed in jax 0.5.
-        pytest.skip("multiprocess CPU backend requires jax >= 0.5")
     port = _free_port()
     addr = f"localhost:{port}"
     from distributeddeeplearning_tpu.utils.compat import set_cpu_device_env
 
     env = dict(os.environ)
-    # 2 procs x 4 = 8 global devices (set_cpu_device_env also rewrites the
-    # inherited 8-device XLA_FLAGS count, which pre-0.5 jax would honor
-    # instead of JAX_NUM_CPU_DEVICES).
-    set_cpu_device_env(env, 4)
+    set_cpu_device_env(env, 4)  # 2 procs x 4 = 8 global devices
     procs = [
         subprocess.Popen(
             [sys.executable, "-c", _WORKER, addr, str(pid)],

@@ -1,8 +1,8 @@
 """Pallas flash-attention kernel vs the pure-jnp oracle (interpret mode).
 
 SURVEY.md §4 tier 1: Pallas kernels are tested on CPU in interpret mode
-against materialized-softmax references; the real-chip compile smoke lives
-in tests/test_tpu_smoke.py (tier 4).
+against materialized-softmax references; the TPU compile lives in
+tests/test_tpu_compile.py and the on-chip parity in chip_smoke.py.
 """
 
 import jax

@@ -12,6 +12,11 @@ Contracts pinned here:
   tail so history is always complete.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -289,22 +294,59 @@ def test_prefetch_size_threaded_from_config(monkeypatch):
     assert seen["size"] == 3
 
 
-def test_compile_cache_dir_wired_through_build_all(tmp_path):
-    import jax
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CACHE_PROBE = (
+    "import json, jax;"
+    "from distributeddeeplearning_tpu.utils.compat import setup_compile_cache;"
+    "print(json.dumps([setup_compile_cache(),"
+    " jax.config.jax_compilation_cache_dir]))"
+)
 
-    from distributeddeeplearning_tpu.cli import build_all
-    from distributeddeeplearning_tpu.config import apply_overrides, load_config
 
-    before = jax.config.jax_compilation_cache_dir
-    cfg = apply_overrides(
-        load_config("configs/resnet18_cifar10.py"),
-        ["data.batch_size=8", "data.image_size=8",
-         'model.kwargs={"num_classes":10,"width":8,"stem":"cifar"}',
-         f"train.compile_cache_dir={tmp_path}/cc"],
+@pytest.mark.parametrize("placed", ["set", "unset"])
+def test_compile_cache_is_placed_from_outside(tmp_path, placed):
+    """THE rule (utils.compat.setup_compile_cache): where
+    JAX_COMPILATION_CACHE_DIR is set the cache lives there and no directory
+    is set in code; unset, it lives at <checkout>/.jax_cache — never at a
+    path built from a temporary name, a pid or the time."""
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(_REPO, ".jax_cache")
+    if placed == "set":
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, cwd=_REPO,
+        capture_output=True, text=True, timeout=300,
     )
-    try:
-        build_all(cfg)
-        assert jax.config.jax_compilation_cache_dir == f"{tmp_path}/cc"
-    finally:
-        # jax config is process-global — restore the harness's cache dir.
-        jax.config.update("jax_compilation_cache_dir", before)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) == [want, want]
+
+
+def test_second_process_finds_what_the_first_compiled(tmp_path):
+    """Two `cli train` processes on one JAX_COMPILATION_CACHE_DIR: the first
+    fills it, the second compiles the same tiny step and adds no entry."""
+    cache = tmp_path / "cc"
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache))
+    cmd = [
+        sys.executable, "-m", "distributeddeeplearning_tpu.cli", "train",
+        "--config", "configs/resnet18_cifar10.py",
+        "--override", "data.batch_size=8", "--override", "data.image_size=8",
+        "--override",
+        'model.kwargs={"num_classes":10,"width":8,"stem":"cifar"}',
+        "--override", "train.steps=2", "--override", "train.log_every=0",
+    ]
+
+    def entries():
+        return sorted(p.name for p in cache.iterdir()
+                      if p.name.endswith("-cache"))
+
+    for attempt in ("first", "second"):
+        out = subprocess.run(
+            cmd, env=env, cwd=_REPO, capture_output=True, text=True,
+            timeout=540,
+        )
+        assert out.returncode == 0, (attempt, out.stderr[-2000:])
+        if attempt == "first":
+            filled = entries()
+            assert filled, "the first process persisted nothing"
+    assert entries() == filled

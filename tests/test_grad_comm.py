@@ -182,26 +182,3 @@ def test_grad_sync_bytes_analytic_ratio():
     assert fp32 > bf16 > int8 > 0
     assert fp32 / int8 == pytest.approx(4 / (1 + 4 / 256), rel=1e-3)
     assert fp32 / bf16 == pytest.approx(2.0, rel=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# AOT: the quantized step lowers for a real TPU topology
-# ---------------------------------------------------------------------------
-
-
-def test_int8_step_lowers_on_v5e_topology():
-    helpers.skip_unless_topology("v5e:2x2")
-    from jax.experimental import topologies
-
-    topo = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2"
-    )
-    from distributeddeeplearning_tpu.mesh import MeshConfig, build_mesh
-
-    mesh = build_mesh(MeshConfig(dp=4), devices=list(topo.devices))
-    text = _compiled_step_text(mesh, grad_comm="int8")
-    cb = collective_bytes(text, 4)
-    assert cb["collective-permute"], (
-        "TPU lowering of the quantized step has no ring permutes"
-    )
-    assert "s8[" in text

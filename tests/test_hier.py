@@ -22,6 +22,7 @@ Contracts pinned here:
 """
 
 import io
+import json
 import os
 import socket
 import subprocess
@@ -37,7 +38,6 @@ from distributeddeeplearning_tpu import comms_hier as ch
 from distributeddeeplearning_tpu import data as data_lib
 from distributeddeeplearning_tpu import models
 from distributeddeeplearning_tpu.train import Trainer, get_task, make_optimizer
-from distributeddeeplearning_tpu.utils import compat
 
 N = 8
 DCN = 2
@@ -88,7 +88,7 @@ def test_resolve_hierarchy_modes():
 
 
 def _sm(fn, mesh):
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(P("dp", None),), out_specs=P("dp", None),
         check_vma=False,
     )
@@ -180,16 +180,27 @@ def test_hier_quantized_all_reduce_replicated_and_close(hier_data, mode):
 # ---------------------------------------------------------------------------
 
 
+def _assert_losses_within_ulps(got, want, maxulp=4):
+    """Per-step float32 losses ``maxulp`` apart at most (observed: 0, 0, 0,
+    1 on jax 0.9.0's XLA:CPU)."""
+    np.testing.assert_array_max_ulp(
+        np.float32(got), np.float32(want), maxulp=maxulp
+    )
+
+
 def test_train_parity_hier_equals_flat_fp32():
     mesh = helpers.mesh_of(dp=N)
     flat, _ = helpers.train_tiny_gpt2(mesh, n_steps=4)
     hier, _ = helpers.train_tiny_gpt2(
         mesh, n_steps=4, dcn_dp=DCN, comm_hierarchy="hierarchical"
     )
-    # Bitwise on this backend/model: the re-associated fp32 sums agree
-    # exactly here (pinned as such); the decomposition itself is proven
-    # bitwise against the numpy oracle above.
-    assert hier == flat, (hier, flat)
+    # NOT the same math: flat sums every gradient element over 8 members in
+    # one all-reduce, the hierarchy over 4 (intra-slice reduce-scatter) and
+    # then over 2 (cross-slice all-reduce). The fp32 sums are re-associated,
+    # so params already differ in their last bits after ONE step (~40 % of
+    # elements after four) and the losses can only agree to a few ulps. The
+    # decomposition itself is proven bitwise against the numpy oracle above.
+    _assert_losses_within_ulps(hier, flat)
 
 
 def test_train_parity_hier_bucketed_and_fused_ksteps():
@@ -219,23 +230,67 @@ def test_train_parity_hier_bucketed_and_fused_ksteps():
 
     flat = run()
     hier = run(dcn_dp=DCN, comm_hierarchy="auto")
-    assert hier == flat, (hier, flat)
+    # Flat against hierarchy: the same re-associated gradient sum as above.
+    _assert_losses_within_ulps(hier, flat)
+
+
+_SAME_MATH_WORKER = """
+import hashlib, json, sys
+import jax, numpy as np
+sys.path.insert(0, "tests")
+import helpers
+
+mesh = helpers.mesh_of(dp=8)
+kw = dict(n_steps=4, dcn_dp=2, comm_hierarchy="auto", grad_bucket_mb=0.05)
+out = {}
+for name, extra in (
+    ("replicated", {}), ("sharded", {"update_sharding": "sharded"})
+):
+    losses, state = helpers.train_tiny_gpt2(mesh, **kw, **extra)
+    digest = hashlib.sha256()
+    for leaf in jax.tree.leaves(state.params):
+        digest.update(np.asarray(leaf).tobytes())
+    out[name] = {
+        "losses": [float(x).hex() for x in losses],
+        "params": digest.hexdigest(),
+    }
+print("RESULT", json.dumps(out))
+"""
 
 
 def test_train_parity_sharded_equals_replicated_under_hier():
     # The intra-slice reduce-scatter doubles as the shard split: member i
     # updates global chunk pi(i), the two-phase gather reassembles — the
-    # update must be the SAME math as the replicated hierarchy, bitwise.
-    mesh = helpers.mesh_of(dp=N)
-    rep, _ = helpers.train_tiny_gpt2(
-        mesh, n_steps=4, dcn_dp=DCN, comm_hierarchy="auto",
-        grad_bucket_mb=0.05,
+    # update must be the SAME math as the replicated hierarchy, bitwise, in
+    # every loss and every final param.
+    #
+    # No reduction differs between the two (after one step the Adam moments
+    # are bit-equal); what differs on jax 0.9.0's XLA:CPU is instruction
+    # selection: LLVM contracts ``b1*mu + (1-b1)*g`` into an FMA in one
+    # program's update fusion and not in the other's (flat 1/N shards there,
+    # per-leaf shapes here), so from step 2 on ``mu`` rounds differently.
+    # Bitwise is therefore asserted where no FMA exists: a child process
+    # held to plain AVX. (``xla_cpu_max_isa`` is read once per process, not
+    # per compile, hence the child.)
+    from distributeddeeplearning_tpu.utils.compat import set_cpu_device_env
+
+    env = dict(os.environ)
+    set_cpu_device_env(env, N)
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=AVX"
+    ).strip()
+    proc = subprocess.run(
+        [sys.executable, "-c", _SAME_MATH_WORKER],
+        capture_output=True, text=True, env=env, cwd=REPO, timeout=300,
     )
-    sh, _ = helpers.train_tiny_gpt2(
-        mesh, n_steps=4, dcn_dp=DCN, comm_hierarchy="auto",
-        grad_bucket_mb=0.05, update_sharding="sharded",
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(
+        next(
+            line for line in proc.stdout.splitlines()
+            if line.startswith("RESULT")
+        )[len("RESULT "):]
     )
-    assert rep == sh, (rep, sh)
+    assert out["sharded"] == out["replicated"], out
 
 
 @pytest.mark.parametrize("mode", ["bf16", "int8"])
@@ -385,10 +440,8 @@ def test_launch_plan_threads_coordinator_env():
         # jax distributed init (telemetry.resolve_process_index reads it).
         assert env["DDL_PROCESS_INDEX"] == str(pid)
         assert env["KEEP"] == "me"
-        # Device pinning goes through the same compat shim the tests use.
         assert env["JAX_PLATFORMS"] == "cpu"
         assert env["JAX_NUM_CPU_DEVICES"] == "2"
-        assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]
     assert plan[0][0] == plan[1][0]  # same command, env differs per process
 
 
@@ -505,8 +558,6 @@ def test_two_process_hier_matches_single_process():
     """dp=4 split as 2 processes x 2 devices (each process one simulated
     slice), hierarchical sync on — the launcher-shaped topology — must
     match the single-process dp=4 flat run within fp32 tolerance."""
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip("multiprocess CPU backend requires jax >= 0.5")
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]

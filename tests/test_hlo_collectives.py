@@ -117,7 +117,7 @@ def test_ep_emits_token_exchange():
     # record: rule on = 6 all-gathers / 70 all-reduces, rule deleted =
     # 3 / 42, all-to-all = 0 in both — XLA's CPU SPMD pipeline lowers this
     # exchange in gather form, so the all-to-all-specific form is pinned to
-    # the TPU tier (tests/test_tpu_smoke.py::test_ep_lowering_on_tpu).
+    # the deviceless TPU compile (tests/test_tpu_compile.py).
     from distributeddeeplearning_tpu.sharding import make_rules
 
     mesh = mesh_of(dp=2, ep=4)

@@ -21,7 +21,6 @@ from distributeddeeplearning_tpu import data as data_lib
 from distributeddeeplearning_tpu import models
 from distributeddeeplearning_tpu.config import HealthConfig
 from distributeddeeplearning_tpu.train import Trainer, get_task, make_optimizer
-from distributeddeeplearning_tpu.utils import compat
 
 N = 8
 
@@ -143,8 +142,8 @@ def test_bucketed_all_reduce_bitwise_matches_per_leaf_psum():
 
     specs = jax.tree.map(lambda _: P("dp"), tree)
     kw = dict(mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False)
-    got = jax.jit(compat.shard_map(bucketed, **kw))(tree)
-    want = jax.jit(compat.shard_map(per_leaf, **kw))(tree)
+    got = jax.jit(jax.shard_map(bucketed, **kw))(tree)
+    want = jax.jit(jax.shard_map(per_leaf, **kw))(tree)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
@@ -164,7 +163,7 @@ def test_reduce_scatter_then_gather_matches_psum():
         return jax.tree.map(lambda x: x[None], out)
 
     specs = jax.tree.map(lambda _: P("dp"), tree)
-    got = jax.jit(compat.shard_map(
+    got = jax.jit(jax.shard_map(
         rs_ag, mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False
     ))(tree)
     want = jax.tree.map(lambda x: np.asarray(x).sum(0), tree)

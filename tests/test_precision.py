@@ -414,45 +414,6 @@ def test_int8_grad_comm_keeps_fp32_residual_under_bf16():
         assert leaf.dtype == jnp.float32, leaf.dtype
 
 
-# ---------------------------------------------------------------------------
-# Real-MXU numerics (CPU sim proves nothing about hardware bf16 dots)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.tpu
-@pytest.mark.tpu_only
-def test_bf16_step_trains_on_chip():
-    helpers.run_on_tpu(
-        """
-import numpy as np
-import jax, jax.numpy as jnp
-from distributeddeeplearning_tpu import data as data_lib, models
-from distributeddeeplearning_tpu.mesh import single_device_mesh
-from distributeddeeplearning_tpu.train import Trainer, get_task, make_optimizer
-
-mesh = single_device_mesh()
-model = models.get_model(
-    "gpt2", size="tiny", vocab_size=256, max_len=64, dropout_rate=0.0,
-    dtype=jnp.bfloat16,
-)
-ds = data_lib.SyntheticTokens(
-    batch_size=16, seq_len=32, vocab_size=256, seed=0, n_distinct=4)
-tr = Trainer(model, make_optimizer("adamw", 1e-3, precision="bf16"),
-             get_task("lm"), mesh, donate=False, precision="bf16")
-state = tr.init(0, ds.batch(0))
-losses = []
-for batch in data_lib.sharded_batches(
-        (ds.batch(i) for i in range(3)), mesh):
-    state, m = tr.train_step(state, batch)
-    losses.append(float(m["loss"]))
-assert all(np.isfinite(l) for l in losses), losses
-assert losses[-1] < losses[0], losses
-assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(state.params))
-print("MXU_BF16_OK", losses)
-"""
-    )
-
-
 def test_bench_mixed_precision_artifact():
     # The committed per-policy benchmark artifact (ISSUE 5 acceptance bar;
     # regenerate with tools/bench_mixed_precision.py): every policy row

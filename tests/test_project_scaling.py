@@ -119,13 +119,16 @@ def test_dcn_projection_costs_more_than_ici(shrunk):
 
 
 def test_measured_base_present_only_with_silicon_record(shrunk):
-    rn, gpt = shrunk["scenarios"]
-    # resnet50 has the round-3 silicon number (BENCH_BASELINE.json);
-    # projections must carry throughput columns derived from it.
-    assert rn["t_compute_ms"] and rn["t_compute_ms"] > 0
-    assert "images_per_sec_per_chip_no_overlap" in rn["projections"][0]
-    eff = rn["projections"][0]["scaling_efficiency_no_overlap"]
-    assert 0 < eff <= 1
+    # No training cell has a chip record yet (ROADMAP S1): a projection
+    # without a measured compute base would be fiction, so every scenario
+    # reports bytes and comm time only, and says why.
+    for row in shrunk["scenarios"]:
+        assert row["t_compute_ms"] is None
+        assert "no chip measurement" in row["t_compute_provenance"]
+        for proj in row["projections"]:
+            assert proj["comm_ms_per_step"] > 0
+            assert not any(k.startswith("scaling_efficiency") for k in proj)
+            assert "images_per_sec_per_chip_no_overlap" not in proj
 
 
 def test_measured_overlap_feeds_projection(shrunk):
@@ -139,12 +142,9 @@ def test_measured_overlap_feeds_projection(shrunk):
         pytest.skip("BENCH_OVERLAP.json not generated")
     assert 0.0 <= mo["fraction"] <= 1.0
     assert "BENCH_OVERLAP.json" in mo["source"]
-    rn = shrunk["scenarios"][0]  # resnet50 has the silicon compute base
-    for proj in rn["projections"]:
-        eff = proj["scaling_efficiency_measured_overlap"]
-        assert (proj["scaling_efficiency_no_overlap"]
-                <= eff
-                <= proj["scaling_efficiency_full_overlap"])
+    # The bracketed efficiency needs a measured compute base; none exists.
+    for proj in shrunk["scenarios"][0]["projections"]:
+        assert "scaling_efficiency_measured_overlap" not in proj
 
 
 def test_measured_dcn_calibration_feeds_projection(shrunk):
@@ -159,13 +159,9 @@ def test_measured_dcn_calibration_feeds_projection(shrunk):
     else:
         assert md["effective_gbytes_per_sec"] > 0
         assert "BENCH_MULTISLICE.json" in md["source"]
-    rn = shrunk["scenarios"][0]  # resnet50 has the silicon compute base
-    ici_proj, dcn_proj = rn["projections"]
+    ici_proj, dcn_proj = shrunk["scenarios"][0]["projections"]
     assert "scaling_efficiency_measured_dcn" not in ici_proj  # DCN-only
-    assert dcn_proj["comm_ms_per_step_measured_dcn"] > 0
-    assert (dcn_proj["scaling_efficiency_no_overlap"]
-            <= dcn_proj["scaling_efficiency_measured_dcn"]
-            <= dcn_proj["scaling_efficiency_full_overlap"])
+    assert "scaling_efficiency_measured_dcn" not in dcn_proj  # no base yet
 
 
 def test_committed_artifact_is_full_size():

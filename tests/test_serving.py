@@ -407,6 +407,49 @@ def test_decode_donation_counter_in_registry(tmp_path):
     assert dec["recompiles"] == 0
 
 
+def test_cache_warm_engine_serves_the_reference_tokens():
+    # Serving warm-starts from the persistent compilation cache, donated
+    # decode included: an engine whose every executable is a cache HIT must
+    # serve generate()'s tokens. (An older jax returned stale bytes from a
+    # cache-hit donated executable and the engine bypassed the cache; on
+    # jax 0.9.0 it does not, here or on the chip — chip_smoke.py makes the
+    # same comparison there.)
+    hits = []
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    model, params = _model_and_params("gpt2")
+    prompts = _prompts((5, 9, 3, 12))
+    padded, lens = pad_prompts(prompts, pad_id=0)
+    ref = np.asarray(generate(
+        model, params, padded, max_new_tokens=11, prompt_lens=lens
+    ))[:, -11:]
+
+    def serve():
+        eng = _engine(model, params)
+        eng.warmup()
+        for p in prompts:
+            eng.submit(Request(prompt=p, max_new_tokens=11))
+        return [st.generated for st in eng.run()]
+
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        # No compile-time floor: these tiny executables compile in well
+        # under the 1 s below which the suite's cache persists nothing.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        serve()  # fills the cache (or finds it filled by an earlier run)
+        hits.clear()
+        warm = serve()
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+    assert len(hits) >= len(_CFG.prompt_buckets) + 1  # prefills + decode
+    assert warm == [list(r) for r in ref]
+
+
 # ---------------------------------------------------------------------------
 # Page-table range safety (XLA gather clamps OOB indices silently)
 # ---------------------------------------------------------------------------

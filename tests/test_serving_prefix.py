@@ -1,6 +1,6 @@
 """Shared-prefix KV reuse on the serving engine (serving.prefix_cache):
 exact greedy token parity between warm (trie-hit) and cold admissions —
-pinned against the frozen generate golden — the
+both held to the no-cache full-forward reference — the
 len(prompt_buckets)+len(suffix_buckets)+1 compile pin with zero
 steady-state recompiles under warm/cold/decode-route traffic mix, the
 full-prefix decode route, composition with speculative decoding and with
@@ -13,8 +13,6 @@ config-time fences in tests/test_composition_fences.py.
 """
 
 import dataclasses
-import json
-import os
 
 import numpy as np
 import pytest
@@ -23,6 +21,7 @@ import jax
 
 from distributeddeeplearning_tpu import models
 from distributeddeeplearning_tpu.config import ServingConfig
+from distributeddeeplearning_tpu.generate import greedy_agreement
 from distributeddeeplearning_tpu.serving import (
     KVBlockPool,
     Request,
@@ -84,7 +83,7 @@ def _run_waves(eng, waves, max_new=9, temperature=0.0):
 
 
 # ---------------------------------------------------------------------------
-# Greedy parity: warm == cold == cache-off, and both pin to the golden
+# Greedy parity: warm == cold == cache-off, and both match the reference
 # ---------------------------------------------------------------------------
 
 
@@ -108,26 +107,23 @@ def test_warm_admissions_match_cache_off_engine(name):
 
 
 @pytest.mark.parametrize("name", ["gpt2", "llama"])
-def test_warm_greedy_matches_frozen_golden(name):
-    # The golden recipe (tests/test_generate_golden.py seeds/shapes,
-    # max_new=11) submitted TWICE: the first wave runs cold and seeds the
-    # trie; the second wave re-runs the identical prompts warm — the
-    # 9-token prompt becomes a full-prefix decode-route admission, the
-    # 5-token one a suffix-only prefill. Both waves must equal the
-    # FROZEN pre-cache artifact bit-for-bit, so a bug that shifted warm
-    # and cold in lockstep still fails.
-    golden_path = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "generate_golden.json"
-    )
-    with open(golden_path) as f:
-        golden = np.asarray(json.load(f)[name]["greedy"])
+def test_warm_greedy_matches_full_forward_reference(name):
+    # The generate-parity recipe (tests/test_generate_golden.py seeds and
+    # shapes, max_new=11) submitted TWICE: the first wave runs cold and
+    # seeds the trie; the second wave re-runs the identical prompts warm —
+    # the 9-token prompt becomes a full-prefix decode-route admission, the
+    # 5-token one a suffix-only prefill. Both waves are held to the
+    # no-cache full-forward reference computed here on the same params, not
+    # to each other — a bug that shifted warm and cold in lockstep still
+    # fails.
     model, params = _model_and_params(name)
     prompts = _prompts((5, 9, 3))
     eng = _engine(model, params)
     cold, warm = _run_waves(eng, [prompts, prompts], max_new=11)
-    for i in range(len(prompts)):
-        assert cold[i] == list(golden[i][-11:]), f"cold request {i}"
-        assert warm[i] == list(golden[i][-11:]), f"warm request {i}"
+    for wave, got in (("cold", cold), ("warm", warm)):
+        rec = greedy_agreement(model, params, prompts, got)
+        assert rec["tokens"] == 3 * 11, wave
+        assert rec["worst_logit_gap"] <= 1e-4, (wave, rec)
     pc = eng.stats()["prefix_cache"]
     assert pc["hit_tokens"] > 0
     assert pc["decode_route_admits"] >= 1  # the repeated 9-token prompt

@@ -152,21 +152,15 @@ def test_speculative_greedy_matches_generate(name):
 
 
 @pytest.mark.parametrize("name", ["gpt2", "llama"])
-def test_speculative_greedy_matches_frozen_golden(name):
+def test_speculative_greedy_matches_full_forward_reference(name):
     # Same recipe as tests/test_generate_golden.py (seeds, shapes,
-    # max_new=11) but decoded by the SPECULATIVE engine: the accepted
-    # token streams must equal the pre-refactor golden file bit-for-bit.
-    # This pins speculation to a FROZEN artifact, not to whatever
-    # generate() currently emits — a bug that shifted both paths in
-    # lockstep would still fail here.
-    import json
-    import os
+    # max_new=11) but decoded by the SPECULATIVE engine: every accepted
+    # token must be the argmax of the no-cache full-forward reference
+    # computed here on the same params — not whatever generate() currently
+    # emits, so a bug that shifted both cached paths in lockstep still
+    # fails.
+    from distributeddeeplearning_tpu.generate import greedy_agreement
 
-    golden_path = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "generate_golden.json"
-    )
-    with open(golden_path) as f:
-        golden = np.asarray(json.load(f)[name]["greedy"])
     model, params = _model_and_params(name)
     prompts = _prompts((5, 9, 3))
     eng = _engine(model, params)
@@ -174,9 +168,11 @@ def test_speculative_greedy_matches_frozen_golden(name):
         eng.submit(Request(prompt=p, max_new_tokens=11))
     done = eng.run()
     assert eng.calls["verify"] > 0, "speculation never engaged"
-    # golden rows are left-padded to the longest prompt (9) + 11 new.
-    for i, st in enumerate(done):
-        assert st.generated == list(golden[i][-11:]), f"request {i}"
+    rec = greedy_agreement(
+        model, params, prompts, [st.generated for st in done]
+    )
+    assert rec["tokens"] == 3 * 11
+    assert rec["worst_logit_gap"] <= 1e-4, rec
 
 
 @pytest.mark.parametrize("name", ["gpt2", "llama"])

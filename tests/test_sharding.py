@@ -56,6 +56,22 @@ def test_constrain_outside_mesh_is_noop():
     np.testing.assert_allclose(x, y)
 
 
+def test_constrain_inside_shard_map_is_noop(mesh_factory):
+    # In a shard_map body the mesh axes are Manual: the body holds its shard,
+    # and with_sharding_constraint refuses a spec naming a Manual axis (jax
+    # 0.9.0 raises where older releases ignored it) — with or without the
+    # Trainer's activation mesh around the call.
+    mesh = mesh_factory(dp=8)
+    x = jnp.arange(64.0).reshape(16, 4)
+    body = jax.shard_map(
+        lambda v: sh.constrain(v, "batch", "embed") * 2.0,
+        mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+    )
+    np.testing.assert_array_equal(jax.jit(body)(x), x * 2.0)
+    with sh.activation_mesh(mesh):
+        np.testing.assert_array_equal(jax.jit(body)(x), x * 2.0)
+
+
 def test_constrain_applies_default_rules_under_mesh(mesh_factory):
     # Inside jit under a mesh, constrain() must actually shard via the
     # default rules table without any ambient nn.logical_axis_rules context.
