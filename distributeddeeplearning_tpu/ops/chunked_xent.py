@@ -55,6 +55,7 @@ def is_chunked_head(out) -> bool:
     return isinstance(out, dict) and "hidden" in out and "emb" in out
 
 
+@jax.named_scope("chunked_xent")
 def chunked_xent(
     out: ChunkedHeadOut,
     targets: jax.Array,

@@ -171,6 +171,7 @@ def _fwd(q, k, v, vl, causal, sm_scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(vl, q, k, v)
 
@@ -322,6 +323,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, valid_len, use_vl,
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(vl, q, k, v, o, do, lse)
 
@@ -347,6 +349,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, valid_len, use_vl,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(vl, q, k, v, o, do, lse)
     # vl is an integer input: no cotangent.

@@ -124,6 +124,7 @@ def _fused_leaf(p, g, m, v, lr, c1, c2, *, b1, b2, eps, wd, interpret):
         # (Indices are positions in the full input list, scalars included:
         # p=3 -> delta, m=5 -> new_m, v=6 -> new_v; dtypes/shapes match.)
         input_output_aliases={3: 0, 5: 1, 6: 2},
+        name="fused_adamw",
         interpret=interpret,
     )(
         jnp.asarray(lr, jnp.float32).reshape(1, 1),

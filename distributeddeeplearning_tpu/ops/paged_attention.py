@@ -257,6 +257,7 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, num_rep, kv_heads, D), q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(
         jnp.asarray(page_table, jnp.int32), jnp.asarray(seq_lens, jnp.int32),

@@ -139,6 +139,7 @@ def _ring_step(qf, kt, vt, m, l, acc, mode, *, block_q, block_k, interpret):
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="ring_fwd",
         interpret=interpret,
     )(mode, qf, kt, vt, m, l, acc)
 
@@ -316,6 +317,7 @@ def _ring_bwd_step(
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="ring_bwd_dq",
         interpret=interpret,
     )(mode, q, kt, vt, do, lse, delta, dq)
 
@@ -340,6 +342,7 @@ def _ring_bwd_step(
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="ring_bwd_dkv",
         interpret=interpret,
     )(mode, q, kt, vt, do, lse, delta, dkt, dvt)
     return dq, dkt, dvt

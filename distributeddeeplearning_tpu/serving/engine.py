@@ -592,10 +592,16 @@ class ServingEngine:
         self.static_batching = static_batching
         self.events: list[dict] = []
         self._emit = emit if emit is not None else self.events.append
-        # Telemetry bundle (telemetry.py): schedule/prefill/decode spans,
-        # per-executable compile+memory records, and an event mirror for
-        # the flight recorder. NULL when the caller didn't wire one.
+        # Telemetry bundle (telemetry.py): an engine_step span a step,
+        # tiled by schedule/prefill/decode and the host work around them
+        # (docs/OBSERVABILITY.md), per-executable compile+memory records,
+        # and an event mirror for the flight recorder. NULL when the
+        # caller didn't wire one.
         self._tel = telemetry if telemetry is not None else NULL_TELEMETRY
+        if self._tel.enabled:
+            # One clock: every span is also an event on the host plane of
+            # any jax profile taken meanwhile, beside the device ops.
+            self._tel.tracer.annotate = jax.profiler.TraceAnnotation
         self.gauge_every = int(getattr(cfg, "gauge_every", 0))
         self.max_seq_len = int(cfg.max_seq_len) or int(model.max_len)
         if self.max_seq_len > int(model.max_len):
@@ -1279,14 +1285,18 @@ class ServingEngine:
 
     def _prefill_fn(self, params, cache, tokens, pos, rng, temp, tk, tp):
         out, cache = prefill(self.model, self._dequant(params), cache, tokens)
-        tok, rng = self._sample_body(logits_at(out, pos), rng, temp, tk, tp)
+        with jax.named_scope("sample"):
+            tok, rng = self._sample_body(
+                logits_at(out, pos), rng, temp, tk, tp
+            )
         return tok, rng, cache
 
     def _decode_fn(self, params, cache, tok, rng, temp, tk, tp):
         logits, cache = decode_step(
             self.model, self._dequant(params), cache, tok
         )
-        tok, rng = self._sample_body(logits, rng, temp, tk, tp)
+        with jax.named_scope("sample"):
+            tok, rng = self._sample_body(logits, rng, temp, tk, tp)
         return tok, rng, cache
 
     def _verify_fn(self, params, cache, toks):
@@ -1299,8 +1309,9 @@ class ServingEngine:
                  donate_argnums=()):
         self.num_compiles += 1
         t0 = time.perf_counter()
-        jitted = jax.jit(fn, donate_argnums=donate_argnums)
-        exe = jitted.lower(*args).compile()
+        with self._tel.span("compile", name=name):
+            jitted = jax.jit(fn, donate_argnums=donate_argnums)
+            exe = jitted.lower(*args).compile()
         if name is not None:
             # Device registry: compile wall time + memory_analysis(); a
             # second record under one name shows up as recompiles > 0 —
@@ -1379,11 +1390,12 @@ class ServingEngine:
         fenced disjoint from prompt buckets, so the arithmetic is exact
         and steady-state traffic of any prompt/suffix mix recompiles
         nothing."""
-        self._decode_exe_or_compile()
-        if self.spec_k:
-            self._verify_exe_or_compile()
-        for b in self._prefill_widths:
-            self._prefill_exe_for(b)
+        with self._tel.span("warmup"):
+            self._decode_exe_or_compile()
+            if self.spec_k:
+                self._verify_exe_or_compile()
+            for b in self._prefill_widths:
+                self._prefill_exe_for(b)
 
     # ------------------------------------------------------------------
     # request lifecycle
@@ -1514,64 +1526,71 @@ class ServingEngine:
         self._tel.hist("ttft").record(now - state.arrival_s)
 
     def _admit_one(self, state: RequestState):
-        req, slot = state.request, state.slot
-        # Promote FIRST (before the decode-route branch too — a full-
-        # prefix hit can ride through spilled nodes): the device_put
-        # inside dispatches async and overlaps everything below, through
-        # the suffix-prefill dispatch.
-        self._apply_promotions(state)
-        row = np.zeros((self.pages,), np.int32)
-        chain = state.cached_blocks + state.blocks  # logical block order
-        row[: len(chain)] = chain
-        rng = np.asarray(
-            jax.random.fold_in(
-                jax.random.PRNGKey(self._seed), req.request_id
-            ),
-            np.uint32,
-        )[None]
-        # Arm the sampling operands either way; the rng chain starts at
-        # the same fold_in(seed, request_id) on every admission path, so
-        # tokens are independent of the cache state that admitted them.
-        self._table[slot] = row
-        self._temp[slot] = req.temperature
-        self._top_k[slot] = req.top_k
-        self._top_p[slot] = req.top_p
-        if state.decode_route:
-            if self.role == "prefill":
-                # Full-prefix hit on a PREFILL replica: the entire
-                # exportable chain is already resident, so hand off
-                # without running a forward at all. written=len-1: the
-                # last prompt token's KV was never computed here (no
-                # decode step runs on this role) — the retirement
-                # publish must not cover it.
-                self._queue_handoff(state, written=len(req.prompt) - 1)
+        """One admission (the ``prefill`` span is the caller's):
+        ``prefill_prepare`` holds everything before the prefill executable
+        is called and ``prefill_readback`` everything from the first host
+        read of its outputs on; the dispatch and ``_fold_pools`` between
+        them are what is left of ``prefill``."""
+        tel = self._tel
+        with tel.span("prefill_prepare"):
+            req, slot = state.request, state.slot
+            # Promote FIRST (before the decode-route branch too — a full-
+            # prefix hit can ride through spilled nodes): the device_put
+            # inside dispatches async and overlaps everything below, through
+            # the suffix-prefill dispatch.
+            self._apply_promotions(state)
+            row = np.zeros((self.pages,), np.int32)
+            chain = state.cached_blocks + state.blocks  # logical block order
+            row[: len(chain)] = chain
+            rng = np.asarray(
+                jax.random.fold_in(
+                    jax.random.PRNGKey(self._seed), req.request_id
+                ),
+                np.uint32,
+            )[None]
+            # Arm the sampling operands either way; the rng chain starts at
+            # the same fold_in(seed, request_id) on every admission path, so
+            # tokens are independent of the cache state that admitted them.
+            self._table[slot] = row
+            self._temp[slot] = req.temperature
+            self._top_k[slot] = req.top_k
+            self._top_p[slot] = req.top_p
+            if state.decode_route:
+                if self.role == "prefill":
+                    # Full-prefix hit on a PREFILL replica: the entire
+                    # exportable chain is already resident, so hand off
+                    # without running a forward at all. written=len-1: the
+                    # last prompt token's KV was never computed here (no
+                    # decode step runs on this role) — the retirement
+                    # publish must not cover it.
+                    self._queue_handoff(state, written=len(req.prompt) - 1)
+                    return
+                # Full-prefix hit: every position but the last prompt token is
+                # cached, and matching is capped there — so there is nothing
+                # to prefill. Arm the lane with the last prompt token as the
+                # pending input; the next batched decode/verify step writes
+                # its KV (position len-1, in the request's OWN first block)
+                # and samples the first new token.
+                self._lens[slot] = len(req.prompt) - 1
+                self._tok[slot] = req.prompt[-1]
+                self._rng[slot] = rng[0]
                 return
-            # Full-prefix hit: every position but the last prompt token is
-            # cached, and matching is capped there — so there is nothing
-            # to prefill. Arm the lane with the last prompt token as the
-            # pending input; the next batched decode/verify step writes
-            # its KV (position len-1, in the request's OWN first block)
-            # and samples the first new token.
-            self._lens[slot] = len(req.prompt) - 1
-            self._tok[slot] = req.prompt[-1]
-            self._rng[slot] = rng[0]
-            return
-        off = state.cached_len  # 0 = cold, else suffix-only prefill
-        P = state.bucket
-        suffix = req.prompt[off:]
-        tokens = np.zeros((1, P), np.int32)
-        tokens[0, : len(suffix)] = suffix  # RIGHT-padded to the width
-        temp = np.float32([req.temperature])
-        tk = np.int32([req.top_k])
-        tp = np.float32([req.top_p])
-        pos = np.int32([len(suffix) - 1])
-        exe = self._prefill_exe_for(P)
-        # The SAME bulk-prefill body starts at any offset: positions, the
-        # causal mask, and the KV scatter all derive from the injected
-        # seq_lens leaf, so seq_lens=off shifts everything at once —
-        # writes land in the request's own blocks (row[off//bs:]), and the
-        # suffix attends to cached prefix KV through the shared table.
-        cache1 = self._inject(self._cache, row[None], np.int32([off]))
+            off = state.cached_len  # 0 = cold, else suffix-only prefill
+            P = state.bucket
+            suffix = req.prompt[off:]
+            tokens = np.zeros((1, P), np.int32)
+            tokens[0, : len(suffix)] = suffix  # RIGHT-padded to the width
+            temp = np.float32([req.temperature])
+            tk = np.int32([req.top_k])
+            tp = np.float32([req.top_p])
+            pos = np.int32([len(suffix) - 1])
+            exe = self._prefill_exe_for(P)
+            # The SAME bulk-prefill body starts at any offset: positions, the
+            # causal mask, and the KV scatter all derive from the injected
+            # seq_lens leaf, so seq_lens=off shifts everything at once —
+            # writes land in the request's own blocks (row[off//bs:]), and the
+            # suffix attends to cached prefix KV through the shared table.
+            cache1 = self._inject(self._cache, row[None], np.int32([off]))
         tok, rng_out, cache1 = exe(
             self._params, cache1, tokens, pos, rng, temp, tk, tp
         )
@@ -1590,25 +1609,33 @@ class ServingEngine:
             self.scheduler.publish_prefix(state, len(req.prompt))
             self._queue_handoff(state, written=len(req.prompt))
             return
-        tok = int(tok[0])
-        now = self.clock()
-        state.generated.append(tok)
-        state.token_times_s.append(now)
-        # Arm the lane for decode: the KV holds len real positions (pad
-        # writes beyond len are masked and will be overwritten in place).
-        self._lens[slot] = len(req.prompt)
-        self._tok[slot] = tok
-        self._rng[slot] = np.asarray(rng_out[0], np.uint32)
-        self._note_first_token(state, now)
-        # Publish the prompt's full blocks now that their KV is written
-        # and final — later arrivals in the same wave already hit them.
-        self.scheduler.publish_prefix(state, len(req.prompt))
-        self._finish_if_done(state, tok)
+        with tel.span("prefill_readback"):
+            tok = int(tok[0])
+            now = self.clock()
+            state.generated.append(tok)
+            state.token_times_s.append(now)
+            # Arm the lane for decode: the KV holds len real positions (pad
+            # writes beyond len are masked and will be overwritten in place).
+            self._lens[slot] = len(req.prompt)
+            self._tok[slot] = tok
+            self._rng[slot] = np.asarray(rng_out[0], np.uint32)
+            self._note_first_token(state, now)
+            # Publish the prompt's full blocks now that their KV is written
+            # and final — later arrivals in the same wave already hit them.
+            self.scheduler.publish_prefix(state, len(req.prompt))
+            self._finish_if_done(state, tok)
 
     def step(self) -> bool:
         """One engine iteration: admit (+prefill) into free lanes, then one
         decode call for the whole batch. Returns False when idle."""
         self.step_count += 1
+        # The whole iteration, early returns included; its children
+        # (schedule, prefill, draft, decode_prepare, decode, collect) leave
+        # the gauge block and loop glue as its self time.
+        with self._tel.span("engine_step", step=self.step_count):
+            return self._step()
+
+    def _step(self) -> bool:
         tel = self._tel
         now = self.clock()
         with tel.span("schedule", step=self.step_count) as sp:
@@ -1680,14 +1707,15 @@ class ServingEngine:
             return not self.scheduler.idle
         toks = dlens = None
         if self.spec_k:
-            toks = np.zeros((self.slots_n, self.spec_k + 1), np.int32)
-            toks[:, 0] = self._tok
-            dlens = np.zeros((self.slots_n,), np.int32)
-            for state in active:
-                d = self._draft_for(state)
-                if d:
-                    toks[state.slot, 1:1 + len(d)] = d
-                    dlens[state.slot] = len(d)
+            with tel.span("draft", step=self.step_count):
+                toks = np.zeros((self.slots_n, self.spec_k + 1), np.int32)
+                toks[:, 0] = self._tok
+                dlens = np.zeros((self.slots_n,), np.int32)
+                for state in active:
+                    d = self._draft_for(state)
+                    if d:
+                        toks[state.slot, 1:1 + len(d)] = d
+                        dlens[state.slot] = len(d)
         if dlens is not None and dlens.any():
             self._verify_batch(active, toks, dlens)
         else:
@@ -1697,18 +1725,29 @@ class ServingEngine:
             self._decode_batch(active)
         return not self.scheduler.idle
 
+    def _decode_operands(self, active, **span_args):
+        """The head both batched calls share (the ``decode_prepare``
+        span): the cache with this step's table and lengths injected, and
+        the arguments of the ``decode`` span that follows."""
+        tel = self._tel
+        with tel.span("decode_prepare", step=self.step_count):
+            cacheS = self._inject(self._cache, self._table, self._lens)
+            decode_args = {
+                "step": self.step_count, "batch": len(active), **span_args
+            }
+            if tel.enabled:
+                # Only materialize the id list when a tracer will keep it.
+                decode_args["request_ids"] = [
+                    s.request.request_id for s in active
+                ]
+        return cacheS, decode_args
+
     def _decode_batch(self, active):
         """One plain decode call (L=1) for the whole batch: the
         non-speculative hot path, and the speculative engine's fallback on
         steps where no lane produced a draft."""
         tel = self._tel
-        cacheS = self._inject(self._cache, self._table, self._lens)
-        decode_args = {"step": self.step_count, "batch": len(active)}
-        if tel.enabled:
-            # Only materialize the id list when a tracer will keep it.
-            decode_args["request_ids"] = [
-                s.request.request_id for s in active
-            ]
+        cacheS, decode_args = self._decode_operands(active)
         with tel.span("decode", **decode_args):
             tok, rng, cacheS = self._decode_exe_or_compile()(
                 self._params, cacheS, self._tok[:, None], self._rng,
@@ -1720,20 +1759,22 @@ class ServingEngine:
             # throughput denominator in serve_bench) flatters L=1 steps
             # relative to the verify path, which must sync to accept.
             tok = np.asarray(tok)
-        self.calls["decode"] += 1
-        self._cache = cacheS
-        # np.array (copy): rows must stay writable for the next admission.
-        self._rng = np.array(rng, np.uint32)
-        now = self.clock()
-        for state in active:
-            slot = state.slot
-            t = int(tok[slot])
-            state.generated.append(t)
-            state.token_times_s.append(now)
-            self._lens[slot] += 1
-            self._tok[slot] = t
-            self._note_first_token(state, now)  # decode-route admissions
-            self._finish_if_done(state, t)
+        with tel.span("collect", step=self.step_count):
+            self.calls["decode"] += 1
+            self._cache = cacheS
+            # np.array (copy): rows must stay writable for the next
+            # admission.
+            self._rng = np.array(rng, np.uint32)
+            now = self.clock()
+            for state in active:
+                slot = state.slot
+                t = int(tok[slot])
+                state.generated.append(t)
+                state.token_times_s.append(now)
+                self._lens[slot] += 1
+                self._tok[slot] = t
+                self._note_first_token(state, now)  # decode-route admissions
+                self._finish_if_done(state, t)
 
     def _draft_for(self, state: RequestState) -> list[int]:
         """Host-side draft source for one lane (overridable in tests): up
@@ -1752,15 +1793,9 @@ class ServingEngine:
         dead until the next step's own K+1-position scatter overwrites it
         (the scatter precedes every attention read)."""
         tel = self._tel
-        cacheS = self._inject(self._cache, self._table, self._lens)
-        decode_args = {
-            "step": self.step_count, "batch": len(active),
-            "speculative": True, "drafted": int(dlens.sum()),
-        }
-        if tel.enabled:
-            decode_args["request_ids"] = [
-                s.request.request_id for s in active
-            ]
+        cacheS, decode_args = self._decode_operands(
+            active, speculative=True, drafted=int(dlens.sum())
+        )
         with tel.span("decode", **decode_args) as sp:
             greedy, cacheS = self._verify_exe_or_compile()(
                 self._params, cacheS, toks
@@ -1812,10 +1847,13 @@ class ServingEngine:
             # Accepted-length span args: the per-step speculation yield,
             # next to the device call in the merged trace view.
             sp.set(accepted=emitted, draft_hits=hits)
-        self.spec["drafted"] += int(dlens.sum())
-        self.spec["draft_hits"] += hits
-        self.spec["emitted"] += emitted
-        self.spec["lane_steps"] += len(active)
+        # The acceptance loop sits inside ``decode`` (above), so after a
+        # verify call ``collect`` holds the counters alone.
+        with tel.span("collect", step=self.step_count):
+            self.spec["drafted"] += int(dlens.sum())
+            self.spec["draft_hits"] += hits
+            self.spec["emitted"] += emitted
+            self.spec["lane_steps"] += len(active)
 
     def run(self, max_steps: int = 0) -> list[RequestState]:
         """Drain the queue; returns the finished states (submit order)."""

@@ -254,7 +254,6 @@ class FleetSupervisor:
                 "worker_give_up", self.router.tick_count,
                 replica=h.index, restarts=h.restarts_done, kind=kind,
             ))
-            self.telemetry.count("worker_give_up")
             return
         backoff = self.backoff_s(h.restarts_done)
         h.respawn_at = self.clock() + backoff
@@ -263,7 +262,6 @@ class FleetSupervisor:
             replica=h.index, kind=kind,
             backoff_s=round(backoff, 6), attempt=h.attempt + 1,
         ))
-        self.telemetry.count("worker_deaths")
 
     def _respawn(self, h: WorkerHandle) -> None:
         h.respawn_at = None
@@ -284,7 +282,6 @@ class FleetSupervisor:
                     replica=h.index, restarts=h.restarts_done,
                     error=f"{type(exc).__name__}: {exc}",
                 ))
-                self.telemetry.count("worker_give_up")
             else:
                 backoff = self.backoff_s(h.restarts_done)
                 h.respawn_at = self.clock() + backoff
@@ -320,7 +317,6 @@ class FleetSupervisor:
         self._emit(event_record(
             "worker_restarted", self.router.tick_count, **rec,
         ))
-        self.telemetry.count("worker_restarts")
         self.telemetry.flight_dump("worker_restart", **rec)
 
     # -- lifecycle ---------------------------------------------------------

@@ -10,8 +10,11 @@ arXiv 2204.06514) applied to this codebase:
 
 - :class:`SpanTracer` — hierarchical host-side spans (``step`` /
   ``data_wait`` / ``dispatch`` / ``device_wait`` / ``checkpoint`` /
-  ``eval`` and the serving phases ``prefill`` / ``decode`` /
-  ``schedule``) in a bounded ring with strictly monotonic timestamps,
+  ``eval``, the serving engine's ``engine_step`` tiled by ``schedule``
+  / ``prefill`` / ``decode`` and the host work around them, and ``gc``
+  for the collector's long runs) in a bounded ring with strictly
+  monotonic timestamps, optionally mirrored as profiler annotations
+  (``SpanTracer.annotate``),
   nestable via context manager, near-zero cost when disabled, exportable
   as Chrome-trace/Perfetto JSON (matched B/E pairs) or a JSONL stream on
   the PR-4 ``metrics.event_record`` shape.
@@ -45,19 +48,27 @@ training run down.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import math
 import os
 import tempfile
 import time
+import weakref
 from collections import deque
 
 # The span taxonomy (docs/OBSERVABILITY.md). Advisory, not enforced:
 # callers may open spans with other names, but the standard loop/serving
 # phases use exactly these so traces compare across runs.
+GC_SPAN = "gc"
 SPAN_NAMES = (
     "step", "data_wait", "dispatch", "device_wait", "checkpoint", "eval",
     "prefill", "decode", "schedule",
+    # ServingEngine.step tiled (docs/OBSERVABILITY.md has extents and parents)
+    "engine_step", "prefill_prepare", "prefill_readback", "decode_prepare",
+    "draft", "collect", "warmup", "compile",
+    # one per run of Python's collector of a millisecond or more (watch_gc)
+    GC_SPAN,
 )
 
 # Speculative-decoding accept counter (serving/engine.py): tokens
@@ -280,7 +291,7 @@ NULL_SPAN = _NullSpan()
 
 
 class _SpanCM:
-    __slots__ = ("_tracer", "_name", "_args", "_start")
+    __slots__ = ("_tracer", "_name", "_args", "_start", "_ann")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
@@ -289,6 +300,11 @@ class _SpanCM:
 
     def __enter__(self):
         tr = self._tracer
+        # The annotation opens before the span's clock is read and closes
+        # after it, so what it costs falls outside the span.
+        self._ann = None if tr.annotate is None else tr.annotate(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         tr._stack.append(self._name)
         self._start = tr._now()
         return self
@@ -307,6 +323,8 @@ class _SpanCM:
         cb = tr.on_close
         if cb is not None:
             cb(span)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -338,6 +356,12 @@ class SpanTracer:
         # Telemetry bundle feeds per-phase latency histograms without the
         # instrumented code changing (still one attribute check when unset).
         self.on_close = None
+        # Optional callable(name) -> context manager entered and left with
+        # every span: ``fit`` and ``ServingEngine`` set
+        # ``jax.profiler.TraceAnnotation`` here (this module imports no
+        # jax), which puts each span on the host plane of any profile taken
+        # meanwhile, on the profiler's clock, beside the device ops.
+        self.annotate = None
 
     def _now(self) -> float:
         t = self._clock()
@@ -346,13 +370,17 @@ class SpanTracer:
         self._last = t
         return t
 
-    def span(self, name: str, **args):
+    def span(self, name: str, /, **args):
         if not self.enabled:
             return NULL_SPAN
         return _SpanCM(self, name, args)
 
     @property
     def spans(self) -> list[Span]:
+        """A snapshot of the ring. Every reader goes through it: the
+        collector's hook appends a ``gc`` span whenever it fires, and a
+        deque that grows under a Python-level loop raises. (The copy is one
+        C call; the collector runs between bytecodes.)"""
         return list(self._ring)
 
     def __len__(self) -> int:
@@ -362,14 +390,21 @@ class SpanTracer:
 
     def chrome_trace(self) -> dict:
         """Chrome-trace/Perfetto JSON: one B and one E event per completed
-        span, microsecond timestamps relative to the oldest ringed span,
-        strictly increasing (rounding collisions are bumped by 1us so the
-        stream stays well-formed after integer truncation). Top-level
+        span (one X event per ``gc`` span), microsecond timestamps relative
+        to the oldest ringed span, strictly increasing (rounding collisions
+        are bumped by 1us so the stream stays well-formed after integer
+        truncation). Top-level
         ``t0_s`` is the tracer-clock zero of the ts axis — what the fleet
         aggregator pairs with the process's wall-clock anchor record to
         place N hosts' traces on one timeline."""
         events = []
-        for s in self._ring:
+        for s in self.spans:
+            if s.name == GC_SPAN:
+                # Recorded from the collector's callback, on whichever
+                # thread it fired: a complete ("X") event, which no B/E
+                # stack has to match.
+                events.append((s.t_start, "X", s))
+                continue
             events.append((s.t_start, "B", s))
             events.append((s.t_end, "E", s))
         events.sort(key=lambda e: e[0])
@@ -384,8 +419,10 @@ class SpanTracer:
             prev_us = us
             ev = {"name": s.name, "ph": ph, "ts": us, "pid": pid, "tid": 1,
                   "cat": "host"}
-            if ph == "B" and s.args:
+            if ph != "E" and s.args:
                 ev["args"] = dict(s.args)
+            if ph == "X":
+                ev["dur"] = max(1, int(round((s.t_end - s.t_start) * 1e6)))
             out.append(ev)
         return {"traceEvents": out, "displayTimeUnit": "ms",
                 "t0_s": round(t0, 9)}
@@ -397,7 +434,7 @@ class SpanTracer:
         """The ringed spans as PR-4 ``event_record``-shaped dicts — the
         JSONL stream form (and what the flight recorder embeds)."""
         out = []
-        for s in self._ring:
+        for s in self.spans:
             step = s.args.get("step", -1)
             rec = {
                 "event": "span",
@@ -421,6 +458,61 @@ class SpanTracer:
             return path
         except OSError:
             return None
+
+
+# Enabled tracers that get a ``gc`` span for each run of Python's collector
+# that took GC_MIN_S or more. Process-wide because the collector is: one
+# ``gc.callbacks`` hook serves them all, and does nothing once they are gone.
+# Shorter runs (the young generations, tens of microseconds each, many a
+# second) stay in the self time of whatever was open: they would crowd the
+# ring out, and none of them can own a millisecond. The hook runs twice for
+# every one of those too, so it holds weak references in a plain list and
+# allocates next to nothing (about a microsecond a run).
+GC_MIN_S = 1e-3
+_gc_watchers: list = []  # weakref.ref(SpanTracer), dropped as they die
+_gc_started: list = []  # (ref, start) of the collection under way
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        # Weak references, not tracers: one this hook held through the
+        # collection could never be collected.
+        _gc_started.clear()
+        for ref in _gc_watchers:
+            tr = ref()
+            if tr is not None and tr.enabled:
+                _gc_started.append((ref, tr._clock()))
+        tr = None
+        return
+    for ref, start in _gc_started:
+        tr = ref()
+        if tr is None:
+            continue
+        end = tr._clock()
+        if end - start < GC_MIN_S:
+            continue
+        # Straight into the ring, never through the span stack or the
+        # fence of its clock: the collector may fire on another thread
+        # (prefetch) while the loop's thread has spans open. One level
+        # below the innermost open span, so that the gap it causes is
+        # attributed to it.
+        span = Span(
+            GC_SPAN, start, end, len(tr._stack),
+            {"generation": info.get("generation"),
+             "collected": info.get("collected")},
+        )
+        tr._ring.append(span)
+        if tr.on_close is not None:
+            tr.on_close(span)
+    _gc_started.clear()
+
+
+def watch_gc(tracer: "SpanTracer") -> None:
+    """Record a ``gc`` span into ``tracer`` for every run of the collector
+    of ``GC_MIN_S`` or more from now on, for as long as the tracer lives."""
+    _gc_watchers.append(weakref.ref(tracer, _gc_watchers.remove))
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def validate_chrome_trace(trace) -> list[str]:
@@ -799,7 +891,6 @@ class Telemetry:
         self.events: deque = deque(maxlen=int(flight_last))
         self.ledger = None
         self.hists: dict[str, LatencyHistogram] = {}
-        self.counters: dict[str, int] = {}
         self._gauge_last: dict = {}
         self._gauge_max: dict = {}
         self._gauge_samples = 0
@@ -811,6 +902,7 @@ class Telemetry:
                 self.tracer.enabled = False
                 return
             self.tracer.on_close = self._on_span_close
+            watch_gc(self.tracer)
             self.ledger = GoodputLedger(
                 os.path.join(out_dir, stamped(goodput_file, process_index)),
                 attempt=attempt, clock=wall_clock,
@@ -857,7 +949,7 @@ class Telemetry:
 
     # -- hooks (all no-ops when disabled) -----------------------------------
 
-    def span(self, name: str, **args):
+    def span(self, name: str, /, **args):
         if not self.enabled:
             return NULL_SPAN
         return self.tracer.span(name, **args)
@@ -896,14 +988,6 @@ class Telemetry:
         """Mirror one emit-stream record into the flight-recorder ring."""
         if self.enabled:
             self.events.append(record)
-
-    def count(self, name: str, n: int = 1) -> None:
-        """Bump a named monotonic counter (worker_restarts,
-        requests_retried, stale_frames, ...) — the resilience tallies the
-        fleet report reads from the stats record without replaying the
-        event stream."""
-        if self.enabled:
-            self.counters[name] = self.counters.get(name, 0) + int(n)
 
     def record_exe(self, name: str, compiled=None, **kw) -> None:
         if self.enabled:
@@ -964,7 +1048,6 @@ class Telemetry:
             "attempt": self.attempt,
             "histograms": {k: h.to_dict()
                            for k, h in sorted(self.hists.items())},
-            "counters": dict(sorted(self.counters.items())),
             "gauges": {
                 "samples": self._gauge_samples,
                 "last": dict(self._gauge_last),

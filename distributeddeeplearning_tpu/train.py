@@ -933,6 +933,7 @@ class Trainer:
             }
         return loss, (metrics, updates)
 
+    @jax.named_scope("optimizer")  # every step variant updates through here
     def _tx_update(self, grads, opt_state, params):
         """Optimizer update; the fused Pallas AdamW runs under ``shard_map``.
 
@@ -1871,6 +1872,11 @@ def fit(
         health.max_consecutive_anomalies if health is not None else 0
     )
     tel = telemetry if telemetry is not None else NULL_TELEMETRY
+    if tel.enabled:
+        # One clock: every span is also an event on the host plane of any
+        # jax profile taken meanwhile (train.profile_steps), beside the
+        # device ops.
+        tel.tracer.annotate = jax.profiler.TraceAnnotation
     ledger = tel.ledger
     attempt = int(os.environ.get(ATTEMPT_ENV, "0") or 0)
 

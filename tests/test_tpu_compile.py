@@ -15,6 +15,9 @@ topology in-process. The persistent compilation cache is off around these
 compiles: a deviceless entry can be written but not read back.
 """
 
+import re
+
+import numpy as np
 import pytest
 
 import jax
@@ -233,3 +236,165 @@ def test_int8_grad_comm_step_lowers_on_four_chips(topo):
         "TPU lowering of the quantized step has no ring permutes"
     )
     assert "s8[" in text
+
+
+# ---------------------------------------------------------------------------
+# names on the device (docs/OBSERVABILITY.md): what a profile, and the
+# benchmark's per-kernel readers, find the kernels and programs by
+# ---------------------------------------------------------------------------
+
+
+def _kernel_names(text: str) -> set:
+    """The instruction names of the Mosaic custom calls in compiled HLO
+    text, less their number: ``%flash_fwd.1 = ... custom-call(...)
+    custom_call_target="tpu_custom_call"`` -> ``flash_fwd``."""
+    names = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text
+    )
+    return {re.sub(r"\.\d+$", "", n) for n in names}
+
+
+def _untransformed(names: set) -> set:
+    """jax wraps the scope next inside a transform in the transform's name
+    (``transpose(jvp(flash_bwd_dq))``, spelt ``transpose_jvp_flash_bwd_dq__``
+    in an instruction's name). In a model the scope next inside is the
+    model's outermost, far from the kernel; differentiated bare, as here,
+    it is the kernel's own."""
+    return {re.sub(r"^(?:transpose_|jvp_)+(.*?)_+$", r"\1", n) for n in names}
+
+
+def _sq_mean(fn):
+    return lambda *a: jnp.mean(fn(*a).astype(jnp.float32) ** 2)
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _kernel_case(case: str, topo, one_chip):
+    """(function, abstract arguments) whose compiled text holds the case's
+    kernels."""
+    if case == "flash_forward":
+        return _flash, _qkv(one_chip)
+    if case == "flash_backward":
+        return jax.grad(_sq_mean(_flash), argnums=(0, 1, 2)), _qkv(one_chip)
+    if case.startswith("ring"):
+        mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+
+        def ring(q, k, v):
+            return ring_attention_pallas(
+                q, k, v, mesh, causal=True, interpret=False
+            )
+
+        fn = ring if case == "ring_forward" else jax.grad(
+            _sq_mean(ring), argnums=(0, 1, 2)
+        )
+        return fn, _qkv(NamedSharding(mesh, P()))
+    if case == "fused_adamw":
+        params = {
+            "w": jax.ShapeDtypeStruct((E, 3 * E), jnp.float32,
+                                      sharding=one_chip)
+        }
+        tx = fused_adamw(1e-3, weight_decay=0.1, interpret=False)
+        return (lambda p, g: tx.update(g, tx.init(p), p)), [params, params]
+    assert case == "paged_decode", case
+    B, NB, BS, pages = 8, 512, 16, 64
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip
+    )
+    pool = S((NB, BS, H, D), jnp.bfloat16)
+    return (
+        lambda q, pk, pv, table, lens: paged_attention(
+            q, pk, pv, table, lens, interpret=False
+        ),
+        [S((B, H, D), jnp.bfloat16), pool, pool, S((B, pages), jnp.int32),
+         S((B,), jnp.int32)],
+    )
+
+
+@pytest.mark.parametrize("case,names", [
+    ("flash_forward", {"flash_fwd"}),
+    ("flash_backward", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    ("ring_forward", {"ring_fwd"}),
+    ("ring_backward", {"ring_fwd", "ring_bwd_dq", "ring_bwd_dkv"}),
+    ("fused_adamw", {"fused_adamw"}),
+    ("paged_decode", {"paged_decode"}),
+])
+def test_kernel_instruction_carries_its_name(topo, one_chip, case, names):
+    # The name a kernel has in a device trace is its instruction's: with no
+    # ``name=`` it is the caller's scope (``attn``, ``step_fn``), which a
+    # refactor of the caller changes under every reader's feet.
+    fn, args = _kernel_case(case, topo, one_chip)
+    got = _kernel_names(_compiled_text(fn, *args))
+    assert _untransformed(got) == names
+    assert all("flash" in n for n in got) or "flash" not in case
+
+
+def test_step_program_keeps_its_names_and_scopes(topo, monkeypatch):
+    # The flagship's step (flash attention, chunked head, fused AdamW) at
+    # a small depth and width, head size 64. The kernels ask
+    # jax.default_backend(), which is the CPU here, so they are steered to
+    # Mosaic from outside.
+    import sys
+
+    for mod in ("flash_attention", "fused_adamw"):
+        monkeypatch.setattr(
+            sys.modules[f"distributeddeeplearning_tpu.ops.{mod}"],
+            "_default_interpret", lambda: False,
+        )
+    mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
+    model = models.get_model(
+        "gpt2", size="tiny", num_heads=2, embed_dim=128, vocab_size=512,
+        max_len=256, dropout_rate=0.0, attn_impl="flash", chunked_head=True,
+    )
+    ds = data_lib.SyntheticTokens(
+        batch_size=2, seq_len=256, vocab_size=512, seed=0
+    )
+    trainer = Trainer(
+        model, make_optimizer("adamw_fused", 1e-3, weight_decay=0.1),
+        get_task("lm"), mesh, donate=False,
+    )
+    lowered = trainer.lower_train_step(ds.batch(0))
+    assert "module @jit_step_fn " in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.startswith("HloModule jit_step_fn")
+    # In a model the kernels' names come out whole (no ``attn``, no
+    # ``step_fn``): the families of a traced run's device_ops.
+    assert _kernel_names(text) == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "fused_adamw"
+    }
+    # Scopes reach op_name only (XLA's own instructions keep their opcode
+    # names): an operator's profile splits the step by them.
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("optimizer", "chunked_xent"):
+        assert any(
+            re.search(rf"[/(]{scope}[/)]", o) for o in ops
+        ), scope
+    assert any("/optimizer/fused_adamw" in o for o in ops)
+
+
+def test_engine_programs_keep_their_names():
+    # The names a device trace shows for the engine's runs (its
+    # ``XLA Modules`` line) are those of the jitted methods; the backend
+    # does not enter into them, so the CPU's lowering pins them.
+    from distributeddeeplearning_tpu.config import ServingConfig
+    from distributeddeeplearning_tpu.serving import ServingEngine
+
+    model = models.get_model("gpt2", size="tiny", vocab_size=97, max_len=64)
+    params = model.init(
+        jax.random.PRNGKey(7), np.zeros((1, 8), np.int32)
+    )["params"]
+    eng = ServingEngine(model, params, ServingConfig(
+        slots=2, block_size=4, hbm_budget_mb=8, max_seq_len=48,
+        prompt_buckets=(8,), speculation="ngram:2",
+    ))
+    programs = {
+        "_decode_fn": eng._decode_exe_or_compile(),
+        "_verify_fn": eng._verify_exe_or_compile(),
+        "_prefill_fn": eng._prefill_exe_for(8),
+    }
+    for name, exe in programs.items():
+        text = exe.as_text()
+        assert text.startswith(f"HloModule jit_{name}"), text[:80]
+        if name != "_verify_fn":  # verify is greedy: it samples nothing
+            assert re.search(r'op_name="[^"]*/sample/', text), name
