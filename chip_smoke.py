@@ -423,8 +423,9 @@ def phase_kernels(*, heads: int, head_dim: int, seq: int, pool_blocks: int,
     for tag, G, R in (("mha", heads, 1), ("gqa", max(1, heads // 2), 2)):
         ks = jax.random.split(jax.random.fold_in(key, G), 3)
         q = jax.random.normal(ks[0], (B, G * R, head_dim), jnp.bfloat16)
-        pk = jax.random.normal(ks[1], (pool_blocks, BS, G, head_dim))
-        pv = jax.random.normal(ks[2], (pool_blocks, BS, G, head_dim))
+        # as the engine stores them: heads folded into the minor dim
+        pk = jax.random.normal(ks[1], (pool_blocks, BS, G * head_dim))
+        pv = jax.random.normal(ks[2], (pool_blocks, BS, G * head_dim))
         rng = np.random.default_rng(seed)
         table = jnp.asarray(
             rng.permutation(pool_blocks)[: B * pages].reshape(B, pages),
@@ -447,7 +448,7 @@ def phase_kernels(*, heads: int, head_dim: int, seq: int, pool_blocks: int,
         qk, sk = block_quantize(pk.reshape(-1), head_dim)
         qv, sv = block_quantize(pv.reshape(-1), head_dim)
         qk, qv = qk.reshape(pk.shape), qv.reshape(pv.shape)
-        sk, sv = sk.reshape(pk.shape[:3]), sv.reshape(pv.shape[:3])
+        sk, sv = (s.reshape(pool_blocks, BS, G) for s in (sk, sv))
         q8 = jax.jit(lambda *a: paged_attention(
             *a[:5], scale_k=a[5], scale_v=a[6], num_rep=R,
             interpret=interpret,

@@ -137,9 +137,19 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     fixed **block pool** shared by all in-flight sequences — ``kv_pages =
     (num_blocks, block_size, pages_per_seq)``:
 
-    - ``pool_key`` / ``pool_value``: [num_blocks, block_size, kv_heads, D],
+    - ``pool_key`` / ``pool_value``: [num_blocks, block_size, kv_heads*D],
       one pool per layer, batch-independent — the SAME pool arrays serve the
-      B=1 prefill graph and the B=slots decode graph;
+      B=1 prefill graph and the B=slots decode graph. Heads are folded
+      into the minor dimension because of how the TPU tiles an array: a
+      bf16 tile is (16, 128), so minor dims (kv_heads, D) = (12, 64) pad
+      to (16, 128), 2.7x the bytes, and the runtime instead stores such
+      a leaf with the BLOCK index minor-most — a layout no scatter or
+      gather can use, so every program re-laid the whole pool out on
+      every call (PERF.md §6, PR 25). kv_heads*D is a multiple of 128 at
+      every served width (768, 1024, 512) and block_size=16 rows is one
+      tile: no padding, the declared order is kept, a block is one
+      contiguous run, and the scatter and the gather below take the
+      array as it lies. Heads are split only on the gathered result;
     - ``page_table``: [B, pages_per_seq] int32 — row b's logical block j
       lives in physical pool block ``page_table[b, j]`` (allocation is
       host-side: serving/scheduler.KVBlockPool);
@@ -201,7 +211,11 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     Pallas kernel inline in VMEM per page DMA (``ops/paged_attention``).
     Scale overhead is 4/D bytes per int8 KV byte (~6%% at D=64), so one
     fp32 pool block's budget holds ~3.8x more int8 tokens — the engine's
-    sizing probe measures the real ratio.
+    sizing probe measures the real ratio. The scale pools keep their
+    head-minor shape, which the TPU runtime stores block-index-minor and
+    each program still re-lays out (that 6%% only): with the block index
+    first, no shape is tile-dense for block_size*kv_heads scales a block
+    (PERF.md §7).
     """
     if kernel not in ("reference", "pallas"):
         raise ValueError(
@@ -215,11 +229,11 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     num_blocks, bs, pages = kv_pages
     B, L, Hkv, D = k.shape
     pk = module.variable(
-        "cache", "pool_key", jnp.zeros, (num_blocks, bs, Hkv, D),
+        "cache", "pool_key", jnp.zeros, (num_blocks, bs, Hkv * D),
         jnp.int8 if quantized else k.dtype,
     )
     pv = module.variable(
-        "cache", "pool_value", jnp.zeros, (num_blocks, bs, Hkv, D),
+        "cache", "pool_value", jnp.zeros, (num_blocks, bs, Hkv * D),
         jnp.int8 if quantized else v.dtype,
     )
     sk = sv = None
@@ -255,25 +269,23 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
         # D-vector (block_size=D), so the scale for a slot is final the
         # moment its KV lands and scatters to the SAME flat index as the
         # int8 values.
-        qk, k_scale = block_quantize(
+        k_w, k_scale = block_quantize(
             k.astype(jnp.float32).reshape(-1), D
         )
-        qv, v_scale = block_quantize(
+        v_w, v_scale = block_quantize(
             v.astype(jnp.float32).reshape(-1), D
         )
-        k_w = qk.reshape(B * L, Hkv, D)
-        v_w = qv.reshape(B * L, Hkv, D)
         sk.value = sk.value.reshape(num_blocks * bs, Hkv).at[flat].set(
             k_scale.reshape(B * L, Hkv)
         ).reshape(sk.value.shape)
         sv.value = sv.value.reshape(num_blocks * bs, Hkv).at[flat].set(
             v_scale.reshape(B * L, Hkv)
         ).reshape(sv.value.shape)
-    pk.value = pk.value.reshape(num_blocks * bs, Hkv, D).at[flat].set(
-        k_w.reshape(B * L, Hkv, D)
+    pk.value = pk.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
+        k_w.reshape(B * L, Hkv * D)
     ).reshape(pk.value.shape)
-    pv.value = pv.value.reshape(num_blocks * bs, Hkv, D).at[flat].set(
-        v_w.reshape(B * L, Hkv, D)
+    pv.value = pv.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
+        v_w.reshape(B * L, Hkv * D)
     ).reshape(pv.value.shape)
     if kernel == "pallas" and L == 1:
         from ..ops.paged_attention import paged_attention
@@ -285,7 +297,8 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
             scale_v=sv.value if quantized else None,
         )[:, None]
     else:
-        # Gather each row's pages into logical order: [B, pages*bs, Hkv, D].
+        # Gather each row's pages into logical order, whole lane-dense
+        # blocks, and split heads on the result: [B, pages*bs, Hkv, D].
         ck = pk.value[table.value].reshape(B, pages * bs, Hkv, D)
         cv = pv.value[table.value].reshape(B, pages * bs, Hkv, D)
         if quantized:

@@ -18,19 +18,25 @@ Layout (see pallas_guide.md and ops/flash_attention.py, the idiom seed):
   table and the per-row cursors are scalar operands available to BOTH the
   index_maps (physical block selection) and the kernel body (causal
   masking at the row's cursor);
-- one grid step takes a WHOLE page, all kv heads: the block
-  ``(1, block_size, kv_heads, D)`` over the ``[NB, BS, G, D]`` pool has
-  its last two dims equal to the array's, which is what the TPU lowering
-  requires of a block narrower than the (8, 128) tile (a block of 1 on
-  the kv-head dim is refused by Mosaic at every width this repo serves);
-- the page stays in its ``(slot, head, D)`` VMEM layout and the math is
-  VPU broadcast-multiply + reduce, no per-head slicing and no MXU: a
-  one-token decode is an M=1 matmul per head, DMA-bound either way;
+- one grid step takes a WHOLE page as it is stored: the block
+  ``(1, block_size, kv_heads*D)`` over the ``[NB, BS, G*D]`` pool has its
+  last two dims equal to the array's, and they fill the (8, 128) tile with
+  no padding (G*D is 768, 1024 or 512 here; 16 rows are one bf16 tile), so
+  the DMA is one contiguous lane-dense run — 24 KB at GPT-2 124M. The
+  pool keeps heads folded into lanes because a ``(G, D) = (12, 64)`` minor
+  pair pads 2.7x and makes the runtime store the block index minor-most
+  (``transformer.paged_decode_attention`` says what that cost);
+- heads are split on the page in VMEM by static lane slices (Mosaic
+  refuses the ``(BS, G*D) -> (BS, G, D)`` shape cast at D=64): one
+  lane-dense multiply ``k * q`` per rep, then per head a lane reduce of
+  its D-wide slice. The softmax state is then head-vectorised, scores
+  ``(BS, G)`` and carries ``(1, G)``. No MXU: a one-token decode is an
+  M=1 matmul per head, DMA-bound either way;
 - GQA: q arrives group-major (query head ``g*num_rep + r`` reads kv
   group ``g``, matching ``transformer._cache_attend``) and is handed to
-  the kernel as ``[B, num_rep, kv_heads, D]`` — rep ``r``'s ``(G, D)``
-  slab lines up with the page's head dim, so the pool is never repeated
-  to the query head count;
+  the kernel as ``[B, num_rep, kv_heads*D]`` — rep ``r``'s row lines up
+  with the page's lanes, so the pool is never repeated to the query head
+  count;
 - pages entirely beyond a row's cursor are skipped with ``pl.when`` (no
   VPU work on the accumulate path); the cursor page is masked per slot
   with ``broadcasted_iota``;
@@ -53,8 +59,10 @@ adds two ``(1, block_size, kv_heads)`` BlockSpec operands whose index_maps
 follow the SAME ``page_table[b, j]`` indirection — the per-page DMA pulls
 the int8 page AND its scale rows into VMEM together, and the dequant
 (``values.astype(f32) * scale``, the ``comms_quant`` codec inverse) is
-fused inline before the online-softmax dot. The fp32 carries (m, l, acc)
-are unchanged, so the only numerics delta vs the fp kernel is the
+fused inline: a scale is constant over its head's D lanes, so it
+multiplies the (slot, head) score and probability instead of the page
+(``sum_d(k*s*q) = s*sum_d(k*q)``). The fp32 carries (m, l, acc) are
+unchanged, so the only numerics delta vs the fp kernel is the
 quantization grid itself.
 """
 
@@ -69,7 +77,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # finite: exp(_NEG_INF - m) == 0 exactly, no inf-inf NaNs
-_LANES = 128
 
 
 def _default_interpret() -> bool:
@@ -78,20 +85,32 @@ def _default_interpret() -> bool:
 
 def _decode_kernel(
     table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-    sm_scale, block_size, num_pages, quantized,
+    sm_scale, block_size, num_pages, kv_heads, quantized,
 ):
-    """One (row, page) grid step. ``q_ref`` (1, R, G, D); ``k_ref`` /
-    ``v_ref`` (1, BS, G, D); quantized pools add ``sk_ref`` / ``sv_ref``
-    (1, BS, G) — the page's scale rows, fetched by the same ``tbl[b, j]``
-    index_map as the page and applied in VMEM (``q.astype(f32) * scale``)
-    right after the DMA. Carries per rep: m, l (G, LANES), acc (G, D)."""
+    """One (row, page) grid step. ``q_ref`` (1, R, G*D); ``k_ref`` /
+    ``v_ref`` (1, BS, G*D), the page as stored; quantized pools add
+    ``sk_ref`` / ``sv_ref`` (1, BS, G) — the page's scale rows, fetched by
+    the same ``tbl[b, j]`` index_map as the page. Carries per rep: m, l
+    (1, G), acc (1, G*D)."""
     if quantized:
         sk_ref, sv_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
-    num_rep, kv_heads = q_ref.shape[1], q_ref.shape[2]
+    num_rep = q_ref.shape[1]
+    head_dim = q_ref.shape[2] // kv_heads
+
+    def head(x, g):  # head g's D lanes of a [.., G*D] value
+        return x[:, g * head_dim:(g + 1) * head_dim]
+
+    def per_head(fn):  # [.., G*n] from fn(g) -> [.., n]
+        return jnp.concatenate([fn(g) for g in range(kv_heads)], axis=1)
+
+    def over_lanes(x):  # (1, G) -> (1, G*D): head g's value on its lanes
+        return per_head(
+            lambda g: jnp.broadcast_to(x[:, g:g + 1], (1, head_dim))
+        )
 
     @pl.when(j == 0)
     def _init():
@@ -104,43 +123,47 @@ def _decode_kernel(
     # Pages strictly beyond the cursor hold no visible columns — skip.
     @pl.when(j * block_size <= pos)
     def _page():
-        k = k_ref[0].astype(jnp.float32)  # (BS, G, D)
+        k = k_ref[0].astype(jnp.float32)  # (BS, G*D)
         v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            k = k * sk_ref[0][:, :, None]
-            v = v * sv_ref[0][:, :, None]
         col = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_size, kv_heads, 1), 0
+            jnp.int32, (block_size, 1), 0
         )
         for r in range(num_rep):
-            q = q_ref[0, r].astype(jnp.float32) * sm_scale  # (G, D)
-            # keepdims: scores stay in the page's (slot, head, lane)
-            # layout, so nothing is relaid out between the two reduces.
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (BS, G, 1)
+            q = q_ref[0, r:r + 1].astype(jnp.float32) * sm_scale  # (1, G*D)
+            kq = k * q
+            s = per_head(  # (BS, G)
+                lambda g: jnp.sum(head(kq, g), axis=-1, keepdims=True)
+            )
+            if quantized:
+                s = s * sk_ref[0]
             s = jnp.where(col <= pos, s, _NEG_INF)
-            m_prev = m_scr[r][:, :1]  # (G, 1)
-            l_prev = l_scr[r][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            p = jnp.exp(s - m_new[None])
+            m_prev = m_scr[r]  # (1, G)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=0)
-            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
-            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            m_scr[r] = m_new
+            pw = p * sv_ref[0] if quantized else p
+            pv = per_head(  # (1, G*D)
+                lambda g: jnp.sum(
+                    pw[:, g:g + 1] * head(v, g), axis=0, keepdims=True
+                )
+            )
+            acc_scr[r] = acc_scr[r] * over_lanes(alpha) + pv
 
     @pl.when(j == num_pages - 1)
     def _finalize():
         for r in range(num_rep):
-            l = jnp.maximum(l_scr[r][:, :1], 1e-30)
-            o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
+            l = over_lanes(jnp.maximum(l_scr[r], 1e-30))
+            o_ref[0, r:r + 1] = (acc_scr[r] / l).astype(o_ref.dtype)
 
 
-def _check_scales(pool_k, scale_k, scale_v):
+def _check_scales(pool_k, scale_k, scale_v, kv_heads):
     """Validate the quantized-pool operand set: int8 pools require BOTH
     scale pools with the pool's (num_blocks, block_size, kv_heads)
     layout; fp pools must not carry scales (a silently ignored scale
     buffer is a caller bug). Returns True when the pool is quantized."""
-    num_blocks, block_size, kv_heads, _ = pool_k.shape
+    num_blocks, block_size, _ = pool_k.shape
     quantized = pool_k.dtype == jnp.int8
     if not quantized:
         if scale_k is not None or scale_v is not None:
@@ -176,8 +199,9 @@ def paged_attention(
 
     - ``q``: [B, H, D] — ONE query token per row, heads group-major over
       kv groups (H = kv_heads * num_rep);
-    - ``pool_k`` / ``pool_v``: [num_blocks, block_size, kv_heads, D] —
-      the shared block pool (un-repeated kv under GQA);
+    - ``pool_k`` / ``pool_v``: [num_blocks, block_size, kv_heads*D] —
+      the shared block pool as the engine stores it, heads folded into
+      lanes (un-repeated kv under GQA: kv_heads = H // num_rep);
     - ``page_table``: [B, pages_per_seq] int32 — row b's logical page j
       lives in physical pool block ``page_table[b, j]``. Every entry must
       be a valid block id; out-of-range ids read whatever block the DMA
@@ -195,15 +219,17 @@ def paged_attention(
     interpret mode off-TPU (the CPU test harness).
     """
     B, H, D = q.shape
-    num_blocks, block_size, kv_heads, Dk = pool_k.shape
-    if pool_v.shape != pool_k.shape:
+    if pool_k.ndim != 3 or pool_v.shape != pool_k.shape:
         raise ValueError(
-            f"pool_k/pool_v shapes differ: {pool_k.shape} {pool_v.shape}"
+            f"pool_k/pool_v must share one [NB,bs,kv_heads*D] shape: "
+            f"{pool_k.shape} {pool_v.shape}"
         )
-    if Dk != D or H != kv_heads * num_rep:
+    num_blocks, block_size, width = pool_k.shape
+    kv_heads = H // num_rep
+    if H != kv_heads * num_rep or width != kv_heads * D:
         raise ValueError(
             f"q [B,H,D]={q.shape} incompatible with pool "
-            f"[NB,bs,kv_heads,D]={pool_k.shape} at num_rep={num_rep}"
+            f"[NB,bs,kv_heads*D]={pool_k.shape} at num_rep={num_rep}"
         )
     num_pages = page_table.shape[-1]
     if page_table.shape != (B, num_pages) or seq_lens.shape != (B,):
@@ -215,26 +241,27 @@ def paged_attention(
         sm_scale = float(1.0 / np.sqrt(D))
     if interpret is None:
         interpret = _default_interpret()
-    quantized = _check_scales(pool_k, scale_k, scale_v)
+    quantized = _check_scales(pool_k, scale_k, scale_v, kv_heads)
 
     # Group-major head fold: head g*num_rep+r -> (group g, rep r), then
-    # rep-major so each rep's (G, D) slab matches the page's head dim.
-    q4 = q.reshape(B, kv_heads, num_rep, D).transpose(0, 2, 1, 3)
+    # rep-major so each rep's G*D row matches the page's lanes.
+    q3 = q.reshape(B, kv_heads, num_rep, D).transpose(0, 2, 1, 3).reshape(
+        B, num_rep, width
+    )
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, block_size=block_size,
-        num_pages=num_pages, quantized=quantized,
+        num_pages=num_pages, kv_heads=kv_heads, quantized=quantized,
     )
     q_spec = pl.BlockSpec(
-        (1, num_rep, kv_heads, D), lambda b, j, tbl, lens: (b, 0, 0, 0)
+        (1, num_rep, width), lambda b, j, tbl, lens: (b, 0, 0)
     )
     # The paged reads: physical block (and, quantized, its scale rows)
     # straight off the scalar-prefetched table.
     page_spec = pl.BlockSpec(
-        (1, block_size, kv_heads, D),
-        lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0),
+        (1, block_size, width), lambda b, j, tbl, lens: (tbl[b, j], 0, 0),
     )
     in_specs = [q_spec, page_spec, page_spec]
-    operands = [q4, pool_k, pool_v]
+    operands = [q3, pool_k, pool_v]
     if quantized:
         scale_spec = pl.BlockSpec(
             (1, block_size, kv_heads),
@@ -248,22 +275,24 @@ def paged_attention(
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((num_rep, kv_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_rep, kv_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_rep, kv_heads, D), jnp.float32),
+            pltpu.VMEM((num_rep, 1, kv_heads), jnp.float32),
+            pltpu.VMEM((num_rep, 1, kv_heads), jnp.float32),
+            pltpu.VMEM((num_rep, 1, width), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, num_rep, kv_heads, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, num_rep, width), q.dtype),
         name="paged_decode",
         interpret=interpret,
     )(
         jnp.asarray(page_table, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
         *operands,
     )
-    return out.transpose(0, 2, 1, 3).reshape(B, H, D)
+    return out.reshape(B, num_rep, kv_heads, D).transpose(
+        0, 2, 1, 3
+    ).reshape(B, H, D)
 
 
 def paged_attention_reference(q, pool_k, pool_v, page_table, seq_lens, *,
@@ -277,10 +306,12 @@ def paged_attention_reference(q, pool_k, pool_v, page_table, seq_lens, *,
     rows — the same dequant-on-gather lowering the engine ships.
     """
     B, H, D = q.shape
-    nb, bs, kv_heads, _ = pool_k.shape
+    nb, bs, _ = pool_k.shape
+    kv_heads = H // num_rep
     pages = page_table.shape[-1]
-    quantized = _check_scales(pool_k, scale_k, scale_v)
-    pool_k, pool_v = pool_k.astype(jnp.float32), pool_v.astype(jnp.float32)
+    quantized = _check_scales(pool_k, scale_k, scale_v, kv_heads)
+    pool_k = pool_k.astype(jnp.float32).reshape(nb, bs, kv_heads, D)
+    pool_v = pool_v.astype(jnp.float32).reshape(nb, bs, kv_heads, D)
     if quantized:
         pool_k = pool_k * scale_k[..., None]
         pool_v = pool_v * scale_v[..., None]

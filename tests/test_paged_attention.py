@@ -50,10 +50,12 @@ def _pool_case(key, *, B, kv_heads, num_rep, D, num_blocks, block_size,
         table[b, :need] = perm[used:used + need]
         used += need
     assert used <= perm.size, "test case over-allocated the pool"
+    # As the engine stores it: heads folded into the minor dimension.
+    folded = (num_blocks, block_size, kv_heads * D)
     return (
         q.astype(dtype),
-        pool_k.astype(dtype),
-        pool_v.astype(dtype),
+        pool_k.astype(dtype).reshape(folded),
+        pool_v.astype(dtype).reshape(folded),
         jnp.asarray(table),
         jnp.asarray(np.asarray(lens, np.int32)),
     )
@@ -130,11 +132,12 @@ def test_scattered_table_vs_contiguous_same_logical_sequence():
     lens = jnp.asarray([19], jnp.int32)
 
     def build(block_ids):
-        pool_k = jnp.zeros((8, bs, kv_heads, D))
-        pool_v = jnp.zeros((8, bs, kv_heads, D))
+        pool_k = jnp.zeros((8, bs, kv_heads * D))
+        pool_v = jnp.zeros((8, bs, kv_heads * D))
         for j, blk in enumerate(block_ids):
-            pool_k = pool_k.at[blk].set(logical_k[j * bs:(j + 1) * bs])
-            pool_v = pool_v.at[blk].set(logical_v[j * bs:(j + 1) * bs])
+            rows = slice(j * bs, (j + 1) * bs)
+            pool_k = pool_k.at[blk].set(logical_k[rows].reshape(bs, -1))
+            pool_v = pool_v.at[blk].set(logical_v[rows].reshape(bs, -1))
         table = jnp.asarray([block_ids], jnp.int32)
         return paged_attention(q, pool_k, pool_v, table, lens)
 
@@ -162,21 +165,22 @@ def test_shape_validation_fails_loudly():
 # ---------------------------------------------------------------------------
 
 
-def _quantize_pool(pool):
+def _quantize_pool(pool, d):
     """Per-(slot, head) D-vector absmax int8 quantization — the same
     layout transformer.paged_decode_attention writes: one f32 scale per
-    written (token, head) vector, so scales are [num_blocks, bs, H]."""
+    written (token, head) vector, so scales are [num_blocks, bs, H]
+    beside the [num_blocks, bs, H*D] int8 pool."""
     from distributeddeeplearning_tpu.comms_quant import block_quantize
 
-    nb, bs, h, d = pool.shape
+    nb, bs, width = pool.shape
     q, s = block_quantize(jnp.asarray(pool, jnp.float32).reshape(-1), d)
-    return q.reshape(nb, bs, h, d), s.reshape(nb, bs, h)
+    return q.reshape(nb, bs, width), s.reshape(nb, bs, width // d)
 
 
 def _quant_case(key, **kw):
     q, pk, pv, table, lens = _pool_case(key, **kw)
-    qk, sk = _quantize_pool(pk)
-    qv, sv = _quantize_pool(pv)
+    qk, sk = _quantize_pool(pk, kw["D"])
+    qv, sv = _quantize_pool(pv, kw["D"])
     return q, qk, qv, table, lens, sk, sv
 
 
@@ -207,8 +211,8 @@ def test_quantized_vs_fp_within_drift_tolerance():
     )
     q, pk, pv, table, lens = args
     fp = paged_attention(q, pk, pv, table, lens, num_rep=2)
-    qk, sk = _quantize_pool(pk)
-    qv, sv = _quantize_pool(pv)
+    qk, sk = _quantize_pool(pk, 32)
+    qv, sv = _quantize_pool(pv, 32)
     q8 = paged_attention(q, qk, qv, table, lens, num_rep=2,
                          scale_k=sk, scale_v=sv)
     assert float(jnp.max(jnp.abs(q8 - fp))) < 0.05

@@ -84,6 +84,58 @@ def test_engine_greedy_matches_generate(name):
         assert st.generated == list(ref[i]), f"request {i}"
 
 
+def _leaves_named(cache, names):
+    return [
+        leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if getattr(path[-1], "key", None) in names
+    ]
+
+
+@pytest.mark.parametrize("name", ["gpt2", "llama"])
+def test_pool_block_bytes_are_the_kv_written_for_it(name):
+    # A pool leaf is [num_blocks, block_size, kv_heads*head_dim] (heads
+    # folded into the minor dimension: transformer.paged_decode_attention
+    # says why), and a block's bytes in C order are the K/V of its
+    # block_size positions, head-major within a position. Pin both against
+    # the OTHER cache: what generate()'s contiguous [B, T, kv_heads, D]
+    # cache holds for the same prompt, read from the pool the way spill
+    # and handoff read it. Llama folds 4 query heads onto 2 kv heads: the
+    # pool is stored pre-repeat.
+    from distributeddeeplearning_tpu.generate import prefill
+
+    model, params = _model_and_params(name)
+    bs = _CFG.block_size
+    prompt = _prompts((11,))[0]
+    dec = model.clone(decode=True)
+    tokens = np.asarray([prompt], np.int32)
+    cache = dec.init(jax.random.PRNGKey(0), tokens)["cache"]
+    _, cache = prefill(dec, params, cache, tokens)
+    want = _leaves_named(cache, ("cached_key", "cached_value"))
+
+    eng = _engine(model, params)
+    st = eng.submit(Request(prompt=prompt, max_new_tokens=4))
+    eng.step()  # admit, prefill, one decode: the lane is still live
+    assert not st.done
+    leaves = eng._pool_leaves()
+    assert len(leaves) == len(want) > 0
+    _, _, kv_heads, head_dim = want[0].shape
+    for leaf in leaves:
+        assert leaf.shape == (eng.num_blocks, bs, kv_heads * head_dim)
+    blocks = [int(b) for b in eng._table[st.slot, :len(prompt) // bs]]
+    assert 0 not in blocks  # the null block holds no request's KV
+    eng._spill_out([(b, bytes([j])) for j, b in enumerate(blocks)])
+    for j in range(len(blocks)):
+        codec, rows = eng._spill_store[bytes([j])]
+        assert codec == "fp"
+        for row, ref in zip(rows, want):
+            assert row.shape == (bs, kv_heads * head_dim)
+            np.testing.assert_allclose(
+                row,
+                np.asarray(ref[0, j * bs:(j + 1) * bs]).reshape(bs, -1),
+                rtol=1e-5, atol=1e-6,
+            )
+
+
 def test_mid_flight_join_uses_freed_slot_and_blocks():
     model, params = _model_and_params("gpt2")
     cfg = dataclasses.replace(_CFG, slots=2)
@@ -639,6 +691,12 @@ def test_int8_pool_compile_pin_and_cache_dtype():
     assert leaves["pool_value"].dtype == jnp.int8
     assert leaves["pool_key_scale"].dtype == jnp.float32
     assert leaves["pool_value_scale"].dtype == jnp.float32
+    # int8 values lane-dense like the fp pool; one scale per (slot, head).
+    nb, bs = eng.num_blocks, _CFG.block_size
+    heads, head_dim = model.num_heads, model.embed_dim // model.num_heads
+    for kv in ("pool_key", "pool_value"):
+        assert leaves[kv].shape == (nb, bs, heads * head_dim)
+        assert leaves[kv + "_scale"].shape == (nb, bs, heads)
 
 
 def test_int8_pool_pallas_matches_reference_engine():

@@ -146,7 +146,7 @@ def test_paged_attention_compiles(one_chip, layout, quantized):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = S((NB, BS, G, D), jnp.int8 if quantized else jnp.bfloat16)
+    pool = S((NB, BS, G * D), jnp.int8 if quantized else jnp.bfloat16)
     args = [S((B, G * R, D), jnp.bfloat16), pool, pool,
             S((B, pages), jnp.int32), S((B,), jnp.int32)]
     if quantized:
@@ -159,6 +159,72 @@ def test_paged_attention_compiles(one_chip, layout, quantized):
         )
 
     _assert_kernel(_compiled_text(fn, *args))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_programs_take_the_pool_as_it_lies(one_chip, program):
+    # The counter of the lane-dense pool (PERF.md §6, PR 25): a leaf whose
+    # minor dims pad badly is stored block-index-minor by the runtime, and
+    # every serving program then re-lays the whole pool out, three copies
+    # a leaf a call. It engages always or never and a CPU run cannot see
+    # it, so: the engine's OWN programs, with the engine's own operands
+    # and donation, at real head geometry (12 x 64), compiled for the chip.
+    from distributeddeeplearning_tpu.config import ServingConfig
+    from distributeddeeplearning_tpu.serving import ServingEngine
+
+    model = models.get_model(
+        "gpt2", size="124m", num_layers=2, vocab_size=512, max_len=256,
+        dtype=jnp.bfloat16,
+    )
+    params = model.init(
+        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
+    )["params"]
+    eng = ServingEngine(model, params, ServingConfig(
+        slots=8, block_size=16, hbm_budget_mb=24, max_seq_len=256,
+        prompt_buckets=(32,),
+    ))
+    assert eng.num_blocks == 256
+
+    def compile_for_chip(fn, *args, name=None, donate_argnums=()):
+        abstract = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), args,
+        )
+        return jax.jit(fn, donate_argnums=donate_argnums).lower(
+            *abstract
+        ).compile()
+
+    eng._compile = compile_for_chip
+    exe = (eng._decode_exe_or_compile() if program == "decode"
+           else eng._prefill_exe_for(32))
+    text = exe.as_text()
+    assert text.startswith(f"HloModule jit__{program}_fn")
+
+    leaves = eng._pool_leaves()
+    assert len(leaves) == 4  # K and V of two layers
+    assert {(leaf.shape, str(leaf.dtype)) for leaf in leaves} == {
+        ((256, 16, H * D), "bfloat16")
+    }
+    shape = rf"bf16\[256,16,{H * D}\]"
+    pool_params = dict(re.findall(
+        rf"%(\S*pool_(?:key|value)\S*) = {shape}\S* parameter\((\d+)\)", text
+    ))
+    assert len(pool_params) == 4, pool_params
+    # The declared order is the stored order: nothing to undo.
+    entry = re.findall(rf"{shape}\{{([\d,]+)", text.split("\n")[0])
+    assert entry and set(entry) == {"2,1,0"}, entry
+    copies = re.findall(rf"= {shape}\S* copy\(", text)
+    if program == "decode":
+        assert not copies, copies
+        aliased = set(re.findall(
+            r"\((\d+), \{\}, (?:may|must)-alias\)",
+            re.search(r"input_output_alias=\{(.*?\)) \}", text).group(1),
+        ))
+        assert set(pool_params.values()) <= aliased, (pool_params, aliased)
+    else:
+        # Prefill is not donated (engine._prefill_exe_for says why): the
+        # one plain copy of a leaf into its output may remain, no more.
+        assert len(copies) <= len(leaves), copies
 
 
 def test_chunked_xent_loss_and_grad_compile(one_chip):
@@ -302,7 +368,7 @@ def _kernel_case(case: str, topo, one_chip):
     S = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one_chip
     )
-    pool = S((NB, BS, H, D), jnp.bfloat16)
+    pool = S((NB, BS, H * D), jnp.bfloat16)
     return (
         lambda q, pk, pv, table, lens: paged_attention(
             q, pk, pv, table, lens, interpret=False
