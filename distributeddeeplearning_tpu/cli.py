@@ -11,7 +11,9 @@ import argparse
 import json
 import sys
 
+import flax.struct
 import jax
+import jax.numpy as jnp
 
 from . import data as data_lib
 from . import models
@@ -79,8 +81,6 @@ def build_all(cfg: Config, split: str = "train", devices=None,
         # The model's compute dtype is DERIVED from the policy — the two
         # knobs disagreeing would either waste the policy (model casts the
         # compute copy back up) or mislead the reader (dtype kwarg ignored).
-        import jax.numpy as jnp
-
         explicit = cfg.model.kwargs.get("dtype")
         if explicit is not None and jnp.dtype(explicit) != policy.compute_dtype:
             raise ValueError(
@@ -173,23 +173,31 @@ def make_eval_fn(cfg: Config, mesh, dataset=None):
     return eval_batches
 
 
+def _restore(cfg: Config, trainer, probe_batch, verb: str):
+    """The latest checkpoint's state, or None where
+    ``train.checkpoint_dir`` is unset or holds none."""
+    if not cfg.train.checkpoint_dir:
+        return None
+    from .checkpoint import CheckpointManager
+
+    ckpt = CheckpointManager(cfg.train.checkpoint_dir)
+    try:
+        if ckpt.latest_step() is None:
+            return None
+        trainer.setup(probe_batch)
+        state, _ = ckpt.restore(trainer.abstract_state_with_shardings())
+        print(f"{verb} checkpoint at step {int(state.step)}")
+        return state
+    finally:
+        ckpt.close()
+
+
 def _restore_or_init(cfg: Config, trainer, probe_batch, verb: str):
     """Latest checkpoint (when ``train.checkpoint_dir`` has one) or a fresh
     init — the shared preamble of every non-training subcommand."""
-    if cfg.train.checkpoint_dir:
-        from .checkpoint import CheckpointManager
-
-        ckpt = CheckpointManager(cfg.train.checkpoint_dir)
-        try:
-            if ckpt.latest_step() is not None:
-                trainer.setup(probe_batch)
-                state, _ = ckpt.restore(
-                    trainer.abstract_state_with_shardings()
-                )
-                print(f"{verb} checkpoint at step {int(state.step)}")
-                return state
-        finally:
-            ckpt.close()
+    state = _restore(cfg, trainer, probe_batch, verb)
+    if state is not None:
+        return state
     print(f"no checkpoint found — {verb} freshly initialized params")
     return trainer.init(cfg.train.seed, probe_batch)
 
@@ -308,14 +316,38 @@ def cmd_generate(cfg: Config, prompts: list[str], max_new_tokens: int,
     return 0
 
 
+@flax.struct.dataclass
+class ServedState:
+    """What a server keeps of a training state: the step it was saved at
+    and the parameters, in the dtype the model's config states."""
+
+    step: jax.Array
+    params: object
+
+
 def serving_model_and_state(cfg: Config, model, trainer, dataset):
     """What ``cmd_serve`` hands the engine, from ``build_all``'s outputs: the
-    restored (or freshly initialized) state, and the model cloned onto the
-    xla attention core on one program (the engine re-fences this; mirrors
-    cmd_generate). Shared with ``chip_smoke.py``, which serves token ids at
-    the published vocabulary that ``cmd_serve``'s byte-tokenizer fence
-    refuses."""
-    state = _restore_or_init(cfg, trainer, dataset.batch(0), "serving from")
+    restored (or freshly initialized) parameters, and the model cloned onto
+    the xla attention core on one program (the engine re-fences this;
+    mirrors cmd_generate). No optimizer state is built for a server: a
+    fresh start initializes the parameters alone (``Trainer.init_params``)
+    and a restore keeps them alone. Shared with ``chip_smoke.py`` and the
+    benchmark's serving driver, which serve token ids at the published
+    vocabulary that ``cmd_serve``'s byte-tokenizer fence refuses."""
+    probe = dataset.batch(0)
+    restored = _restore(cfg, trainer, probe, "serving from")
+    if restored is None:
+        print("no checkpoint found — serving from freshly initialized params")
+        state = ServedState(
+            step=jnp.zeros((), jnp.int32),
+            params=trainer.init_params(cfg.train.seed, probe),
+        )
+    else:
+        state = ServedState(step=restored.step, params=restored.params)
+        jax.tree.map(
+            lambda x: x.delete(),
+            (restored.opt_state, restored.grad_residual),
+        )
     updates = {}
     if hasattr(model, "attn_impl"):
         updates["attn_impl"] = "xla"

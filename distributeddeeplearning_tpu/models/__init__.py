@@ -31,4 +31,6 @@ def available() -> list[str]:
     return sorted(_REGISTRY)
 
 
-from . import bert, gpt2, llama, moe, pipeline, resnet, vit  # noqa: E402,F401
+from . import (  # noqa: E402,F401
+    bert, glm4_moe_lite, gpt2, llama, moe, pipeline, resnet, vit,
+)

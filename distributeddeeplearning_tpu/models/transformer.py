@@ -260,9 +260,7 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
         return attention_core(
             q, rep(k), rep(v), impl="xla", causal=True, dtype=dtype
         )
-    pos = lens.value[:, None] + jnp.arange(L)[None, :]  # [B, L] absolute
-    blk = jnp.take_along_axis(table.value, pos // bs, axis=1)
-    flat = (blk * bs + pos % bs).reshape(-1)
+    pos, flat = _page_writes(table.value, lens.value, L, bs)
     k_w, v_w = k, v
     if quantized:
         # Quantize-at-write: one comms_quant block per (token, head)
@@ -327,6 +325,162 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
         bad = ((table.value < 0) | (table.value >= num_blocks)).any(axis=1)
         out = jnp.where(bad[:, None, None, None], jnp.nan, out)
     lens.value = lens.value + L
+    return out
+
+
+def _page_writes(table, lens, L: int, bs: int):
+    """Where this call's L tokens of every row land: their absolute
+    positions [B, L] and flat slot indices ``block * bs + offset`` [B*L]
+    into a pool viewed as [num_blocks * bs, width]."""
+    pos = lens[:, None] + jnp.arange(L)[None, :]
+    blk = jnp.take_along_axis(table, pos // bs, axis=1)
+    return pos, (blk * bs + pos % bs).reshape(-1)
+
+
+# Queries attended at once by the latent paths below: a bucket of P
+# prompt tokens never holds more than [heads, chunk, keys] float32 scores.
+_LATENT_QUERY_CHUNK = 512
+
+
+def _in_query_chunks(fn, L: int, *per_query):
+    """``fn`` over ``per_query`` arrays ([B, L, ...]) a chunk of queries
+    at a time where L is long (results concatenated along L); one call
+    where it is short."""
+    chunk = _LATENT_QUERY_CHUNK
+    if L <= chunk or L % chunk:
+        return fn(*per_query)
+    split = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape(a.shape[0], L // chunk, chunk, *a.shape[2:]), 1, 0
+    )
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(split(a) for a in per_query))
+    out = jnp.moveaxis(out, 0, 1)
+    return out.reshape(out.shape[0], L, *out.shape[3:])
+
+
+def latent_paged_attention(module, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,
+                           *, dtype, kv_pages, lens_var):
+    """Multi-head LATENT attention (DeepSeek-V2/V3 MLA) against the paged
+    pool: the cache holds, per token and layer, the normed latent
+    ``c_kv`` [rank] and the one shared RoPE key ``k_rope`` [rope] — not
+    per-head K and V — so a token costs rank + rope values a layer
+    whatever the head count (576 against 20 x 512 at GLM-4.7-Flash).
+
+    - ``pool_latent``: [num_blocks, block_size, W], one leaf a layer
+      holding ``[c_kv | k_rope | 0...]``, written by the same
+      scatter-at-cursor and read by the same whole-block page gather as
+      ``paged_decode_attention``'s K and V (same ``page_table`` /
+      ``seq_lens`` leaves, same null block, same per-row cursors), shared
+      between the B=1 prefill and the B=slots decode programs and found by
+      name in the engine (``engine._POOL_LEAVES``). W is rank + rope
+      rounded up to whole lanes of 128 (576 -> 640 at GLM-4.7-Flash, 11%
+      of the pool): in a deviceless ``v5e:2x2`` compile of the engine's
+      programs a leaf of 576 is stored block-index-minor (``{0,2,1}``) and
+      copied whole twice a call, as PR 25's K and V were, and so is the
+      64-wide half of a ``[.., 512]`` + ``[.., 64]`` pair; the leaf of 640
+      keeps its declared order and the decode program copies none
+      (``tests/test_tpu_compile.py`` keeps that count).
+    - ``q_nope`` [B, L, H, nope], ``q_rope`` [B, L, H, rope] (rotated),
+      ``c_kv`` [B, L, rank], ``k_rope`` [B, L, rope] (rotated);
+      ``w_uk`` [rank, H, nope] and ``w_uv`` [rank, H, v] are the two
+      halves of the per-head up-projection ``kv_b``.
+
+    Two read forms, the same mathematics (scores over nope + rope dims):
+
+    - **expanded** — K and V of this call's own tokens are expanded per
+      head (``k_nope = c_kv w_uk``, ``v = c_kv w_uv``) and attended
+      causally among themselves; nothing is read from the pool. Right for
+      a prompt that starts at cursor 0 (bulk prefill), where it costs
+      2 x (nope + rope + v) FLOPs a (query, key, head) against the
+      absorbed form's 2 x (2 rank + rope).
+    - **absorbed** — ``w_uk`` is folded into the query
+      (``q_lat = q_nope w_uk^T`` [rank]) and ``w_uv`` applied after the
+      weighted sum (``o = (softmax c_kv) w_uv``), so the latent is read as
+      it lies, as one shared key/value head: right for decode (L == 1) and
+      for L > 1 at a nonzero cursor (the speculative verify forward,
+      suffix-only prefill under the prefix cache), which must see what the
+      pool holds below the cursor.
+
+    L == 1 compiles the absorbed form alone. L > 1 compiles both and picks
+    by the cursors at run time (all rows at 0: expanded), so one prefill
+    executable per bucket serves cold and suffix-only prefill alike, as
+    ``paged_decode_attention``'s does. Returns [B, L, H, v].
+    """
+    num_blocks, bs, pages = kv_pages
+    B, L, H, _ = q_nope.shape
+    rank, rope = c_kv.shape[-1], k_rope.shape[-1]
+    width = -(-(rank + rope) // 128) * 128  # whole lanes (docstring)
+    pool = module.variable(
+        "cache", "pool_latent", jnp.zeros, (num_blocks, bs, width),
+        c_kv.dtype,
+    )
+    table = module.variable(
+        "cache", "page_table", lambda: jnp.zeros((B, pages), jnp.int32)
+    )
+    scale = 1.0 / np.sqrt(q_nope.shape[-1] + rope)
+
+    def attend(q_a, k_a, q_b, k_b, values, visible):
+        """softmax((q_a . k_a + q_b . k_b) * scale) values; k_b is one key
+        head shared by all query heads, k_a and values per head (4 dims)
+        or shared (3 dims)."""
+        per_head = "bkhe" if k_a.ndim == 4 else "bke"
+        scores = (
+            jnp.einsum(f"bqhe,{per_head}->bhqk", q_a, k_a)
+            + jnp.einsum("bqhe,bke->bhqk", q_b, k_b)
+        ).astype(jnp.float32) * scale
+        scores = jnp.where(visible[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        return jnp.einsum(f"bhqk,{per_head}->bqhe", probs, values)
+
+    def expanded():
+        k_nope = jnp.einsum("bkr,rhe->bkhe", c_kv, w_uk)
+        v = jnp.einsum("bkr,rhe->bkhe", c_kv, w_uv)
+        cols = jnp.arange(L)[None, None, :]
+        return _in_query_chunks(
+            lambda qn, qr, qpos: attend(
+                qn, k_nope, qr, k_rope, v, cols <= qpos[:, :, None]
+            ),
+            L, q_nope, q_rope, jnp.broadcast_to(jnp.arange(L), (B, L)),
+        )
+
+    if module.is_initializing():
+        # Shape-only pass: create the pool and attend this call's tokens.
+        return expanded()
+    pos, flat = _page_writes(table.value, lens_var.value, L, bs)
+    with jax.named_scope("latent_write"):
+        row = jnp.concatenate([
+            c_kv, k_rope,
+            jnp.zeros((B, L, width - rank - rope), c_kv.dtype),
+        ], axis=-1)
+        pool.value = pool.value.reshape(num_blocks * bs, width).at[
+            flat
+        ].set(row.reshape(B * L, width)).reshape(pool.value.shape)
+
+    def absorbed():
+        # Gather each row's pages into logical order, whole blocks:
+        # [B, pages*bs, W]; the slice at ``rank`` is lane-aligned.
+        lat = pool.value[table.value].reshape(B, pages * bs, width)
+        ck, kr = lat[..., :rank], lat[..., rank:rank + rope]
+        cols = jnp.arange(pages * bs)[None, None, :]
+
+        def chunk(qn, qr, qpos):
+            q_lat = jnp.einsum("bqhe,rhe->bqhr", qn, w_uk)
+            o_lat = attend(q_lat, ck, qr, kr, ck, cols <= qpos[:, :, None])
+            return jnp.einsum("bqhr,rhe->bqhe", o_lat, w_uv)
+
+        return _in_query_chunks(chunk, L, q_nope, q_rope, pos)
+
+    with jax.named_scope("mla_attend"):
+        if L == 1:
+            out = absorbed()
+        else:
+            out = jax.lax.cond(
+                jnp.all(lens_var.value == 0), expanded, absorbed
+            )
+    if jax.config.jax_enable_checks:
+        # The OOB tripwire of paged_decode_attention (its comment).
+        bad = ((table.value < 0) | (table.value >= num_blocks)).any(axis=1)
+        out = jnp.where(bad[:, None, None, None], jnp.nan, out)
+    lens_var.value = lens_var.value + L
     return out
 
 

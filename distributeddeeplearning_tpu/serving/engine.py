@@ -115,11 +115,18 @@ from .scheduler import (
 # plus, with kv_quant='int8', the parallel per-(slot, head) scale pools
 # (transformer.paged_decode_attention creates them; with kv_quant='off'
 # the scale names simply never appear in the cache pytree, so every
-# name-matching path degrades to the fp pair for free).
+# name-matching path degrades to the fp pair for free). A latent-attention
+# model allocates ONE leaf a layer instead of the K/V pair
+# (transformer.latent_paged_attention): a name here, not a second manager.
+_LATENT_LEAF = "pool_latent"
 _POOL_LEAVES = (
     "pool_key", "pool_value", "pool_key_scale", "pool_value_scale",
+    _LATENT_LEAF,
 )
 _HOST_LEAVES = ("page_table", "seq_lens")
+# Per-layer counters a served program hands back with its tokens: tokens
+# routed to each expert by that call (models/glm4_moe_lite.RoutedExperts).
+_LOAD_LEAF = "expert_load"
 
 # serving.kv_quant domain: device pool storage codecs.
 KV_QUANT_MODES = ("off", "int8")
@@ -134,7 +141,10 @@ _SPILL_QBLOCK = 256
 # a trace: capacity-MoE decode routes through expert capacity (one-token
 # streams and batched prefills disagree — generate.uses_bulk_prefill),
 # and pipelined models own their own step program.
-SERVABLE_MODELS = ("gpt2", "llama")
+SERVABLE_MODELS = ("gpt2", "llama", "glm4_moe_lite")
+# Families whose cache is the latent leaf: what the K/V pool has and the
+# latent pool does not is refused by name (_check_latent_cache).
+LATENT_CACHE_MODELS = ("glm4_moe_lite",)
 
 # Router-tier knob domains (serving/router.py dispatches on these; they
 # live here so the config-time fence and the ReplicaRouter constructor
@@ -287,6 +297,37 @@ def _check_kv_quant(kv_quant, spill_codec) -> str:
             "pass-through of the int8 payload) or kv_quant='off'."
         )
     return mode
+
+
+def _check_latent_cache(name, kv_quant, attn_kernel, spill_codec) -> None:
+    """What is not built for the latent pool leaf (by name, config time):
+    the int8 pool codec and the Pallas read kernel are written for per-head
+    K and V, and the int8 spill codec's accuracy on a leaf that holds a
+    normed latent beside an un-normed RoPE key is not established. The
+    prefix cache, fp spill, handoff and speculation match the pool by leaf
+    name and run unchanged (tests/test_glm4_moe_lite.py)."""
+    if name not in LATENT_CACHE_MODELS:
+        return
+    if str(kv_quant or "off") != "off":
+        raise NotImplementedError(
+            f"serving.kv_quant={kv_quant!r} x latent paged cache "
+            f"({name!r}): the int8 pool quantizes one scale per (token, "
+            "head) K/V vector and the latent leaf has no heads — an int8 "
+            "latent pool is not built; keep kv_quant='off'"
+        )
+    if str(attn_kernel or "reference") != "reference":
+        raise NotImplementedError(
+            f"serving.attn_kernel={attn_kernel!r} x latent paged cache "
+            f"({name!r}): ops/paged_attention.py reads per-head K and V "
+            "pools — an in-place latent read kernel is not built; keep "
+            "attn_kernel='reference'"
+        )
+    if str(spill_codec or "fp") != "fp":
+        raise NotImplementedError(
+            f"serving.spill_codec={spill_codec!r} x latent paged cache "
+            f"({name!r}): the int8 spill codec is not validated on the "
+            "latent leaf; keep spill_codec='fp' (bitwise)"
+        )
 
 
 # serving.role domain: disaggregated prefill/decode phase roles
@@ -492,6 +533,10 @@ def check_serving_composition(cfg, *, fleet: int = 0) -> None:
     )
     _check_kv_quant(
         getattr(s, "kv_quant", "off"), getattr(s, "spill_codec", "fp")
+    )
+    _check_latent_cache(
+        name, getattr(s, "kv_quant", "off"), kernel,
+        getattr(s, "spill_codec", "fp"),
     )
     if policy == "prefix_affinity" and not prefix_on:
         raise ValueError(
@@ -718,13 +763,14 @@ class ServingEngine:
         )
         tok1 = jax.ShapeDtypeStruct((S, 1), jnp.int32)
         shapes = jax.eval_shape(probe.init, jax.random.PRNGKey(0), tok1)
-        block_bytes = sum(
-            int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        leaf_bytes = [
+            (path[-1].key, int(np.prod(leaf.shape)) * leaf.dtype.itemsize)
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                 shapes["cache"]
             )[0]
-            if path[-1].key in _POOL_LEAVES
-        )
+        ]
+        block_bytes = sum(n for k, n in leaf_bytes if k in _POOL_LEAVES)
+        latent_bytes = sum(n for k, n in leaf_bytes if k == _LATENT_LEAF)
         budget = int(cfg.hbm_budget_mb) * (1 << 20)
         self.num_blocks = budget // block_bytes
         min_blocks = 1 + blocks_for(self.max_seq_len, bs)  # null + 1 request
@@ -802,6 +848,7 @@ class ServingEngine:
                         drop_fn=self._spill_drop),
             self.max_seq_len,
             kv_bytes_per_token=self.block_bytes // bs,
+            latent_bytes_per_token=(latent_bytes // bs) or None,
             kv_quant=self.kv_quant,
             role=self.role,
         )
@@ -1283,13 +1330,27 @@ class ServingEngine:
         tok = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
         return tok, split[:, 1]
 
+    @staticmethod
+    def _expert_load(cache):
+        """[expert layers, experts] int32: the tokens this call routed to
+        each expert, stacked from the cache's ``expert_load`` leaves in
+        flatten (layer) order — or None for a model without experts. It
+        leaves the program beside the tokens, so the host reads both in
+        the one read-back a step already makes."""
+        leaves = [
+            leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cache)[0]
+            if getattr(path[-1], "key", None) == _LOAD_LEAF
+        ]
+        return jnp.stack(leaves) if leaves else None
+
     def _prefill_fn(self, params, cache, tokens, pos, rng, temp, tk, tp):
         out, cache = prefill(self.model, self._dequant(params), cache, tokens)
         with jax.named_scope("sample"):
             tok, rng = self._sample_body(
                 logits_at(out, pos), rng, temp, tk, tp
             )
-        return tok, rng, cache
+        return (tok, self._expert_load(cache)), rng, cache
 
     def _decode_fn(self, params, cache, tok, rng, temp, tk, tp):
         logits, cache = decode_step(
@@ -1297,13 +1358,25 @@ class ServingEngine:
         )
         with jax.named_scope("sample"):
             tok, rng = self._sample_body(logits, rng, temp, tk, tp)
-        return tok, rng, cache
+        return (tok, self._expert_load(cache)), rng, cache
 
     def _verify_fn(self, params, cache, toks):
         # Greedy-only by construction (the x-sampling fence in submit):
         # no rng / temperature operands, so a lane's PRNG chain is
         # untouched by verify steps.
-        return verify_step(self.model, self._dequant(params), cache, toks)
+        greedy, cache = verify_step(
+            self.model, self._dequant(params), cache, toks
+        )
+        return (greedy, self._expert_load(cache)), cache
+
+    def _read_back(self, out):
+        """The step's one device read: the tokens a program sampled and,
+        for a model with experts, the tokens it routed to each (folded
+        into the scheduler's running counts)."""
+        tok, load = jax.device_get(out)
+        if load is not None:
+            self.scheduler.note_expert_load(load)
+        return tok
 
     def _compile(self, fn, *args, name: str | None = None,
                  donate_argnums=()):
@@ -1591,7 +1664,7 @@ class ServingEngine:
             # writes land in the request's own blocks (row[off//bs:]), and the
             # suffix attends to cached prefix KV through the shared table.
             cache1 = self._inject(self._cache, row[None], np.int32([off]))
-        tok, rng_out, cache1 = exe(
+        out, rng_out, cache1 = exe(
             self._params, cache1, tokens, pos, rng, temp, tk, tp
         )
         self.calls["prefill"] += 1
@@ -1610,7 +1683,7 @@ class ServingEngine:
             self._queue_handoff(state, written=len(req.prompt))
             return
         with tel.span("prefill_readback"):
-            tok = int(tok[0])
+            tok = int(self._read_back(out)[0])
             now = self.clock()
             state.generated.append(tok)
             state.token_times_s.append(now)
@@ -1749,7 +1822,7 @@ class ServingEngine:
         tel = self._tel
         cacheS, decode_args = self._decode_operands(active)
         with tel.span("decode", **decode_args):
-            tok, rng, cacheS = self._decode_exe_or_compile()(
+            out, rng, cacheS = self._decode_exe_or_compile()(
                 self._params, cacheS, self._tok[:, None], self._rng,
                 self._temp, self._top_k, self._top_p,
             )
@@ -1758,7 +1831,7 @@ class ServingEngine:
             # must charge for that wait or its histogram (the decode-phase
             # throughput denominator in serve_bench) flatters L=1 steps
             # relative to the verify path, which must sync to accept.
-            tok = np.asarray(tok)
+            tok = self._read_back(out)
         with tel.span("collect", step=self.step_count):
             self.calls["decode"] += 1
             self._cache = cacheS
@@ -1797,12 +1870,12 @@ class ServingEngine:
             active, speculative=True, drafted=int(dlens.sum())
         )
         with tel.span("decode", **decode_args) as sp:
-            greedy, cacheS = self._verify_exe_or_compile()(
+            out, cacheS = self._verify_exe_or_compile()(
                 self._params, cacheS, toks
             )
             self.calls["verify"] += 1
             self._cache = cacheS
-            greedy = np.asarray(greedy)
+            greedy = self._read_back(out)
             now = self.clock()
             # Vectorized acceptance: the leading-match run length for
             # every lane in one [S, K] comparison (cumprod of the match
@@ -1881,6 +1954,7 @@ class ServingEngine:
             "quant": self.quant_report,
             "kv_quant": self.kv_quant,
             "kv_bytes_per_token": self.block_bytes // self.block_size,
+            **self.scheduler.latent_and_expert_gauges(),
             "attn_kernel": self.attn_kernel,
             "max_prefills_per_step": self.max_prefills,
             "draining": self.draining,

@@ -966,6 +966,7 @@ class Scheduler:
 
     def __init__(self, slots: int, pool: KVBlockPool, max_seq_len: int, *,
                  kv_bytes_per_token: int | None = None,
+                 latent_bytes_per_token: int | None = None,
                  kv_quant: str | None = None, role: str | None = None):
         if slots < 1:
             raise ValueError(f"serving.slots must be >= 1, got {slots}")
@@ -977,6 +978,13 @@ class Scheduler:
         # not blocks — with kv_quant='int8' a block holds the same tokens
         # in ~4x fewer bytes, so block counts alone mislead the router.
         self.kv_bytes_per_token = kv_bytes_per_token
+        # The latent pool leaf's share of kv_bytes_per_token (a
+        # latent-attention model: all of it), None for a K/V pool.
+        self.latent_bytes_per_token = latent_bytes_per_token
+        # Tokens routed to each expert so far, [expert layers][experts],
+        # every row the experts computed counted (idle lanes and prompt
+        # padding too); None until a model with experts has run a call.
+        self.expert_load: list[list[int]] | None = None
         self.kv_quant = kv_quant
         # Disaggregation phase role (None = omit from gauges(), the
         # pre-role gauge shape). The engine keeps the two handoff
@@ -1294,6 +1302,41 @@ class Scheduler:
             }
         return out
 
+    def note_expert_load(self, load) -> None:
+        """Fold one call's per-layer per-expert token counts (rows of
+        ints, as the served program returned them) into the running
+        totals."""
+        rows = [[int(n) for n in row] for row in load]
+        if self.expert_load is None:
+            self.expert_load = rows
+        else:
+            self.expert_load = [
+                [a + b for a, b in zip(old, new)]
+                for old, new in zip(self.expert_load, rows)
+            ]
+
+    def latent_and_expert_gauges(self) -> dict:
+        """``latent_bytes_per_token`` for a latent pool and, once a model
+        with experts has run, its load: ``moe_tokens_per_expert`` (the
+        running counts, a row a layer), ``moe_load_max_over_mean`` (the
+        busiest expert of any layer over its layer's mean: 1.0 is even)
+        and ``moe_experts_hit_share`` (experts that got a token so far).
+        Empty for a model with neither."""
+        g = {}
+        if self.latent_bytes_per_token is not None:
+            g["latent_bytes_per_token"] = self.latent_bytes_per_token
+        load = self.expert_load
+        if load and any(sum(row) for row in load):
+            g["moe_tokens_per_expert"] = [list(row) for row in load]
+            g["moe_load_max_over_mean"] = round(max(
+                max(row) * len(row) / sum(row) for row in load if sum(row)
+            ), 4)
+            g["moe_experts_hit_share"] = round(
+                sum(n > 0 for row in load for n in row)
+                / sum(len(row) for row in load), 4
+            )
+        return g
+
     def gauges(self, now: float | None = None) -> dict:
         """The instantaneous capacity gauges (``metrics.serving_gauges``
         kwargs): queue depth + pool occupancy, the subset of :meth:`stats`
@@ -1323,6 +1366,7 @@ class Scheduler:
             # Byte-denominated capacity: free_blocks is not comparable
             # across replicas with different kv_quant settings.
             g["kv_bytes_per_token"] = self.kv_bytes_per_token
+        g.update(self.latent_and_expert_gauges())
         if self.kv_quant is not None:
             g["kv_quant"] = self.kv_quant
         if self.role is not None:

@@ -688,12 +688,19 @@ class Trainer:
             )
         return self._layout
 
-    def _init_fn(self, rng, example_inputs):
+    def _init_variables(self, rng, example_inputs):
+        """(the model's freshly initialized variables, the state's rng):
+        the one place ``rng`` is split, shared by the whole-state init and
+        the parameters-only one."""
         p_rng, d_rng, s_rng = jax.random.split(rng, 3)
         with nn.logical_axis_rules(self.rules):
             variables = self.model.init(
                 {"params": p_rng, "dropout": d_rng}, *example_inputs, train=False
             )
+        return variables, s_rng
+
+    def _init_fn(self, rng, example_inputs):
+        variables, s_rng = self._init_variables(rng, example_inputs)
         params = variables.pop("params")
         # sow()-collections are per-step outputs, not persistent state.
         variables.pop("losses", None)
@@ -847,6 +854,20 @@ class Trainer:
             out_shardings=self.state_shardings,
         )
         return init(jax.random.PRNGKey(seed))
+
+    def init_params(self, seed: int, example_batch):
+        """The parameters ``init`` would give, alone and in its placement:
+        no optimizer state is built (a server never reads one, and for a
+        model that fills the chip there is no room for one)."""
+        self.setup(example_batch)
+
+        def params_only(rng):
+            variables, _ = self._init_variables(rng, self._example_inputs)
+            return nn.meta.unbox(variables["params"])
+
+        return jax.jit(
+            params_only, out_shardings=self.state_shardings.params
+        )(jax.random.PRNGKey(seed))
 
     def abstract_state_with_shardings(self):
         """ShapeDtypeStructs carrying shardings — what orbax needs to restore

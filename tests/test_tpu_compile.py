@@ -161,17 +161,7 @@ def test_paged_attention_compiles(one_chip, layout, quantized):
     _assert_kernel(_compiled_text(fn, *args))
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_engine_programs_take_the_pool_as_it_lies(one_chip, program):
-    # The counter of the lane-dense pool (PERF.md §6, PR 25): a leaf whose
-    # minor dims pad badly is stored block-index-minor by the runtime, and
-    # every serving program then re-lays the whole pool out, three copies
-    # a leaf a call. It engages always or never and a CPU run cannot see
-    # it, so: the engine's OWN programs, with the engine's own operands
-    # and donation, at real head geometry (12 x 64), compiled for the chip.
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ServingEngine
-
+def _gpt2_engine_case():
     model = models.get_model(
         "gpt2", size="124m", num_layers=2, vocab_size=512, max_len=256,
         dtype=jnp.bfloat16,
@@ -179,8 +169,44 @@ def test_engine_programs_take_the_pool_as_it_lies(one_chip, program):
     params = model.init(
         jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
     )["params"]
+    # K and V of two layers, 12 heads x 64 folded into the minor dimension
+    return model, params, 24, r"pool_(?:key|value)", 4, H * D
+
+
+def _glm_engine_case():
+    # The served cell's widths (benchmarks/configs/glm47_flash.json): the
+    # latent leaf holds 512 + 64 values a token, stored 640 wide. One
+    # dense and one expert layer; the parameters stay shapes (1.4 GB real).
+    import flax
+
+    model = models.get_model(
+        "glm4_moe_lite", num_layers=2, vocab_size=512, max_len=256,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    params = flax.core.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
+    )["params"])
+    return model, params, 10, r"pool_latent", 2, 640
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("case", [_gpt2_engine_case, _glm_engine_case],
+                         ids=["gpt2", "glm4_moe_lite"])
+def test_engine_programs_take_the_pool_as_it_lies(one_chip, case, program):
+    # The counter of the lane-dense pool (PERF.md §6, PR 25): a leaf whose
+    # minor dims pad badly is stored block-index-minor by the runtime, and
+    # every serving program then re-lays the whole pool out, three copies
+    # a leaf a call. It engages always or never and a CPU run cannot see
+    # it, so: the engine's OWN programs, with the engine's own operands
+    # and donation, at real head geometry (12 x 64; the latent leaf of
+    # 576 values, which is stored block-index-minor unless padded to 640:
+    # PR 26), compiled for the chip.
+    from distributeddeeplearning_tpu.config import ServingConfig
+    from distributeddeeplearning_tpu.serving import ServingEngine
+
+    model, params, budget_mb, leaf_name, n_leaves, width = case()
     eng = ServingEngine(model, params, ServingConfig(
-        slots=8, block_size=16, hbm_budget_mb=24, max_seq_len=256,
+        slots=8, block_size=16, hbm_budget_mb=budget_mb, max_seq_len=256,
         prompt_buckets=(32,),
     ))
     assert eng.num_blocks == 256
@@ -201,15 +227,15 @@ def test_engine_programs_take_the_pool_as_it_lies(one_chip, program):
     assert text.startswith(f"HloModule jit__{program}_fn")
 
     leaves = eng._pool_leaves()
-    assert len(leaves) == 4  # K and V of two layers
+    assert len(leaves) == n_leaves
     assert {(leaf.shape, str(leaf.dtype)) for leaf in leaves} == {
-        ((256, 16, H * D), "bfloat16")
+        ((256, 16, width), "bfloat16")
     }
-    shape = rf"bf16\[256,16,{H * D}\]"
+    shape = rf"bf16\[256,16,{width}\]"
     pool_params = dict(re.findall(
-        rf"%(\S*pool_(?:key|value)\S*) = {shape}\S* parameter\((\d+)\)", text
+        rf"%(\S*{leaf_name}\S*) = {shape}\S* parameter\((\d+)\)", text
     ))
-    assert len(pool_params) == 4, pool_params
+    assert len(pool_params) == n_leaves, pool_params
     # The declared order is the stored order: nothing to undo.
     entry = re.findall(rf"{shape}\{{([\d,]+)", text.split("\n")[0])
     assert entry and set(entry) == {"2,1,0"}, entry
@@ -225,6 +251,14 @@ def test_engine_programs_take_the_pool_as_it_lies(one_chip, program):
         # Prefill is not donated (engine._prefill_exe_for says why): the
         # one plain copy of a leaf into its output may remain, no more.
         assert len(copies) <= len(leaves), copies
+    if leaf_name == "pool_latent":
+        # The grouped expert product is the compiler's own kernel: no
+        # dense dispatch, and no Pallas kernel of this repo to name.
+        assert "ragged-dot" in text
+        ops = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in ("mla_project", "mla_attend", "latent_write",
+                      "moe_route", "moe_experts", "moe_shared"):
+            assert any(f"/{scope}/" in o for o in ops), scope
 
 
 def test_chunked_xent_loss_and_grad_compile(one_chip):
