@@ -5,6 +5,8 @@ against materialized-softmax references; the TPU compile lives in
 tests/test_tpu_compile.py and the on-chip parity in chip_smoke.py.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,12 @@ from distributeddeeplearning_tpu.ops import (
 )
 
 
+def _module():
+    # ``ops.flash_attention`` names the function; the module under it holds
+    # the tile rule.
+    return sys.modules["distributeddeeplearning_tpu.ops.flash_attention"]
+
+
 def _qkv(key, b=2, s=256, h=2, d=64, dtype=jnp.float32):
     kq, kk, kv = jax.random.split(key, 3)
     shape = (b, s, h, d)
@@ -23,12 +31,37 @@ def _qkv(key, b=2, s=256, h=2, d=64, dtype=jnp.float32):
     return mk(kq), mk(kk), mk(kv)
 
 
+# (shape of q/k/v, blocks): the named blocks the suite began with, then the
+# derived default tiles (no block named): one tile; several of them, so that
+# the sweep inside the kernel and its causal bound run more than once; a
+# length the rule pads (1100 -> 1152, three 384-row blocks); bfloat16 inputs.
+_TILE_CASES = {
+    "blocks128": (dict(), dict(block_q=128, block_k=128)),
+    "default_one_tile": (dict(), {}),
+    "default_several_tiles": (dict(b=1, s=1536, h=2, d=32), {}),
+    "default_padded": (dict(b=1, s=1100, h=1, d=32), {}),
+    "default_bf16": (dict(b=1, s=1024, h=2, dtype=jnp.bfloat16), {}),
+}
+
+
+def _tol(q, f32):
+    return dict(atol=f32, rtol=f32) if q.dtype == jnp.float32 else dict(
+        atol=3e-2, rtol=3e-2
+    )
+
+
+def _f32(tree):
+    return jax.tree.map(lambda t: np.asarray(t, np.float32), tree)
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
 @pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_reference(causal):
-    q, k, v = _qkv(jax.random.PRNGKey(0))
-    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
+def test_forward_matches_reference(causal, case):
+    shape, blocks = _TILE_CASES[case]
+    q, k, v = _qkv(jax.random.PRNGKey(0), **shape)
+    out = flash_attention(q, k, v, causal=causal, **blocks)
     ref = attention_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(_f32(out), _f32(ref), **_tol(q, 2e-5))
 
 
 def test_forward_bf16():
@@ -48,20 +81,91 @@ def test_multi_block_unequal_blocks():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+_GRAD_CASES = {
+    "one_tile": dict(s=128, d=32),
+    "several_tiles": dict(b=1, s=1024, h=2, d=32),
+    "padded": dict(b=1, s=1100, h=1, d=32),
+    "bf16": dict(b=1, s=1024, h=1, d=32, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_GRAD_CASES))
 @pytest.mark.parametrize("causal", [False, True])
-def test_grads_match_reference(causal):
-    q, k, v = _qkv(jax.random.PRNGKey(3), s=128, d=32)
+def test_grads_match_reference(causal, case):
+    # Every case at the derived default tiles (no block named).
+    q, k, v = _qkv(jax.random.PRNGKey(3), **_GRAD_CASES[case])
     w = jax.random.normal(jax.random.PRNGKey(4), q.shape)
 
     def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal) * w)
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=causal).astype(jnp.float32) * w
+        )
 
     g_flash = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(
+            _f32(gf), _f32(gr), **_tol(q, 5e-5), err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_several_major_blocks_match_reference(causal, monkeypatch):
+    # A sequence too long for K and V (dK/dV: Q and dO) to stay in VMEM whole
+    # is swept in several major blocks on the grid's third axis, with the
+    # swept pair's index map clamped to the causal diagonal. 32K rows do that
+    # on the chip; here the cap is shrunk until 128 rows do: 4 major blocks
+    # of one 32-row tile for q (block 32), 2 of one 64-row tile for k.
+    fa = _module()
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", 1)
+    cut = fa.tiles(128, 32, jnp.float32, causal, 32, 64)
+    assert (cut.major_q, cut.major_k) == (32, 64)
+    q, k, v = _qkv(jax.random.PRNGKey(18), s=128, d=32)
+    w = jax.random.normal(jax.random.PRNGKey(19), q.shape)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal, **kw) * w)
+
+    blocks = dict(block_q=32, block_k=64)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=causal, **blocks),
+        attention_reference(q, k, v, causal=causal), atol=2e-5, rtol=2e-5,
+    )
+    g_flash = jax.grad(loss(flash_attention, **blocks), argnums=(0, 1, 2))(
+        q, k, v
+    )
+    g_ref = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
             gf, gr, atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
         )
+
+
+def test_tile_rule_grid_and_divisibility():
+    # The rule itself: at the training cell's call, bf16[8*16, 1024, 64]
+    # causal, at most 1,024 grid steps a kernel (the 128 x 128 grid had
+    # 8,192); and for any length no block that fails to divide the padded
+    # sequence, no major block that fails to hold whole tiles.
+    fa = _module()
+    cut = fa.tiles(1024, 64, jnp.bfloat16, True)
+    bh = 8 * 16
+    assert bh * (cut.seq // cut.block_q) * (cut.seq // cut.major_k) <= 1024
+    assert bh * (cut.seq // cut.block_k) * (cut.seq // cut.major_q) <= 1024
+    assert cut.seq == 1024
+    for seq in (8, 17, 64, 100, 128, 197, 509, 1000, 1024, 1100, 4096, 5000,
+                32768):
+        for causal in (False, True):
+            for dtype, d in ((jnp.bfloat16, 64), (jnp.float32, 128)):
+                cut = fa.tiles(seq, d, dtype, causal)
+                assert seq <= cut.seq < seq + 128, (seq, cut)
+                for blk, maj in ((cut.block_q, cut.major_q),
+                                 (cut.block_k, cut.major_k)):
+                    assert cut.seq % maj == 0 and maj % blk == 0, (seq, cut)
+    # Named blocks: cut to the sequence, one common block where they do not
+    # divide it (the pad stays under a block).
+    assert fa.tiles(256, 64, jnp.float32, True, 64, 128)[:3] == (64, 128, 256)
+    assert fa.tiles(96, 64, jnp.float32, True, 64, 64)[:3] == (64, 64, 128)
+    assert fa.tiles(64, 64, jnp.float32, True, 128, 128)[:3] == (64, 64, 64)
 
 
 def test_grads_under_jit_and_blocks():
@@ -172,7 +276,10 @@ def test_transformer_flash_matches_xla():
     )
 
 
-def test_kv_valid_lens_match_masked_reference():
+@pytest.mark.parametrize("seq,blocks", [
+    (64, {}), (64, dict(block_q=32, block_k=32)), (2048, {}),
+], ids=["default_one_tile", "blocks32", "default_several_tiles"])
+def test_kv_valid_lens_match_masked_reference(seq, blocks):
     # Per-sequence key-padding limits (the contiguous-prefix mask case):
     # valid query rows must match a -inf-masked reference; padded rows are
     # garbage by contract (the loss masks them).
@@ -185,28 +292,32 @@ def test_kv_valid_lens_match_masked_reference():
         p = jax.nn.softmax(jnp.where(keep, s, -1e30), -1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
-    q, k, v = _qkv(jax.random.PRNGKey(11), b=4, s=64, h=4, d=16)
-    vl = jnp.array([64, 37, 50, 12], jnp.int32)
+    q, k, v = _qkv(
+        jax.random.PRNGKey(11), b=4, s=seq, h=4 if seq == 64 else 1, d=16
+    )
+    vl = jnp.array([64, 37, 50, 12], jnp.int32) * (seq // 64)
     ref = ref_attn(q, k, v, vl)
     # block 32 -> 2 kv blocks, with vl values crossing block boundaries and
     # one sequence (12 < 32) whose SECOND block is fully masked — exercises
-    # the online-softmax recurrence over masked trailing blocks.
-    for blocks in ({}, dict(block_q=32, block_k=32)):
-        out = flash_attention(q, k, v, kv_valid_lens=vl, **blocks)
-        for i in range(4):
-            n = int(vl[i])
-            np.testing.assert_allclose(
-                out[i, :n], ref[i, :n], atol=5e-5, rtol=5e-5
-            )
+    # the online-softmax recurrence over masked trailing blocks. At 2048 the
+    # default tiles are 512 x 1024: the same over the sweep inside the kernel.
+    out = flash_attention(q, k, v, kv_valid_lens=vl, **blocks)
+    for i in range(4):
+        n = int(vl[i])
+        np.testing.assert_allclose(
+            out[i, :n], ref[i, :n], atol=5e-5, rtol=5e-5
+        )
     # Gradients with a validity-weighted loss (padded rows contribute 0).
-    wmask = (jnp.arange(64)[None, :] < vl[:, None]).astype(jnp.float32)
+    wmask = (jnp.arange(seq)[None, :] < vl[:, None]).astype(jnp.float32)
     wmask = wmask[:, :, None, None]
 
     def loss(fn):
         return lambda q, k, v: ((fn(q, k, v) * wmask) ** 2).sum()
 
     gf = jax.grad(
-        loss(lambda q, k, v: flash_attention(q, k, v, kv_valid_lens=vl)),
+        loss(lambda q, k, v: flash_attention(
+            q, k, v, kv_valid_lens=vl, **blocks
+        )),
         argnums=(0, 1, 2),
     )(q, k, v)
     gr = jax.grad(

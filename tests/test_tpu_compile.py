@@ -112,6 +112,30 @@ def test_flash_backward_compiles(one_chip):
     ))
 
 
+@pytest.mark.parametrize("shape,causal", [
+    # The training cell's own call (benchmarks/configs/gpt2_medium.json,
+    # traffic fit_b8_s1024) at the derived default tiles: VMEM fits, and the
+    # names the benchmark's readers look for are kept.
+    ((8, 1024, 16, 64), True),
+    # 16K rows: K and V no longer stay in VMEM whole (several major blocks).
+    ((1, 16384, 8, 128), True),
+    # ViT's 197 tokens, not causal: padded to 256, one tile.
+    ((4, 197, 12, 64), False),
+], ids=["train_cell", "L16384-major-blocks", "L197-padded-noncausal"])
+def test_flash_default_tiles_compile(one_chip, shape, causal):
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=False)
+
+    qkv = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)] * 3
+    assert _kernel_names(_compiled_text(flash, *qkv)) == {"flash_fwd"}
+    got = _kernel_names(_compiled_text(
+        jax.grad(_sq_mean(flash), argnums=(0, 1, 2)), *qkv
+    ))
+    assert _untransformed(got) == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"
+    }
+
+
 def test_ring_attention_pallas_cp1_compiles(topo):
     mesh = build_mesh(MeshConfig(dp=1), devices=[topo.devices[0]])
     _assert_kernel(_compiled_text(
