@@ -88,10 +88,12 @@ class CompileLog:
 def peak_bytes() -> int | None:
     """Highest ``peak_bytes_in_use`` over local devices (a process-lifetime
     high-water mark), or None on the CPU backend."""
-    from distributeddeeplearning_tpu.benchmark import device_memory_stats
+    import jax
 
-    mem = device_memory_stats()
-    return mem["hbm_peak_bytes"] if mem else None
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if any(s is None for s in stats):
+        return None
+    return max(s["peak_bytes_in_use"] for s in stats)
 
 
 def emit(phase: str, t0: float, compiles, **checked) -> dict:

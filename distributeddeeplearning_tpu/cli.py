@@ -2,7 +2,7 @@
 
 Subcommands mirror the reference's per-config training entrypoints
 (``BASELINE.json:5`` "the existing training entrypoints"): one config file per
-workload, plus ``benchmark`` for the north-star throughput measurement.
+workload.
 """
 
 from __future__ import annotations
@@ -1082,8 +1082,8 @@ def cmd_launch(args) -> int:
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="distributeddeeplearning_tpu")
     sub = parser.add_subparsers(dest="cmd", required=True)
-    for name in ("train", "eval", "benchmark", "generate", "serve",
-                 "supervise", "launch"):
+    for name in ("train", "eval", "generate", "serve", "supervise",
+                 "launch"):
         p = sub.add_parser(name)
         p.add_argument("--config", required=True, help="path to a config .py")
         p.add_argument(
@@ -1218,14 +1218,6 @@ def main(argv=None) -> int:
             cfg, args.prompt, args.max_new_tokens, args.temperature,
             args.seed, top_k=args.top_k, top_p=args.top_p,
         )
-    if args.cmd == "benchmark":
-        try:
-            from .benchmark import run_benchmark
-        except ImportError:
-            print("benchmark subcommand not implemented yet", file=sys.stderr)
-            return 2
-        print(json.dumps(run_benchmark(cfg)))
-        return 0
     return 2
 
 

@@ -408,23 +408,3 @@ def hier_all_gather_buckets(shards, layout: BucketLayout, axis: str,
     ``comms_overlap.all_gather_buckets``."""
     flats = [hier_all_gather(s, axis, topo) for s in shards]
     return layout.unbucket(flats)
-
-
-# ---------------------------------------------------------------------------
-# Telemetry (benchmark.py)
-# ---------------------------------------------------------------------------
-
-
-def phase_wire_bytes(total_payload_bytes: float, topo: HierTopology) -> dict:
-    """Per-member ring-model wire bytes of one hierarchical sync, by phase
-    (the accounting ``tools/project_scaling.py`` projects): intra RS moves
-    the full payload over ICI, the cross all-reduce moves ``payload/ici``
-    over DCN, the intra all-gather the full payload again. Keys are stable —
-    ``benchmark.py`` reports them and ``dcn_wire_bytes`` is the cross
-    phase."""
-    p, ici, dcn = float(total_payload_bytes), topo.ici, topo.dcn
-    return {
-        "intra_reduce_scatter_bytes": int(p * (ici - 1) / ici),
-        "cross_all_reduce_bytes": int((p / ici) * 2 * (dcn - 1) / dcn),
-        "intra_all_gather_bytes": int(p * (ici - 1) / ici),
-    }

@@ -145,8 +145,7 @@ class BucketLayout:
 
     def wire_bytes(self, mode: str, block_size: int = DEFAULT_BLOCK_SIZE):
         """Per-bucket wire payload bytes of one sync under ``mode`` (the
-        f32 padded size scaled by the codec's compression ratio) — telemetry
-        for ``benchmark.py``."""
+        f32 padded size scaled by the codec's compression ratio)."""
         from .comms_quant import compression_ratio
 
         r = compression_ratio(mode, block_size)

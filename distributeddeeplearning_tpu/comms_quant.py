@@ -3,15 +3,15 @@ and reduce-scatter (PAPERS.md: "EQuARX: Efficient Quantized AllReduce in
 XLA", arXiv 2506.17615).
 
 The gradient-sync all-reduce is the dominant inter-chip byte stream of a
-data-parallel step (``PROJECTED_SCALING.json`` models it from HLO-lowered
-collective bytes). These wrappers cut those bytes ~4x by running the ring
+data-parallel step (its time is not measured on the chip: no four-chip
+cell, PERF.md §7). These wrappers cut those bytes ~4x by running the ring
 algorithm on a **compressed payload**: every hop ships int8 values plus one
 f32 scale per ``block_size`` elements (or a bf16 cast in ``bf16`` mode)
 instead of f32, while accumulation stays in f32 on-device. Implemented with
 ``shard_map`` ring primitives (``lax.ppermute`` — one ICI-neighbor hop each),
 so the compiled HLO's collective-permute payloads ARE the compressed bytes
-and the comm-cost model (``utils/hlo.py`` + ``tools/project_scaling.py``)
-counts the win directly.
+and the byte count from the HLO (``utils/hlo.py``; asserted by
+``tests/test_grad_comm.py``) shows the cut directly.
 
 Quantization error discipline:
 
@@ -87,8 +87,9 @@ def _decompress(payload, mode: str):
 
 
 def compression_ratio(mode: str, block_size: int = DEFAULT_BLOCK_SIZE) -> float:
-    """Payload bytes per f32 element (scales included) — the model
-    ``tools/project_scaling.py`` uses for its quantized-mode rows."""
+    """Payload bytes per f32 element (scales included), as a share of the
+    f32 payload: what ``grad_sync_bytes`` and ``BucketLayout.wire_bytes``
+    scale by."""
     if mode == "fp32":
         return 1.0
     if mode == "bf16":

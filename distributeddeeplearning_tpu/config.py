@@ -394,8 +394,8 @@ class ServingConfig:
     # Spill payload codec: 'fp' keeps the pool dtype bitwise (warm-vs-
     # cold greedy parity stays exact), 'int8' block-quantizes through
     # comms_quant (~4x more spilled tokens per host byte; promoted
-    # logits drift within the pinned tolerance — see BENCH_SERVING.json
-    # kv_hierarchy). Only meaningful with spill_blocks > 0 — fenced.
+    # logits drift within the tolerance tests/test_serving_spill.py
+    # pins). Only meaningful with spill_blocks > 0 — fenced.
     spill_codec: str = "fp"
     # Quantized DEVICE-resident paged KV (docs/SERVING.md quantized-KV
     # section): 'off' stores pool blocks in the model dtype; 'int8'
@@ -405,8 +405,8 @@ class ServingConfig:
     # Pallas per-page DMA; dequant-on-gather in the reference kernel),
     # so the same HBM budget mints ~2-4x more pool blocks (the engine's
     # sizing probe measures the real per-block bytes). fp32 attention
-    # carries are unchanged; greedy output drifts within the pinned
-    # tolerance (BENCH_SERVING.json kv_quant). Incompatible by name
+    # carries are unchanged; greedy output matches the fp pool token for
+    # token on tests/test_serving.py's trace. Incompatible by name
     # with spill_codec='int8' (spilled payloads are ALREADY int8 —
     # double quantization would compound error for zero bytes saved).
     kv_quant: str = "off"

@@ -458,10 +458,10 @@ def decode_bench(
     time, with the prefill rate and the blended end-to-end rate as
     separate, labeled fields.
 
-    Methodology matches ``benchmark.run_benchmark``: a warmup call absorbs
-    compilation, ``reps`` (>= 3 enforced) timed repetitions of each stage
-    bounded by ``block_until_ready``, medians reported, and a recompile
-    guard (the stage jit caches must not grow inside the timed window).
+    Methodology: a warmup call absorbs compilation, ``reps`` (>= 3
+    enforced) timed repetitions of each stage bounded by
+    ``block_until_ready``, medians reported, and a recompile guard (the
+    stage jit caches must not grow inside the timed window).
 
     ``tokens`` is bit-identical to :func:`generate`'s output for the same
     arguments (same stage bodies, composed; pinned by tests).

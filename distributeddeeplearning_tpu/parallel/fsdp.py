@@ -78,8 +78,7 @@ def grad_sync_bytes(
     ring all-reduce over ``n`` members ships ``2*(n-1)`` hops of ``P/n``
     elements each, at the payload width of the ``grad_comm`` mode
     (comms_quant: int8 values + one f32 scale per ``block_size`` — ~4x under
-    fp32). ``bench.py`` / ``benchmark.py`` report this next to measured
-    step time so the byte win per mode is visible without an HLO dump.
+    fp32), so the byte cut per mode is visible without an HLO dump.
 
     ``wire_elem_bytes`` overrides the uncompressed element width — under a
     mixed-precision policy grads leave the backward pass in the compute

@@ -620,8 +620,7 @@ class ServingEngine:
     """
 
     def __init__(self, model, params, cfg, *, emit=None,
-                 clock=time.monotonic, seed: int = 0,
-                 static_batching: bool = False, telemetry=None):
+                 clock=time.monotonic, seed: int = 0, telemetry=None):
         if getattr(model, "attn_impl", "xla") != "xla":
             raise NotImplementedError(
                 f"serving x attn_impl={model.attn_impl!r} (see "
@@ -629,12 +628,6 @@ class ServingEngine:
             )
         self.cfg = cfg
         self.clock = clock
-        # Static-batching BASELINE mode (tools/serve_bench.py): admission
-        # only into an EMPTY engine — a batch forms, runs to completion,
-        # then the next batch forms. Same compiled programs, same pool,
-        # same scheduler; the only delta is no mid-flight join, so the
-        # bench isolates exactly what continuous batching buys.
-        self.static_batching = static_batching
         self.events: list[dict] = []
         self._emit = emit if emit is not None else self.events.append
         # Telemetry bundle (telemetry.py): an engine_step span a step,
@@ -696,42 +689,6 @@ class ServingEngine:
             getattr(cfg, "role", "unified"), self.prefix_cache,
             getattr(cfg, "speculation", "off"),
         )
-        if static_batching and self.role != "unified":
-            raise NotImplementedError(
-                f"serving.role={self.role!r} x static_batching: the "
-                "static baseline forms whole batches and runs them to "
-                "completion in one engine — there is no phase boundary "
-                "to split across replicas; benchmark role-split fleets "
-                "against the unified CONTINUOUS fleet instead "
-                "(tools/serve_bench.py disagg block does)"
-            )
-        if static_batching and self.kv_quant != "off":
-            raise NotImplementedError(
-                f"serving.kv_quant={self.kv_quant!r} x static_batching: "
-                "the static baseline exists as the exact-numerics anchor "
-                "the bench comparisons (and parity claims) are measured "
-                "against, and a quantized pool perturbs logits — "
-                "benchmark kv_quant against the kv_quant='off' "
-                "CONTINUOUS engine instead (tools/serve_bench.py does)"
-            )
-        if static_batching and self.spill_blocks:
-            raise NotImplementedError(
-                "serving.spill_blocks x static_batching (spill_codec="
-                f"{self.spill_codec!r}): the host tier exists to carry "
-                "warm prefixes ACROSS batches, and the static baseline "
-                "admits only into an empty engine — exactly the cross-"
-                "batch reuse it exists to exclude; benchmark spill "
-                "against the spill-off CONTINUOUS engine instead"
-            )
-        if static_batching and self.prefix_cache:
-            raise NotImplementedError(
-                "serving.prefix_cache x static_batching: the static "
-                "baseline exists to isolate continuous batching against a "
-                "fixed per-batch prefill cost, and cross-batch KV reuse "
-                "would confound exactly that comparison — benchmark the "
-                "prefix cache against the cache-off CONTINUOUS engine "
-                "instead (tools/serve_bench.py does)"
-            )
         S, bs = int(cfg.slots), int(cfg.block_size)
         self.slots_n, self.block_size = S, bs
         # Speculative decoding (module docstring): up to K host-drafted
@@ -862,13 +819,8 @@ class ServingEngine:
             "adopted": 0, "adopt_blocks": 0, "adopt_bytes": 0,
             "adopt_skipped_blocks": 0, "adopt_fallbacks": 0,
         }
-        # True async spill promote (ROADMAP 2b): device_put uploads for
-        # promoted chains are kicked for EVERY state admitted this step
-        # before the first suffix prefill dispatches, so the H2D copies
-        # hide under earlier admissions' prefill compute (and the
-        # preceding decode). False restores the upload-at-prefill-
-        # dispatch behavior — the bench's sync baseline.
-        self.promote_async = True
+        # Promote uploads in flight, by request id: _start_promotions
+        # stages them, _apply_promotions scatters them.
         self._staged_promotes: dict[int, tuple] = {}
         self._table = np.zeros((S, self.pages), np.int32)
         self._lens = np.zeros((S,), np.int32)
@@ -885,7 +837,7 @@ class ServingEngine:
         self._verify_exe = None
         self.num_compiles = 0
         self.calls = {"prefill": 0, "decode": 0, "verify": 0}
-        # Speculation yield counters (stats() / serve_bench columns):
+        # Speculation yield counters (stats()):
         # drafted = draft tokens offered to verify, draft_hits = drafted
         # tokens accepted, emitted = tokens emitted by verify steps (hits
         # + one correction/bonus token per lane per step), lane_steps =
@@ -1006,9 +958,7 @@ class ServingEngine:
         everything the engine does between here and the scatter — other
         admissions' prefills, the preceding decode's tail — overlaps the
         H2D copy. ``step()`` calls this for every admitted state at
-        admission/match time (``promote_async``, ROADMAP 2b); with the
-        flag off, :meth:`_apply_promotions` stages inline (the upload
-        waits until suffix-prefill dispatch — the old behavior). Staged
+        admission/match time, before the first prefill dispatches. Staged
         nodes carry refcount >= 1 (the admission acquired the chain), so
         they cannot be re-spilled before the scatter lands."""
         pairs = state.promoted
@@ -1049,17 +999,12 @@ class ServingEngine:
         row's ``seq_lens`` cursor with exactly the bytes the trie
         published there (bitwise for fp), so published-block immutability
         holds. ``promote_wait`` measures the host time this request's
-        prefill dispatch spends on promotion — with ``promote_async`` the
-        upload was already in flight (scatter dispatch only); without it,
-        the pop + ``device_put`` dispatch are paid here, which is exactly
-        the delta the kv_hierarchy bench pins."""
+        prefill dispatch spends on promotion: the upload is already in
+        flight (:meth:`_start_promotions`), so scatter dispatch only."""
         t0 = time.perf_counter()
         staged = self._staged_promotes.pop(state.request.request_id, None)
         if staged is None:
-            if not state.promoted:
-                return
-            self._start_promotions(state)
-            staged = self._staged_promotes.pop(state.request.request_id)
+            return
         codec, ids, n, uploads = staged
         it = iter(uploads)
 
@@ -1457,7 +1402,7 @@ class ServingEngine:
         AND suffix buckets — one executable per distinct width, shared
         ``_prefill_exe`` table), and (speculation on) the verify graph
         now, so the serving loop's first requests don't pay compile
-        latency (serve_bench calls this before the timed window). The
+        latency (callers run it before any timed window). The
         compile-count pin: ``len(prompt_buckets) + len(suffix_buckets) +
         1`` executables, ``+ 2`` with speculation on — suffix buckets are
         fenced disjoint from prompt buckets, so the arithmetic is exact
@@ -1595,7 +1540,7 @@ class ServingEngine:
         )
         # SLO feed: TTFT (arrival -> first token, queueing included) into
         # the mergeable fleet histogram (telemetry.LatencyHistogram) —
-        # what serve_bench and the FLEET.json report read percentiles from.
+        # what the FLEET.json report reads percentiles from.
         self._tel.hist("ttft").record(now - state.arrival_s)
 
     def _admit_one(self, state: RequestState):
@@ -1712,15 +1657,12 @@ class ServingEngine:
         tel = self._tel
         now = self.clock()
         with tel.span("schedule", step=self.step_count) as sp:
-            admitted = (
-                [] if self.static_batching and self.scheduler.active
-                else self.scheduler.admit(
-                    now, self.bucket_of, max_admit=self.max_prefills,
-                    suffix_bucket_of=(
-                        self.suffix_bucket_of if self.prefix_cache else None
-                    ),
-                    cover_tokens=self.pages * self.block_size,
-                )
+            admitted = self.scheduler.admit(
+                now, self.bucket_of, max_admit=self.max_prefills,
+                suffix_bucket_of=(
+                    self.suffix_bucket_of if self.prefix_cache else None
+                ),
+                cover_tokens=self.pages * self.block_size,
             )
             if admitted:
                 # Request ids discovered inside the span land on its B
@@ -1728,13 +1670,12 @@ class ServingEngine:
                 # decode lifecycle is traceable end-to-end in the merged
                 # Perfetto view.
                 sp.set(request_ids=[s.request.request_id for s in admitted])
-        if self.promote_async:
-            # Kick EVERY admitted state's promote uploads before the
-            # first prefill dispatches: the H2D copies run while earlier
-            # admissions prefill, instead of each waiting for its own
-            # prefill's operand prep (ROADMAP 2b, true async promote).
-            for state in admitted:
-                self._start_promotions(state)
+        # Kick EVERY admitted state's promote uploads before the first
+        # prefill dispatches: the H2D copies run while earlier admissions
+        # prefill, instead of each waiting for its own prefill's operand
+        # prep.
+        for state in admitted:
+            self._start_promotions(state)
         for state in admitted:
             extra = {}
             if self.prefix_cache:
@@ -1828,8 +1769,7 @@ class ServingEngine:
             )
             # Sync INSIDE the span: dispatch is async, and the engine
             # blocks on the sampled tokens either way — the decode span
-            # must charge for that wait or its histogram (the decode-phase
-            # throughput denominator in serve_bench) flatters L=1 steps
+            # must charge for that wait or its histogram flatters L=1 steps
             # relative to the verify path, which must sync to accept.
             tok = self._read_back(out)
         with tel.span("collect", step=self.step_count):

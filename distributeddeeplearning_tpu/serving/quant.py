@@ -72,7 +72,7 @@ def quantize_params(params, block_size: int = 256):
 
     Returns (tree, report): ``tree`` mirrors ``params`` with quantized
     leaves replaced by payload dicts, ``report`` has byte counts for the
-    engine's startup log / BENCH_SERVING.json.
+    engine's ``stats()``.
     """
     orig_bytes = quant_bytes = 0
 
@@ -120,7 +120,7 @@ def dequantize_params(tree):
 
 def quantization_error(params, block_size: int = 256) -> float:
     """Max relative L2 round-trip error across quantized leaves (host-side
-    sanity metric surfaced in BENCH_SERVING.json)."""
+    sanity metric in the engine's ``quant_report``)."""
     worst = 0.0
     for leaf in jax.tree_util.tree_leaves(params):
         if not _should_quantize(leaf, block_size):

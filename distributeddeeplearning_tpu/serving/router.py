@@ -38,7 +38,8 @@ engines stay completely unaware of each other:
   or a queue slot. Admitting it instead would rot in a queue, get
   deadline-dropped engine-side anyway, and meanwhile push every request
   behind it past ITS deadline — shedding is what keeps goodput from
-  collapsing under overload (the 100x rows in BENCH_SERVING.json).
+  collapsing under overload (the shed-vs-no-shed parity and overload
+  tests in ``tests/test_serving_router.py``; not measured on the chip).
 
 - **Elastic membership**: :meth:`drain` cuts one replica's intake
   (in-flight and queued work completes token-identically, new
@@ -246,9 +247,6 @@ class Replica:
 
     def do_warmup(self) -> None:
         self.engine.warmup()
-
-    def set_engine_clock(self, clock) -> None:
-        self.engine.clock = clock
 
     def close(self) -> None:
         pass
@@ -606,9 +604,6 @@ class SocketReplica:
     def do_warmup(self) -> None:
         pass  # workers AOT-compile before reporting worker_ready
 
-    def set_engine_clock(self, clock) -> None:
-        pass  # the worker's clock is its own
-
     def send_op(self, op: str, **fields) -> None:
         net.send_frame(self.sock, {"op": op, **fields})
 
@@ -695,7 +690,7 @@ class ReplicaRouter:
     """
 
     def __init__(self, model, params, cfg, *, clock=time.monotonic,
-                 seed: int = 0, emit=None, static_batching: bool = False,
+                 seed: int = 0, emit=None,
                  telemetry_dir: str | None = None, transports=None):
         n = len(transports) if transports is not None else int(
             getattr(cfg, "replicas", 1)
@@ -705,14 +700,6 @@ class ReplicaRouter:
                 f"serving.replicas must be >= 1, got {n} — 1 serves "
                 "through a single engine, > 1 fronts N replicas with a "
                 "ReplicaRouter"
-            )
-        if static_batching:
-            raise NotImplementedError(
-                f"serving.replicas={n} x static_batching: the "
-                "static-batching baseline exists to isolate ONE engine's "
-                "continuous-batching delta (tools/serve_bench.py) — a "
-                "router in front would re-mix admission policy into the "
-                "measurement. Benchmark static on a single engine."
             )
         self.policy = str(getattr(cfg, "router_policy", "least_loaded"))
         if self.policy not in ROUTER_POLICIES:
@@ -1460,21 +1447,6 @@ class ReplicaRouter:
         merges into FLEET.json."""
         for r in self.replicas:
             r.telemetry.write_trace()
-
-    def set_clock(self, clock, per_replica=None) -> None:
-        """Swap the router clock and every replica engine's clock —
-        benches install an offset/virtual clock after warmup so compile
-        time stays outside the timed window. ``per_replica`` (optional,
-        ``fn(index) -> clock``) gives each replica its OWN clock: the
-        virtual-time N-chip simulation in tools/serve_bench.py."""
-        self.clock = clock
-        # The sweep's pause detector must not read a timebase swap as a
-        # 15-minute router stall (or as instant staleness).
-        self._last_sweep_s = None
-        for r in self.replicas:
-            r.set_engine_clock(
-                per_replica(r.index) if per_replica is not None else clock
-            )
 
     def shutdown_fleet(self, *, wait_s: float = 5.0) -> None:
         """Politely stop every socket worker: send the ``shutdown`` op,

@@ -930,7 +930,7 @@ class RequestState:
         return self.finish_s is not None
 
     def metrics(self) -> dict:
-        """Per-request latency record (serve_bench aggregates these)."""
+        """Per-request latency record (``cli serve`` prints these)."""
         itl = [
             b - a for a, b in zip(self.token_times_s, self.token_times_s[1:])
         ]

@@ -1,9 +1,8 @@
 """Fleet replica worker: one ServingEngine behind one socket.
 
 ``python -m distributeddeeplearning_tpu.serving.worker`` is the child
-process ``cli serve --fleet N`` (and tools/serve_bench.py's fleet block)
-spawns per replica. It builds ONE engine, AOT-warms it, binds a
-listening socket, prints a single ``worker_ready`` JSON line (the parent
+process ``cli serve --fleet N`` spawns per replica. It builds ONE engine,
+AOT-warms it, binds a listening socket, prints a single ``worker_ready`` JSON line (the parent
 parses the port from it), accepts the router's connection, and then runs
 the serve loop:
 
@@ -82,22 +81,13 @@ def armed_fault(scfg, replica_index: int, env=None):
     return fault
 
 
-def check_fleet_composition(cfg, fleet: int, *,
-                            static_batching: bool = False) -> None:
+def check_fleet_composition(cfg, fleet: int) -> None:
     """Config-time fences for ``cli serve --fleet N`` (fail BY NAME
     before any process is spawned). ``cfg`` is a ServingConfig."""
     if fleet < 1:
         raise ValueError(
             f"serve --fleet must be >= 1, got {fleet} — each fleet "
             "worker is one engine process; 0 workers serve nothing"
-        )
-    if static_batching:
-        raise NotImplementedError(
-            f"serve --fleet {fleet} x static_batching: the static-"
-            "batching baseline exists to isolate ONE engine's "
-            "continuous-batching delta — a socket fleet in front would "
-            "re-mix admission policy into the measurement. Benchmark "
-            "static on a single in-process engine."
         )
     host = getattr(cfg, "worker_host", "127.0.0.1")
     if not isinstance(host, str) or not host.strip():
@@ -141,8 +131,8 @@ class ReplicaWorker:
     ``conn`` is the (nonblocking) socket to the router; ``clock`` and
     ``sleep`` are injectable for deterministic tests. ``step_dwell_s``
     adds a wall-clock sleep after every engine step — the CPU sim's
-    stand-in for device program latency (tools/serve_bench.py documents
-    the timebase); 0 (the default) for real use.
+    stand-in for device program latency (tools/serve_chaos.py uses it;
+    ROADMAP D4); 0 (the default) for real use.
 
     Drive it with :meth:`pump` until ``exit_code`` is not None.
     """

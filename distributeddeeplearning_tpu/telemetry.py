@@ -27,8 +27,7 @@ arXiv 2204.06514) applied to this codebase:
 - :class:`DeviceRegistry` — per-executable ``memory_analysis()``
   (argument/output/temp/generated-code bytes), compile wall time, and
   donation/recompile counters for every compiled step/serving program;
-  surfaced by ``benchmark.py`` and ``tools/telemetry_report.py``
-  (TELEMETRY.json).
+  surfaced by ``tools/telemetry_report.py`` (TELEMETRY.json).
 - :func:`dump_flight` — the crash flight recorder: on
   fault/health-rollback/SIGTERM (and supervisor hang/crash kills) the
   last N spans + events are dumped to a quarantine-adjacent file (the
@@ -112,9 +111,8 @@ class LatencyHistogram:
 
     The SLO-grade percentile sketch: ``n`` buckets geometrically spaced
     over ``[lo, hi)`` (out-of-range samples clamp into the edge buckets),
-    so memory is O(n) regardless of sample count — unlike the
-    store-every-sample ``np.percentile`` math it replaces in
-    ``tools/serve_bench.py``. Two invariants the tests pin:
+    so memory is O(n) regardless of sample count — unlike
+    store-every-sample ``np.percentile`` math. Two invariants the tests pin:
 
     - **exact count**: ``sum(counts) == count`` always — a recorded
       sample is never lost to rounding;
@@ -228,7 +226,7 @@ class LatencyHistogram:
         return h
 
     def summary(self) -> dict:
-        """The report-facing digest (FLEET.json / BENCH_SERVING.json)."""
+        """The report-facing digest (FLEET.json)."""
         return {
             "count": self.count,
             "p50_s": _round6(self.percentile(50)),
@@ -1001,8 +999,8 @@ class Telemetry:
         NOTE: the AOT path does NOT share the traced-call executable cache
         on this jax (verified empirically — both directions pay a full
         compile), so this is a REAL extra compile. It belongs in tools that
-        acknowledge the cost (``tools/telemetry_report.py``, benchmark's
-        probe), never in the training hot loop — ``fit`` instead classifies
+        acknowledge the cost (``tools/telemetry_report.py``), never in the
+        training hot loop — ``fit`` instead classifies
         its first cold dispatch as ledger ``compile`` time and registers
         the executable without a memory probe. Once per name: re-entry
         must not re-pay or double-count."""

@@ -2031,7 +2031,7 @@ def fit(
                     # it costs a full SECOND compile), so the honest
                     # accounting is: classify the whole first dispatch as
                     # "compile" and register the executable without a
-                    # memory probe (benchmark.py/telemetry_report own that
+                    # memory probe (tools/telemetry_report.py owns that
                     # probe and its extra compile). Registry presence
                     # doubles as the warm-cache marker, so a health-
                     # rollback re-entry goes back to step accounting.

@@ -85,9 +85,9 @@ def collective_bytes(hlo_text: str, n_devices: int) -> dict[str, list]:
     (for all-gather that is the gathered size; callers apply the per-kind
     ring-cost formula). ``group_size`` comes from ``replica_groups``
     (explicit or iota form); ops without a parsable group default to
-    ``n_devices``. Feeds ``tools/project_scaling.py``'s projected-scaling
-    model (SURVEY §6 hard part #5: multi-chip claims must be labeled
-    projected, with their method inspectable)."""
+    ``n_devices``. The tests of the gradient-sync byte cuts
+    (``tests/helpers.sync_wire_bytes``) count from it; a byte count is not
+    a time (no four-chip cell: PERF.md §7)."""
     out: dict[str, list] = {k: [] for k in COLLECTIVE_KINDS}
     for line in hlo_text.splitlines():
         m = _OP_LINE.search(line)

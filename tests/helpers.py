@@ -116,8 +116,7 @@ def compiled_step_text(trainer, example_batch, mesh, *, spmd: bool = False):
 
 
 def sync_wire_bytes(text: str, n: int) -> float:
-    """Ring-model per-member wire bytes of the dp-group collectives — the
-    same accounting tools/project_scaling.py reports per grad_comm mode.
+    """Ring-model per-member wire bytes of the dp-group collectives.
     Robust to the CPU SPMD emitter's op choices (e.g. reduce-scatter
     lowered as all-reduce + dynamic-slice) because it totals over kinds."""
     from distributeddeeplearning_tpu.utils.hlo import collective_bytes

@@ -612,31 +612,6 @@ def test_router_legal_compositions_pass(kwargs):
     check_serving_composition(cfg)  # must not raise
 
 
-def test_router_rejects_static_batching_by_name():
-    # The router exists to keep lanes busy across replicas; static
-    # batching (admission only into an EMPTY engine) defeats the load
-    # gauges the router balances on. Fenced in the ReplicaRouter ctor —
-    # the flag is an engine-constructor argument, not config, so the
-    # config-level check cannot see it.
-    import jax
-
-    from distributeddeeplearning_tpu import models
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ReplicaRouter
-
-    model = models.get_model(
-        "gpt2", size="tiny", vocab_size=97, max_len=64,
-    )
-    import numpy as np
-    params = model.init(
-        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
-    )["params"]
-    cfg = ServingConfig(slots=2, block_size=4, hbm_budget_mb=8,
-                        max_seq_len=32, prompt_buckets=(8,), replicas=2)
-    with pytest.raises(NotImplementedError, match="static_batching"):
-        ReplicaRouter(model, params, cfg, static_batching=True)
-
-
 # ---------------------------------------------------------------------------
 # Prefix-cache fence matrix (serving.prefix_cache x buckets/batching/policy)
 # ---------------------------------------------------------------------------
@@ -729,31 +704,8 @@ def test_prefix_cache_legal_compositions_pass(kwargs):
     check_serving_composition(cfg)  # must not raise
 
 
-def test_prefix_cache_rejects_static_batching_by_name():
-    # Static batching admits only into an EMPTY engine, so a warm trie
-    # has nothing to overlap against and the suffix executables would be
-    # compiled for a path that cannot pay off. Engine-ctor fence (the
-    # flag is a constructor argument, invisible to the config check).
-    import jax
-    import numpy as np
-
-    from distributeddeeplearning_tpu import models
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ServingEngine
-
-    model = models.get_model("gpt2", size="tiny", vocab_size=97, max_len=64)
-    params = model.init(
-        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
-    )["params"]
-    cfg = ServingConfig(slots=2, block_size=4, hbm_budget_mb=8,
-                        max_seq_len=32, prompt_buckets=(8,),
-                        prefix_cache=True)
-    with pytest.raises(NotImplementedError, match="static_batching"):
-        ServingEngine(model, params, cfg, static_batching=True)
-
-
 # ---------------------------------------------------------------------------
-# Quantized-KV fence matrix (serving.kv_quant x codec/batching)
+# Quantized-KV fence matrix (serving.kv_quant x codec)
 # ---------------------------------------------------------------------------
 
 
@@ -806,66 +758,39 @@ def test_kv_quant_legal_compositions_pass(kwargs):
     check_serving_composition(cfg)  # must not raise
 
 
-def test_kv_quant_rejects_static_batching_by_name():
-    # The static baseline exists as the exact-numerics anchor every
-    # continuous-batching feature is diffed against; a quantized pool
-    # would fold int8 rounding into that anchor. Engine-ctor fence (the
-    # flag is a constructor argument, invisible to the config check).
-    import jax
-    import numpy as np
-
-    from distributeddeeplearning_tpu import models
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ServingEngine
-
-    model = models.get_model("gpt2", size="tiny", vocab_size=97, max_len=64)
-    params = model.init(
-        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
-    )["params"]
-    cfg = ServingConfig(slots=2, block_size=4, hbm_budget_mb=8,
-                        max_seq_len=32, prompt_buckets=(8,),
-                        kv_quant="int8")
-    with pytest.raises(NotImplementedError, match="static_batching"):
-        ServingEngine(model, params, cfg, static_batching=True)
-
-
 # ---------------------------------------------------------------------------
-# Socket fleet fence matrix (cli serve --fleet x batching/ports/heartbeats)
+# Socket fleet fence matrix (cli serve --fleet x ports/heartbeats)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fleet,kwargs,extra,err,match", [
+@pytest.mark.parametrize("fleet,kwargs,err,match", [
     # fleet size bounds name the flag
-    (0, {}, {}, ValueError, "fleet must be >= 1"),
-    (-3, {}, {}, ValueError, "fleet must be >= 1"),
-    # fleet x static_batching: the static baseline is a ONE-engine
-    # measurement — a socket fleet in front re-mixes admission policy
-    (4, {}, dict(static_batching=True), NotImplementedError,
-     "static_batching"),
+    (0, {}, ValueError, "fleet must be >= 1"),
+    (-3, {}, ValueError, "fleet must be >= 1"),
     # endpoint config: bad host/port fail before any process spawns
-    (2, dict(worker_host=""), {}, ValueError, "worker_host"),
-    (2, dict(worker_host="   "), {}, ValueError, "worker_host"),
-    (2, dict(worker_port=-1), {}, ValueError, "worker_port"),
-    (2, dict(worker_port=70000), {}, ValueError, "worker_port"),
+    (2, dict(worker_host=""), ValueError, "worker_host"),
+    (2, dict(worker_host="   "), ValueError, "worker_host"),
+    (2, dict(worker_port=-1), ValueError, "worker_port"),
+    (2, dict(worker_port=70000), ValueError, "worker_port"),
     # worker i binds worker_port + i: the last worker must not overflow
-    (4, dict(worker_port=65534), {}, ValueError, "worker_port"),
+    (4, dict(worker_port=65534), ValueError, "worker_port"),
     # heartbeat cadence: the router's policies run on pushed state — a
     # worker that never heartbeats is permanently stale
-    (2, dict(heartbeat_interval_s=0.0), {}, ValueError,
+    (2, dict(heartbeat_interval_s=0.0), ValueError,
      "heartbeat_interval_s"),
-    (2, dict(heartbeat_interval_s=-1.0), {}, ValueError,
+    (2, dict(heartbeat_interval_s=-1.0), ValueError,
      "heartbeat_interval_s"),
     # a timeout under one interval quarantines healthy workers
-    (2, dict(heartbeat_interval_s=0.5, heartbeat_timeout_s=0.25), {},
+    (2, dict(heartbeat_interval_s=0.5, heartbeat_timeout_s=0.25),
      ValueError, "heartbeat_timeout_s"),
 ])
-def test_fleet_fence_matrix(fleet, kwargs, extra, err, match):
+def test_fleet_fence_matrix(fleet, kwargs, err, match):
     from distributeddeeplearning_tpu.config import ServingConfig
     from distributeddeeplearning_tpu.serving import check_fleet_composition
 
     cfg = ServingConfig(**kwargs)
     with pytest.raises(err, match=match):
-        check_fleet_composition(cfg, fleet, **extra)
+        check_fleet_composition(cfg, fleet)
 
 
 @pytest.mark.parametrize("fleet,kwargs", [
@@ -874,8 +799,7 @@ def test_fleet_fence_matrix(fleet, kwargs, extra, err, match):
     (2, dict(heartbeat_timeout_s=0.0)),  # 0 = staleness sweep disabled
     # the capability compositions the fleet must keep serving: affinity
     # needs the trie, quant and speculation are per-engine features the
-    # transport never sees (parity pinned in tests/test_serving_worker.py
-    # and the serve_bench fleet block)
+    # transport never sees (parity pinned in tests/test_serving_worker.py)
     (4, dict(prefix_cache=True, router_policy="prefix_affinity")),
     (2, dict(kv_quant="int8")),
     (2, dict(speculation="ngram:3")),
@@ -1042,28 +966,6 @@ def test_disagg_legal_compositions_pass(kwargs, fleet):
         serving=ServingConfig(**kwargs),
     )
     check_serving_composition(cfg, fleet=fleet)  # must not raise
-
-
-def test_role_split_engine_rejects_static_batching_by_name():
-    # The static baseline forms whole batches in ONE engine: there is no
-    # phase boundary to split. Fenced in the engine ctor because tests
-    # build engines directly from a ServingConfig.
-    import jax
-    import numpy as np
-
-    from distributeddeeplearning_tpu import models
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ServingEngine
-
-    model = models.get_model("gpt2", size="tiny", vocab_size=97, max_len=64)
-    params = model.init(
-        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
-    )["params"]
-    cfg = ServingConfig(slots=2, block_size=4, hbm_budget_mb=8,
-                        max_seq_len=32, prompt_buckets=(8,),
-                        prefix_cache=True, role="prefill")
-    with pytest.raises(NotImplementedError, match="static_batching"):
-        ServingEngine(model, params, cfg, static_batching=True)
 
 
 @pytest.mark.parametrize("roles,match", [

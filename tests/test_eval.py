@@ -1,4 +1,4 @@
-"""Evaluation path (VERDICT.md round-1 missing #3): ``eval_every`` drives a
+"""Evaluation path (VERDICT round-1 missing #3): ``eval_every`` drives a
 real eval loop inside ``fit``, ``evaluate`` reports top-1 accuracy for the
 vision tasks (``BASELINE.json:2`` "top-1 parity"), and the ``eval`` CLI
 subcommand works standalone.

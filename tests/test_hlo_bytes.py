@@ -1,5 +1,5 @@
-"""Unit tests for ``utils/hlo.collective_bytes`` — the byte counter feeding
-the projected-scaling model (tools/project_scaling.py). Synthetic HLO lines
+"""Unit tests for ``utils/hlo.collective_bytes`` — the byte counter under
+the gradient-sync byte-cut tests. Synthetic HLO lines
 mirror the forms observed in real compiled programs (sync tuple all-reduces
 with ``/*index=N*/`` comments, async -start/-done pairs, iota and explicit
 replica groups)."""

@@ -412,42 +412,3 @@ def test_int8_grad_comm_keeps_fp32_residual_under_bf16():
     np.testing.assert_allclose(lossy, plain, atol=2e-2)
     for leaf in jax.tree.leaves(state.grad_residual):
         assert leaf.dtype == jnp.float32, leaf.dtype
-
-
-def test_bench_mixed_precision_artifact():
-    # The committed per-policy benchmark artifact (ISSUE 5 acceptance bar;
-    # regenerate with tools/bench_mixed_precision.py): every policy row
-    # carries throughput + latency + measured per-member state bytes, and
-    # bf16_full shows the >= 3x param+opt-state reduction.
-    import json
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "BENCH_MIXED_PRECISION.json",
-    )
-    if not os.path.exists(path):
-        pytest.skip("BENCH_MIXED_PRECISION.json not yet generated")
-    with open(path) as f:
-        rec = json.load(f)
-    assert rec["bf16_full_state_reduction_met"] is True
-    assert rec["state_bytes_reduction_vs_fp32"]["bf16_full"] >= 3.0
-    assert rec["state_bytes_reduction_vs_fp32"]["bf16"] > 2.0
-    assert rec["grad_sync_reduction_vs_fp32"]["bf16"] == pytest.approx(
-        2.0, rel=0.01
-    )
-    for pol in ("fp32", "bf16", "bf16_full"):
-        row = rec["policies"][pol]
-        assert row["steps_per_sec"] > 0
-        assert row["p90_step_ms"] >= row["p50_step_ms"] > 0
-        assert np.isfinite(row["loss"])
-        assert row["state_bytes_per_member"] == (
-            row["param_bytes_per_member"] + row["opt_state_bytes_per_member"]
-        )
-    # Monotone: each policy strictly cuts durable state vs the previous.
-    sizes = [rec["policies"][p]["state_bytes_per_member"]
-             for p in ("fp32", "bf16", "bf16_full")]
-    assert sizes[0] > sizes[1] > sizes[2] > 0
-    # The closed-form projection the acceptance bar names: 5x at N=8.
-    at_n8 = rec["modeled"]["resident_state_bytes_per_param_per_member"]["at_n8"]
-    assert at_n8["fp32"] / at_n8["bf16_full"] >= 3.0

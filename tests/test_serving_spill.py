@@ -17,8 +17,7 @@ telemetry (stats keys, promote_wait histogram), and the constrain_pool
 bench hook's guards.
 
 Three-tier soak + gauges live in tests/test_serving_units.py; config
-fences in tests/test_composition_fences.py; the committed capacity
-headline in BENCH_SERVING.json (tools/serve_bench.py kv_hierarchy).
+fences in tests/test_composition_fences.py.
 """
 
 import dataclasses
@@ -469,6 +468,7 @@ def _warm_suffix_logits(model, params, codec):
         cover_tokens=eng.pages * eng.block_size,
     )
     assert st.promoted, "warm admission did not cross the host tier"
+    eng._start_promotions(st)
     eng._apply_promotions(st)
     row = np.zeros((eng.pages,), np.int32)
     chain = st.cached_blocks + st.blocks
@@ -487,8 +487,8 @@ def _warm_suffix_logits(model, params, codec):
 
 def test_int8_promote_within_logit_tolerance(gpt2):
     # The codec bar: int8-promoted KV may move the next-token logits by
-    # at most 5% of the fp logits' dynamic range (the pinned tolerance
-    # BENCH_SERVING.json commits). fp is the bitwise reference.
+    # at most 5% of the fp logits' dynamic range. fp is the bitwise
+    # reference.
     model, params = gpt2
     ref = _warm_suffix_logits(model, params, "fp")
     quant = _warm_suffix_logits(model, params, "int8")
@@ -555,12 +555,6 @@ def test_constrain_pool_guards(gpt2):
     eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
     with pytest.raises(RuntimeError, match="in flight"):
         eng.constrain_pool(8)
-
-
-def test_static_batching_rejects_spill_by_name(gpt2):
-    model, params = gpt2
-    with pytest.raises(NotImplementedError, match="static_batching"):
-        ServingEngine(model, params, _CFG, static_batching=True)
 
 
 # ---------------------------------------------------------------------------

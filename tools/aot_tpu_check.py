@@ -12,8 +12,7 @@ kernels entirely on the host CPU. Per config this records:
   - ``ok``: the TPU lowering compiles at FULL model/batch size;
   - ``collectives``: payload bytes by kind from the TPU HLO
     (``utils/hlo.collective_bytes``) — unlike the CPU SPMD emitter, the
-    TPU pipeline emits true reduce-scatters and async-start forms, so
-    this is the authoritative input for PROJECTED_SCALING's comm model;
+    TPU pipeline emits true reduce-scatters and async-start forms;
   - ``memory``: XLA's ``compiled.memory_analysis()`` — argument/output/
     temp/code bytes, i.e. the compiler's own HBM budget. This decides
     feasibility questions (VERDICT r4 Weak #5: "will the batch-512 MFU
@@ -184,8 +183,7 @@ def _compile_row(cfg_name: str, overrides: list, devices) -> dict:
             k: sum(b for b, _ in v) for k, v in cb.items() if v
         },
         # FULL-mesh-group traffic (the dp/fsdp axes on these compiles) vs
-        # tp/ep/cp subgroup ops — the split tools/project_scaling.py's
-        # comm model consumes, from the AUTHORITATIVE TPU lowering (the
+        # tp/ep/cp subgroup ops, from the AUTHORITATIVE TPU lowering (the
         # CPU SPMD emitter lowers reduce-scatter as all-reduce and keeps
         # fp32 where the TPU pipeline syncs bf16). Caveat: permutes carry
         # no replica_groups and default to full-mesh, so rows whose mesh

@@ -22,9 +22,8 @@ via ``$DDL_SERVE_FAULT_WORKER``):
   attempt epoch, so any late result frames from the stalled attempt
   are discarded by epoch — never double-delivered.
 
-Every run drives the same two-wave shared-prefix workload (the
-prefix-cache + spill-tier shape from tools/serve_bench.py, device pool
-constrained below the prefix working set so the spill tier is hot) over
+Every run drives the same two-wave shared-prefix workload (prefix
+cache + spill tier, device pool constrained below the prefix working set so the spill tier is hot) over
 a 2-worker fleet, waits for the supervisor to detect + restart, then
 submits wave B so the restarted worker serves real post-recovery load
 from its re-warmed cache. Pins per run:

@@ -1,4 +1,4 @@
-"""Telemetry self-measurement -> TELEMETRY.json + BENCH_TELEMETRY.json.
+"""Telemetry self-measurement -> TELEMETRY.json + FLEET.json.
 
 Two questions the telemetry subsystem (telemetry.py, docs/
 OBSERVABILITY.md) must answer about ITSELF, measured on the 8-device
@@ -9,9 +9,7 @@ CPU sim with a real ``fit`` loop (GPT-2 tiny, adamw, synthetic tokens):
    disabled/enabled segments through ONE warm process (same jit cache,
    same dataset), median over segments. The acceptance bar is
    ``overhead_fraction <= 0.02`` of steps/s — telemetry that slows the
-   loop isn't observability, it's interference. The headline lands in
-   BENCH_TELEMETRY.json so tools/bench_report.py folds it into
-   BENCH_TRAJECTORY.json.
+   loop isn't observability, it's interference.
 
 2. **What does it see?** One enabled run's artifacts, verified: the
    Chrome trace is structurally valid (``validate_chrome_trace``), the
@@ -42,7 +40,7 @@ describes would be interference, same principle as the loop overhead).
 
 Usage: python tools/telemetry_report.py            (measure + write)
        python tools/telemetry_report.py --check    (validate committed)
-Env: $DDL_TELEMETRY_OUT / $DDL_TELEMETRY_BENCH_OUT / $DDL_FLEET_OUT
+Env: $DDL_TELEMETRY_OUT / $DDL_FLEET_OUT
 override the output paths; $DDL_TELEMETRY_STEPS sets the per-segment
 step count; DDL_TELEMETRY_SHRINK=1 is the CI dry-run (short segments).
 """
@@ -67,9 +65,6 @@ _SHRINK = os.environ.get("DDL_TELEMETRY_SHRINK") == "1"
 _OUT = os.environ.get(
     "DDL_TELEMETRY_OUT", os.path.join(_REPO, "TELEMETRY.json")
 )
-_BENCH_OUT = os.environ.get(
-    "DDL_TELEMETRY_BENCH_OUT", os.path.join(_REPO, "BENCH_TELEMETRY.json")
-)
 _SEG_STEPS = int(os.environ.get(
     "DDL_TELEMETRY_STEPS", "16" if _SHRINK else "32"
 ))
@@ -88,10 +83,9 @@ _FLEET_SUM_TOL = 1e-5
 
 
 def _workload():
-    """(trainer, dataset, state) — GPT-2 tiny on synthetic tokens, the
-    same cheap-step workload the other bench tools use (dispatch-bound,
-    so per-step host overhead is MAXIMALLY visible — an honest worst
-    case for the overhead bar)."""
+    """(trainer, dataset, state) — GPT-2 tiny on synthetic tokens, a
+    cheap-step workload (dispatch-bound, so per-step host overhead is
+    MAXIMALLY visible — an honest worst case for the overhead bar)."""
     import jax
 
     from distributeddeeplearning_tpu import data as data_lib
@@ -144,7 +138,7 @@ def _median(vals):
 
 
 def measure() -> tuple[dict, dict]:
-    """(telemetry_artifact, bench_artifact) — raises on any failed
+    """(telemetry_artifact, fleet_artifact) — raises on any failed
     internal check so main() can refuse to write."""
     import jax
 
@@ -280,22 +274,7 @@ def measure() -> tuple[dict, dict]:
         "fleet": {**fleet_run, "headline": fleet["headline"]},
         "utc": utc,
     }
-    bench_art = {
-        "ok": True,
-        "n": _SEGMENTS,
-        "steps_per_sec": round(enabled_sps, 4),
-        "disabled_steps_per_sec": round(disabled_sps, 4),
-        "enabled_steps_per_sec": round(enabled_sps, 4),
-        "overhead_fraction": round(overhead, 6),
-        "aggregation_overhead_fraction":
-            fleet_run["aggregation_overhead_fraction"],
-        "pod_goodput_fraction": fleet["headline"]["pod_goodput_fraction"],
-        "max_step_skew_s": fleet["headline"]["max_step_skew_s"],
-        "shrunk": _SHRINK,
-        "workload": telemetry_art["workload"],
-        "utc": utc,
-    }
-    return telemetry_art, bench_art, fleet
+    return telemetry_art, fleet
 
 
 _FLEET_CFG = '''\
@@ -527,18 +506,17 @@ def main(argv=None) -> int:
         print(f"{_OUT} and {_FLEET_OUT} valid")
         return 0
     try:
-        telemetry_art, bench_art, fleet = measure()
+        telemetry_art, fleet = measure()
     except Exception as e:
         # Refuse to clobber committed artifacts with a failed run.
         print(f"measurement FAILED ({type(e).__name__}: {e}); leaving "
-              f"{_OUT}, {_BENCH_OUT} and {_FLEET_OUT} untouched",
+              f"{_OUT} and {_FLEET_OUT} untouched",
               file=sys.stderr)
         raise
     _write(_OUT, telemetry_art)
-    _write(_BENCH_OUT, bench_art)
     _write(_FLEET_OUT, fleet)
     ov = telemetry_art["overhead"]
-    print(f"wrote {_OUT}, {_BENCH_OUT} and {_FLEET_OUT} "
+    print(f"wrote {_OUT} and {_FLEET_OUT} "
           f"(overhead_fraction={ov['overhead_fraction']}, "
           f"enabled {ov['enabled_steps_per_sec']} vs disabled "
           f"{ov['disabled_steps_per_sec']} steps/s; pod goodput "
