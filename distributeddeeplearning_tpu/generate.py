@@ -51,7 +51,11 @@ def _filter_logits(logits, top_k, top_p):
     temperature sharpens). Both knobs are TRACED operands (0 = off) —
     scalars (generate: one setting per batch) or [B] vectors (serving
     engine: per-request sampling params in one decode batch) — sharing one
-    descending sort, so sweeping them never recompiles."""
+    descending sort, so sweeping them never recompiles. With both knobs 0
+    a row comes back as it went in (nothing lies below -inf), after paying
+    for the sort of the whole vocabulary all the same: callers that can
+    tell beforehand do not call it (``_make_pick``'s static ``filtered``;
+    the serving engine's ``filtered`` arm, ``engine.sampler_arm``)."""
     B, V = logits.shape
     sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
     # top-k threshold: the kth-largest logit (clamped into [1, V] so an

@@ -29,10 +29,17 @@ and injected by leaf name into the cache pytree before every call, so the
 device-side cursor copies are write-only.
 
 Sampling is per-REQUEST inside the compiled graphs: temperature / top_k /
-top_p ride as [B] operands through the per-row ``generate._filter_logits``
-(0 = off / greedy), and each lane carries its own PRNG key chain
-(``fold_in(seed, request_id)``), so one decode batch can mix greedy and
-sampled requests and a request's tokens do not depend on its batchmates.
+top_p ride as [B] operands (0 = off / greedy), and each lane carries its
+own PRNG key chain (``fold_in(seed, request_id)``), so one decode batch
+can mix greedy and sampled requests and a request's tokens do not depend
+on its batchmates. Every program ends in ONE conditional over three
+samplers, chosen on the device from those operands by :func:`sampler_arm`
+(the rule ``generate._make_pick`` applies with static flags): ``greedy``
+(no lane samples: the argmax alone, ``rng`` untouched), ``plain`` (some
+lane samples, none of them filters: temper, split, draw) and ``filtered``
+(the per-row ``generate._filter_logits`` and its whole-vocabulary sort
+before the draw). The host counts the arm of every call from its own
+copy of the operands (``stats()["sampler"]``).
 
 With ``serving.speculation='ngram:K'`` a THIRD program joins the pair: a
 **verify** executable that scores K+1 positions per lane in one batched
@@ -92,11 +99,13 @@ no new compiled bodies, the compile pin above is unchanged.
 
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..comms_quant import block_dequantize, block_quantize
 from ..generate import (
@@ -127,6 +136,24 @@ _HOST_LEAVES = ("page_table", "seq_lens")
 # Per-layer counters a served program hands back with its tokens: tokens
 # routed to each expert by that call (models/glm4_moe_lite.RoutedExperts).
 _LOAD_LEAF = "expert_load"
+
+# The samplers a served program ends in, cheapest first; sampler_arm
+# indexes them.
+SAMPLER_ARMS = ("greedy", "plain", "filtered")
+
+
+def sampler_arm(temp, top_k, top_p):
+    """Index into :data:`SAMPLER_ARMS` of the cheapest sampler that serves
+    every lane of one call: 0 where no lane samples (``temp > 0``), 1 where
+    some do and none of THOSE filters (``top_k > 0`` or ``top_p > 0``; a
+    greedy lane's filters are never applied), else 2. One rule for both
+    sides: the compiled programs call it on their traced [B] operands, the
+    host on the NumPy arrays it passes as those operands, so the counters
+    in ``stats()["sampler"]`` say what the device ran."""
+    sampling = temp > 0
+    filtering = sampling & ((top_k > 0) | (top_p > 0))
+    return sampling.any().astype(np.int32) + filtering.any().astype(np.int32)
+
 
 # serving.kv_quant domain: device pool storage codecs.
 KV_QUANT_MODES = ("off", "int8")
@@ -837,6 +864,9 @@ class ServingEngine:
         self._verify_exe = None
         self.num_compiles = 0
         self.calls = {"prefill": 0, "decode": 0, "verify": 0}
+        # Sampler arm taken by each prefill and decode call (a verify call
+        # has no sampler): sums to calls["prefill"] + calls["decode"].
+        self.sampler = dict.fromkeys(SAMPLER_ARMS, 0)
         # Speculation yield counters (stats()):
         # drafted = draft tokens offered to verify, draft_hits = drafted
         # tokens accepted, emitted = tokens emitted by verify steps (hits
@@ -1267,13 +1297,39 @@ class ServingEngine:
     # ------------------------------------------------------------------
 
     def _sample_body(self, logits, rng, temp, top_k, top_p):
-        greedy = jnp.argmax(logits, axis=-1)
-        tempered = logits / jnp.where(temp > 0, temp, 1.0)[:, None]
-        filtered = _filter_logits(tempered, top_k, top_p)
-        split = jax.vmap(jax.random.split)(rng)  # [B, 2, 2]
-        sampled = jax.vmap(jax.random.categorical)(split[:, 0], filtered)
-        tok = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
-        return tok, split[:, 1]
+        """Tokens [B] and the advanced ``rng`` [B, 2], through the
+        cheapest arm :func:`sampler_arm` allows; the chip runs only the
+        taken one. Every arm gives every lane the token the ``filtered``
+        arm (the whole of this body before PR 29) would: a greedy lane's
+        token is the same argmax in all three; ``_filter_logits`` with
+        ``top_k = top_p = 0`` returns its input, so ``plain`` and
+        ``filtered`` draw alike where both apply; and a sampling lane's
+        ``rng`` row splits in every call that holds it, because a call
+        that holds it takes a sampling arm. The calls in which ``greedy``
+        left a row unsplit cannot reach a later request: admission
+        re-seeds the row (``fold_in(seed, request_id)``, ``_admit_one``),
+        and a lane that retires has its ``_temp`` zeroed, so a stale lane
+        neither draws nor holds a slow arm."""
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def draw(rng, filtered):
+            tempered = logits / jnp.where(temp > 0, temp, 1.0)[:, None]
+            if filtered:
+                tempered = _filter_logits(tempered, top_k, top_p)
+            split = jax.vmap(jax.random.split)(rng)  # [B, 2, 2]
+            sampled = jax.vmap(jax.random.categorical)(split[:, 0], tempered)
+            tok = jnp.where(temp > 0, sampled, greedy)
+            return tok.astype(jnp.int32), split[:, 1]
+
+        return lax.switch(
+            sampler_arm(temp, top_k, top_p),
+            (
+                lambda rng: (greedy, rng),
+                functools.partial(draw, filtered=False),
+                functools.partial(draw, filtered=True),
+            ),
+            rng,
+        )
 
     @staticmethod
     def _expert_load(cache):
@@ -1602,6 +1658,7 @@ class ServingEngine:
             tk = np.int32([req.top_k])
             tp = np.float32([req.top_p])
             pos = np.int32([len(suffix) - 1])
+            arm = SAMPLER_ARMS[int(sampler_arm(temp, tk, tp))]
             exe = self._prefill_exe_for(P)
             # The SAME bulk-prefill body starts at any offset: positions, the
             # causal mask, and the KV scatter all derive from the injected
@@ -1613,6 +1670,7 @@ class ServingEngine:
             self._params, cache1, tokens, pos, rng, temp, tk, tp
         )
         self.calls["prefill"] += 1
+        self.sampler[arm] += 1
         self._fold_pools(cache1)
         if self.role == "prefill":
             # Prefill-only completion: publish the prompt's blocks (KV
@@ -1709,6 +1767,8 @@ class ServingEngine:
                 gauges["spec_accept_rate"] = round(
                     self.spec["draft_hits"] / self.spec["drafted"], 4
                 )
+            for name, n in self.sampler.items():
+                gauges[f"sampler_{name}"] = n  # running counts of calls
             rec = serving_gauges(self.step_count, **gauges)
             self._emit(rec)
             tel.note_event(rec)
@@ -1761,7 +1821,11 @@ class ServingEngine:
         non-speculative hot path, and the speculative engine's fallback on
         steps where no lane produced a draft."""
         tel = self._tel
-        cacheS, decode_args = self._decode_operands(active)
+        # The host's copy of the rule, on the arrays the call gets below.
+        arm = SAMPLER_ARMS[
+            int(sampler_arm(self._temp, self._top_k, self._top_p))
+        ]
+        cacheS, decode_args = self._decode_operands(active, sampler=arm)
         with tel.span("decode", **decode_args):
             out, rng, cacheS = self._decode_exe_or_compile()(
                 self._params, cacheS, self._tok[:, None], self._rng,
@@ -1774,6 +1838,7 @@ class ServingEngine:
             tok = self._read_back(out)
         with tel.span("collect", step=self.step_count):
             self.calls["decode"] += 1
+            self.sampler[arm] += 1
             self._cache = cacheS
             # np.array (copy): rows must stay writable for the next
             # admission.
@@ -1890,6 +1955,7 @@ class ServingEngine:
             "suffix_buckets": list(self.suffix_buckets),
             "num_compiles": self.num_compiles,
             "calls": dict(self.calls),
+            "sampler": dict(self.sampler),
             "steps": self.step_count,
             "quant": self.quant_report,
             "kv_quant": self.kv_quant,

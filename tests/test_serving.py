@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from distributeddeeplearning_tpu import models
 from distributeddeeplearning_tpu.config import (
@@ -17,11 +18,16 @@ from distributeddeeplearning_tpu.config import (
     ServingConfig,
     apply_overrides,
 )
-from distributeddeeplearning_tpu.generate import generate, pad_prompts
+from distributeddeeplearning_tpu.generate import (
+    _filter_logits, generate, pad_prompts,
+)
 from distributeddeeplearning_tpu.serving import (
     Request,
     ServingEngine,
     check_serving_composition,
+)
+from distributeddeeplearning_tpu.serving.engine import (
+    SAMPLER_ARMS, sampler_arm,
 )
 
 _CFG = ServingConfig(
@@ -224,6 +230,233 @@ def test_greedy_and_sampled_mix_in_one_batch():
     eng.run()
     # the greedy lane is untouched by its sampled batchmates
     assert greedy.generated == list(ref)
+
+
+# ---------------------------------------------------------------------------
+# One conditional, three samplers (PR 29): the step picks the cheapest arm
+# its lanes allow, and every arm gives the straight-line body's tokens
+# ---------------------------------------------------------------------------
+
+
+def _straight_line_sample_body(self, logits, rng, temp, top_k, top_p):
+    """The whole of ``ServingEngine._sample_body`` before PR 29, kept as
+    the oracle: sort, filter and draw for every lane of every call, then
+    keep the argmax for the greedy ones."""
+    greedy = jnp.argmax(logits, axis=-1)
+    tempered = logits / jnp.where(temp > 0, temp, 1.0)[:, None]
+    filtered = _filter_logits(tempered, top_k, top_p)
+    split = jax.vmap(jax.random.split)(rng)  # [B, 2, 2]
+    sampled = jax.vmap(jax.random.categorical)(split[:, 0], filtered)
+    tok = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+    return tok, split[:, 1]
+
+
+def _arm_by_hand(temp, top_k, top_p):
+    """The rule of ``engine.sampler_arm`` lane by lane, in plain Python."""
+    lanes = [(k, p) for t, k, p in zip(temp, top_k, top_p) if t > 0]
+    if not lanes:
+        return "greedy"
+    return "filtered" if any(k > 0 or p > 0 for k, p in lanes) else "plain"
+
+
+def _spy_on_programs(eng):
+    """Compile every program and wrap each, so that the test sees the
+    (temp, top_k, top_p) operands of every call the engine makes: the
+    sampling operands are the last three of both signatures."""
+    seen = []
+
+    def spy(exe):
+        def call(*args):
+            seen.append(tuple(np.array(a) for a in args[-3:]))
+            return exe(*args)
+        return call
+
+    eng.warmup()
+    eng._decode_exe = spy(eng._decode_exe)
+    eng._prefill_exe = {b: spy(e) for b, e in eng._prefill_exe.items()}
+    return seen
+
+
+_GREEDY, _PLAIN = {}, {"temperature": 0.9}
+_TOP_K = {"temperature": 1.1, "top_k": 7}
+_TOP_P = {"temperature": 0.7, "top_p": 0.8}
+# Five requests over three lanes each: lanes retire and refill, so a mix
+# passes through several arms and a lane changes its kind of request.
+_SAMPLER_MIXES = {
+    "all_greedy": [_GREEDY] * 5,
+    "all_plain": [_PLAIN, {"temperature": 0.5}, _PLAIN, _PLAIN, _PLAIN],
+    "all_filtered": [_TOP_K, _TOP_P, {**_TOP_K, "top_p": 0.9}, _TOP_P,
+                     _TOP_K],
+    "mixed": [_GREEDY, _PLAIN, _TOP_K, _GREEDY, _TOP_P],
+    # a greedy lane's filters are never applied, so they choose no arm
+    "greedy_with_filters": [{"top_k": 5}, {"top_p": 0.5}, _PLAIN,
+                            {"top_k": 3, "top_p": 0.9}, _GREEDY],
+}
+
+
+def _serve_mix(model, params, mix, vocab, cfg=_CFG):
+    eng = _engine(model, params, cfg, seed=3)
+    seen = _spy_on_programs(eng)
+    rng = np.random.default_rng(11)
+    states = [
+        eng.submit(Request(
+            prompt=list(map(int, rng.integers(1, vocab, 4 + 3 * i))),
+            max_new_tokens=4 + 2 * (i % 3), **kw,
+        ))
+        for i, kw in enumerate(mix)
+    ]
+    eng.run()
+    return eng, seen, [list(st.generated) for st in states]
+
+
+@pytest.mark.parametrize("mix", list(_SAMPLER_MIXES))
+@pytest.mark.parametrize("name", ["gpt2", "glm4_moe_lite"])
+def test_sampler_arms_give_the_straight_line_bodys_tokens(
+        name, mix, monkeypatch):
+    if name == "gpt2":
+        (model, params), vocab = _model_and_params(name), 97
+    else:
+        model = models.get_model(name, size="tiny")
+        params = model.init(
+            jax.random.PRNGKey(7), np.zeros((1, 8), np.int32)
+        )["params"]
+        vocab = 256
+    eng, seen, got = _serve_mix(model, params, _SAMPLER_MIXES[mix], vocab)
+    # The counters say which arm each call's OPERANDS select, call by call.
+    want = dict.fromkeys(SAMPLER_ARMS, 0)
+    for operands in seen:
+        want[_arm_by_hand(*operands)] += 1
+    st = eng.stats()
+    assert st["sampler"] == want
+    assert sum(want.values()) == (
+        st["calls"]["prefill"] + st["calls"]["decode"]
+    ) == len(seen)
+    if mix == "all_greedy":
+        assert want["plain"] == want["filtered"] == 0
+    elif mix in ("all_plain", "greedy_with_filters"):
+        assert want["plain"] > 0 and want["filtered"] == 0
+    elif mix == "all_filtered":
+        assert want["greedy"] == want["plain"] == 0
+    else:
+        assert all(want.values()), want
+    # Token for token what the straight-line body gives, sampled or greedy.
+    monkeypatch.setattr(
+        ServingEngine, "_sample_body", _straight_line_sample_body
+    )
+    _, _, oracle = _serve_mix(model, params, _SAMPLER_MIXES[mix], vocab)
+    assert got == oracle
+
+
+def test_sampler_arm_is_one_rule_for_host_and_program():
+    cases = [
+        ([0, 0, 0], [0, 0, 0], [0, 0, 0]),
+        ([0, 0, 0], [4, 0, 0], [0, .5, 0]),
+        ([0, .7, 0], [4, 0, 0], [0, 0, .5]),
+        ([0, .7, 0], [0, 3, 0], [0, 0, 0]),
+        ([.2, .7, 1.], [0, 0, 0], [0, .9, 0]),
+    ]
+    for temp, top_k, top_p in cases:
+        t, k, p = np.float32(temp), np.int32(top_k), np.float32(top_p)
+        arm = int(sampler_arm(t, k, p))
+        assert SAMPLER_ARMS[arm] == _arm_by_hand(t, k, p)
+        assert int(jax.jit(sampler_arm)(t, k, p)) == arm
+
+
+def test_sampled_request_after_greedy_steps_draws_as_on_a_fresh_engine():
+    # The greedy arm leaves the rng rows unsplit, where the straight-line
+    # body split every row every call: a lane's chain must not depend on
+    # how many such calls went before, because admission re-seeds it.
+    model, params = _model_and_params("gpt2")
+    cfg = dataclasses.replace(_CFG, slots=1)
+    sampled = dict(prompt=_prompts((6,))[0], max_new_tokens=8,
+                   temperature=0.9, top_k=11, request_id=9)
+    fresh = _engine(model, params, cfg, seed=5)
+    want = fresh.submit(Request(**sampled))
+    fresh.run()
+
+    eng = _engine(model, params, cfg, seed=5)
+    eng.submit(Request(prompt=_prompts((5,))[0], max_new_tokens=7))
+    eng.run()
+    assert eng.stats()["sampler"] == {"greedy": 7, "plain": 0, "filtered": 0}
+    got = eng.submit(Request(**sampled))  # the one lane again
+    eng.run()
+    assert got.slot == 0 and got.generated == want.generated
+    assert eng.stats()["sampler"]["filtered"] == 8
+
+
+@pytest.mark.parametrize("path", [
+    "max_new_in_decode", "max_new_in_prefill", "eos", "handoff",
+    "handoff_decode_route",
+])
+def test_freed_sampled_lane_returns_the_engine_to_the_greedy_arm(path):
+    # Every way a lane is freed (scheduler.complete from a decode step or
+    # from the prefill itself, on the token count or on EOS;
+    # scheduler.complete_handoff after a prefill or on a full-prefix hit)
+    # zeroes the lane's temperature, so a stale lane cannot hold the next
+    # call on a sampling arm.
+    model, params = _model_and_params("gpt2")
+    cfg, kw = dataclasses.replace(_CFG, slots=2), {}
+    sampled = dict(prompt=_prompts((6,))[0], max_new_tokens=4,
+                   temperature=0.9, top_p=0.8, request_id=1)
+    if path == "max_new_in_prefill":
+        sampled["max_new_tokens"] = 1
+    elif path == "eos":
+        probe = _engine(model, params, cfg)
+        toks = probe.submit(Request(**{**sampled, "max_new_tokens": 12}))
+        probe.run()
+        sampled["max_new_tokens"] = 12
+        kw["eos_id"] = toks.generated[2]
+        assert kw["eos_id"] not in toks.generated[:2]
+    elif path.startswith("handoff"):
+        kw.update(role="prefill", prefix_cache=True)
+    eng = _engine(model, params, dataclasses.replace(cfg, **kw))
+    if path == "handoff_decode_route":
+        # The same prompt first: the second admission is a full-prefix hit
+        # and hands off without a forward pass.
+        eng.submit(Request(**{**sampled, "request_id": 0}))
+        eng.run()
+    st = eng.submit(Request(**sampled))
+    if not path.startswith("handoff"):
+        eng.submit(Request(prompt=_prompts((7,))[0], max_new_tokens=16,
+                           request_id=2))
+    for _ in range(40):
+        if st.done or eng._handoffs:
+            break
+        eng.step()
+    assert st.done or eng._handoffs
+    assert not eng._temp.any()
+    if path.startswith("handoff"):
+        assert len(eng.take_handoffs()) == 1
+        return
+    if path == "eos":
+        assert st.generated[-1] == kw["eos_id"] and len(st.generated) == 3
+    before = eng.stats()["sampler"]
+    assert before["filtered"] > 0
+    assert eng.step()  # the greedy batchmate decodes on
+    after = eng.stats()["sampler"]
+    assert after == {**before, "greedy": before["greedy"] + 1}
+
+
+def test_sampler_arm_rides_the_decode_span_and_the_gauges(tmp_path):
+    from distributeddeeplearning_tpu.telemetry import Telemetry
+
+    model, params = _model_and_params("gpt2")
+    tel = Telemetry(enabled=True, out_dir=str(tmp_path))
+    eng = _engine(model, params, dataclasses.replace(_CFG, gauge_every=1),
+                  telemetry=tel)
+    eng.submit(Request(prompt=_prompts((5,))[0], max_new_tokens=6))
+    eng.submit(Request(prompt=_prompts((4,))[0], max_new_tokens=3,
+                       temperature=0.8))
+    eng.run()
+    arms = [sp.args["sampler"] for sp in tel.tracer.spans
+            if sp.name == "decode"]
+    assert arms == ["plain"] * 2 + ["greedy"] * 3
+    counts = eng.stats()["sampler"]
+    assert counts == {"greedy": 4, "plain": 3, "filtered": 0}
+    # The gauge block runs before the step's decode call: one behind.
+    last = tel.stats_dict()["gauges"]["last"]
+    assert last["sampler_plain"] == 3 and last["sampler_filtered"] == 0
+    assert last["sampler_greedy"] == 3
 
 
 # ---------------------------------------------------------------------------

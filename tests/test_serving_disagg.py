@@ -127,6 +127,55 @@ def test_engine_pair_handoff_parity_and_ledger(mp, prompts, oracle):
     assert dst["handoff"]["adopt_skipped_blocks"] > 0
 
 
+@pytest.mark.parametrize("sampling", [
+    [{"temperature": 0.9}],
+    [{"temperature": 1.1, "top_k": 7}, {"temperature": 0.7, "top_p": 0.8}],
+    [{}, {"temperature": 0.9}, {"temperature": 1.1, "top_k": 7}],
+], ids=["plain", "filtered", "mixed"])
+def test_engine_pair_handoff_parity_for_sampled_requests(
+        mp, prompts, sampling):
+    # The prefill replica draws a sampled request's first token (on a
+    # sampling arm of its prefill program) and discards it; the decode
+    # replica draws it again from fold_in(seed, request_id), on a lane
+    # whose rng row the greedy arm may have left unsplit for any number
+    # of calls. Same tokens as one unified engine, sampled or not.
+    def requests():
+        return [
+            Request(prompt=list(p), max_new_tokens=_MAX_NEW, request_id=i,
+                    **sampling[i % len(sampling)])
+            for i, p in enumerate(prompts)
+        ]
+
+    model, params = mp
+    uni = ServingEngine(model, params, _CFG, clock=_fake_clock())
+    for req in requests():
+        uni.submit(req)
+    want = {s.request.request_id: list(s.generated) for s in uni.run()}
+
+    pre = _engine(mp, "prefill")
+    dec = _engine(mp, "decode")
+    by_id = {req.request_id: req for req in requests()}
+    for req in by_id.values():
+        pre.submit(req)
+    assert pre.run() == []
+    # A B=1 prefill takes the arm of its own request's kind, no other.
+    kinds = {"filtered" if len(kw) > 1 else "plain" if kw else "greedy"
+             for kw in sampling}
+    arms = pre.stats()["sampler"]
+    assert sum(arms.values()) == pre.calls["prefill"] > 0
+    assert {k for k, n in arms.items() if n} == kinds
+    for h in pre.take_handoffs():
+        req = by_id[h["request"].request_id]
+        dec.adopt_chain(req.prompt, h["payloads"])
+        dec.submit(dataclasses.replace(req))
+    got = {s.request.request_id: list(s.generated) for s in dec.run()}
+    assert got == want
+    for side in (dec, uni):
+        arms = side.stats()["sampler"]
+        assert (arms["filtered"] > 0) == ("filtered" in kinds)
+        assert arms["plain"] > 0 or "filtered" in kinds
+
+
 def test_adopt_chain_dedupes_stale_slices_and_layout_mismatch(mp, prompts):
     pre = _engine(mp, "prefill")
     dec = _engine(mp, "decode")

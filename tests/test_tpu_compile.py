@@ -213,27 +213,13 @@ def _glm_engine_case():
     return model, params, 10, r"pool_latent", 2, 640
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("case", [_gpt2_engine_case, _glm_engine_case],
-                         ids=["gpt2", "glm4_moe_lite"])
-def test_engine_programs_take_the_pool_as_it_lies(one_chip, case, program):
-    # The counter of the lane-dense pool (PERF.md §6, PR 25): a leaf whose
-    # minor dims pad badly is stored block-index-minor by the runtime, and
-    # every serving program then re-lays the whole pool out, three copies
-    # a leaf a call. It engages always or never and a CPU run cannot see
-    # it, so: the engine's OWN programs, with the engine's own operands
-    # and donation, at real head geometry (12 x 64; the latent leaf of
-    # 576 values, which is stored block-index-minor unless padded to 640:
-    # PR 26), compiled for the chip.
+@pytest.fixture(scope="module")
+def engine_program(one_chip):
+    """``(case, program) -> (engine, compiled text)``: the engine's OWN
+    programs, with the engine's own operands and donation, compiled for
+    the chip once a module however many tests read the text."""
     from distributeddeeplearning_tpu.config import ServingConfig
     from distributeddeeplearning_tpu.serving import ServingEngine
-
-    model, params, budget_mb, leaf_name, n_leaves, width = case()
-    eng = ServingEngine(model, params, ServingConfig(
-        slots=8, block_size=16, hbm_budget_mb=budget_mb, max_seq_len=256,
-        prompt_buckets=(32,),
-    ))
-    assert eng.num_blocks == 256
 
     def compile_for_chip(fn, *args, name=None, donate_argnums=()):
         abstract = jax.tree.map(
@@ -244,11 +230,47 @@ def test_engine_programs_take_the_pool_as_it_lies(one_chip, case, program):
             *abstract
         ).compile()
 
-    eng._compile = compile_for_chip
-    exe = (eng._decode_exe_or_compile() if program == "decode"
-           else eng._prefill_exe_for(32))
-    text = exe.as_text()
-    assert text.startswith(f"HloModule jit__{program}_fn")
+    built = {}
+
+    def build(case, program):
+        if (case, program) not in built:
+            model, params, budget_mb = case()[:3]
+            eng = ServingEngine(model, params, ServingConfig(
+                slots=8, block_size=16, hbm_budget_mb=budget_mb,
+                max_seq_len=256, prompt_buckets=(32,),
+            ))
+            assert eng.num_blocks == 256
+            eng._compile = compile_for_chip
+            exe = (eng._decode_exe_or_compile() if program == "decode"
+                   else eng._prefill_exe_for(32))
+            text = exe.as_text()
+            assert text.startswith(f"HloModule jit__{program}_fn")
+            built[case, program] = eng, text
+        return built[case, program]
+
+    return build
+
+
+def _each_engine_program(test):
+    test = pytest.mark.parametrize(
+        "case", [_gpt2_engine_case, _glm_engine_case],
+        ids=["gpt2", "glm4_moe_lite"],
+    )(test)
+    return pytest.mark.parametrize("program", ["decode", "prefill"])(test)
+
+
+@_each_engine_program
+def test_engine_programs_take_the_pool_as_it_lies(
+        engine_program, case, program):
+    # The counter of the lane-dense pool (PERF.md §6, PR 25): a leaf whose
+    # minor dims pad badly is stored block-index-minor by the runtime, and
+    # every serving program then re-lays the whole pool out, three copies
+    # a leaf a call. It engages always or never and a CPU run cannot see
+    # it, so: the engine's programs at real head geometry (12 x 64; the
+    # latent leaf of 576 values, which is stored block-index-minor unless
+    # padded to 640: PR 26), compiled for the chip.
+    _, _, _, leaf_name, n_leaves, width = case()
+    eng, text = engine_program(case, program)
 
     leaves = eng._pool_leaves()
     assert len(leaves) == n_leaves
@@ -283,6 +305,65 @@ def test_engine_programs_take_the_pool_as_it_lies(one_chip, case, program):
         for scope in ("mla_project", "mla_attend", "latent_write",
                       "moe_route", "moe_experts", "moe_shared"):
             assert any(f"/{scope}/" in o for o in ops), scope
+
+
+def _hlo_computations(text: str) -> dict:
+    """name -> body of every computation of a compiled module's text."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}$", text, re.M | re.S
+    )}
+
+
+def _hlo_reachable(comps: dict, roots) -> set:
+    """The computations ``roots`` call, directly or through others."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for one, group in re.findall(
+            r"(?:to_apply|calls|body|condition|true_computation"
+            r"|false_computation)=%([\w.\-]+)"
+            r"|branch_computations=\{([^}]*)\}", comps[name],
+        ):
+            todo += [one] if one else re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+@_each_engine_program
+def test_engine_programs_sort_the_vocabulary_only_inside_a_branch(
+        engine_program, case, program):
+    # PR 29: a served program ends in one conditional over three samplers
+    # (engine.sampler_arm) and the whole-vocabulary sort belongs to the
+    # last. A CPU run cannot see whether the chip skips an arm; this pins
+    # that the chip's compiler kept the branch (it may flatten a cheap
+    # conditional into a select, which would run every arm) and hoisted no
+    # sort out of it: every lane's logits are [lanes, 512] here, and no
+    # sort of that width lies in the entry computation or anywhere the
+    # greedy and plain arms reach. The router's own small sorts (64
+    # scores, 32 or 128 pairs: glm4_moe_lite) stay where they were.
+    _, text = engine_program(case, program)
+    comps = _hlo_computations(text)
+    sampler = [
+        re.findall(r"%([\w.\-]+)", m.group(1))
+        for body in comps.values()
+        for m in re.finditer(
+            r" conditional\(.*branch_computations=\{([^}]*)\}"
+            r".*op_name=\"[^\"]*/sample/cond\"", body)
+    ]
+    assert len(sampler) == 1, sampler
+    greedy, plain, filtered = sampler[0]
+    wide = {
+        name for name, body in comps.items()
+        if re.search(r"= \(?\w+\[\d+,512\]\S*.* sort\(", body)
+    }
+    assert wide and wide <= _hlo_reachable(comps, [filtered]), wide
+    assert not wide & _hlo_reachable(comps, [greedy, plain])
+    # The greedy arm hands back the argmax and the rng it was given.
+    assert set(re.findall(r" ([\w\-]+)\(", comps[greedy])) <= {
+        "parameter", "get-tuple-element", "tuple", "bitcast", "copy",
+    }, comps[greedy]
 
 
 def test_chunked_xent_loss_and_grad_compile(one_chip):
