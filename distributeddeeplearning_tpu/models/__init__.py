@@ -32,5 +32,5 @@ def available() -> list[str]:
 
 
 from . import (  # noqa: E402,F401
-    bert, glm4_moe_lite, gpt2, llama, moe, pipeline, resnet, vit,
+    bert, cohere2_moe, glm4_moe_lite, gpt2, llama, moe, pipeline, resnet, vit,
 )

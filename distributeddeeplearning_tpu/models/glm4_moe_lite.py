@@ -21,9 +21,12 @@ reference the tests and the served cell hold this file to):
   [tokens, experts, capacity] dispatch tensor (``parallel/ep.route_top_k``,
   which the capacity models in ``models/moe.py`` use and which is why those
   stay fenced from serving), and bulk prefill and one-token decode route a
-  token alike. Every expert lives on the chip that runs the layer: an
-  expert layer told which experts it holds (expert parallelism) is not
-  built, and the ``ep`` mesh axis is refused for this model by the Trainer.
+  token alike. The layer can be told which experts it holds
+  (``RoutedExperts.held``: the chip's share of a deployment that divides
+  each layer's experts over chips, ``models/cohere2_moe.py``); here every
+  expert lives on the chip that runs the layer. The exchange between the
+  holders is not built, and the ``ep`` mesh axis is refused for this model
+  by the Trainer.
 - The selection bias is a buffer here as published (training would move it
   outside the gradient, which is not built: it trains as a parameter).
 
@@ -78,19 +81,44 @@ def route(x, router, bias, top_k: int, scale: float):
     """Token-choice routing of ``x`` [T, D]: (chosen experts [T, k] int32,
     their weights [T, k] float32). Scores are sigmoids computed in float32
     on a float32 copy of the input at full matmul precision (the TPU's
-    default would round the operands to bfloat16); ``bias`` selects and
-    does not weigh; the weights sum to ``scale``."""
+    default would round the operands to bfloat16); ``bias`` (None: the
+    model publishes none) selects and does not weigh; the weights sum to
+    ``scale``."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", x.astype(jnp.float32), router.astype(jnp.float32),
         precision=HIGHEST, preferred_element_type=jnp.float32,
     ))
-    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    _, chosen = jax.lax.top_k(
+        scores if bias is None else scores + bias, top_k
+    )
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     return chosen, w / (jnp.sum(w, -1, keepdims=True) + 1e-20) * scale
 
 
+# (token, choice) pairs the grouped product takes at once: a longer call
+# runs it a chunk of tokens at a time (routing is a token's own, so the
+# chunks are exact), and no more than [pairs, max(D, F)] of sorted rows,
+# gate, up and down products are alive together. GLM-4.7-Flash's largest
+# bucket (4096 tokens x 4) is one chunk.
+_PAIR_CHUNK = 16384
+
+
 class RoutedExperts(nn.Module):
-    """The expert FFN: ``sum_i w_i E_i(x) + E_shared(x)`` (module doc)."""
+    """The expert FFN: ``sum_i w_i E_i(x) + E_shared(x)`` (module doc).
+
+    ``held=(first, count)`` tells the layer which of the
+    ``num_routed_experts`` published experts live here (expert
+    parallelism's layer, run on one chip without its exchange; default:
+    all). The router keeps its published width, ``route`` picks the top k
+    over all of them and normalises over all k chosen; the expert leaves
+    are ``[count, ...]``; a pair whose expert is held elsewhere adds
+    nothing here: the held pairs are sorted first and the grouped product
+    is given the held experts' counts alone, so the rows after them belong
+    to no group. ``expert_load`` stays ``[published]``: the router's view,
+    what the deployment's other chips would receive.
+
+    ``num_shared`` shared experts are one SwiGLU of width ``num_shared x
+    F``; ``shared_combine`` is ``"sum"`` or ``"average"`` (their mean)."""
 
     num_routed_experts: int
     expert_dim: int
@@ -100,11 +128,20 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     decode: bool = False  # serving: count the tokens each expert gets
+    held: tuple | None = None  # (first, count) of the experts held here
+    shared_combine: str = "sum"
+    selection_bias: bool = True  # a router_bias that selects (noaux_tc)
 
     @nn.compact
     def __call__(self, x):
         B, L, D = x.shape
         E, F, k = self.num_routed_experts, self.expert_dim, self.top_k
+        first, count = self.held or (0, E)
+        if first < 0 or count < 1 or first + count > E:
+            raise ValueError(f"held={self.held!r} of {E} experts")
+        if self.shared_combine not in ("sum", "average"):
+            raise ValueError(f"shared_combine={self.shared_combine!r}")
+        partial = count < E
         router = self.param(
             "router", _init("embed", None), (D, E), self.param_dtype
         )
@@ -112,9 +149,9 @@ class RoutedExperts(nn.Module):
             "router_bias",
             nn.with_logical_partitioning(nn.initializers.zeros, (None,)),
             (E,), jnp.float32,
-        )
+        ) if self.selection_bias else None
         w_gate, w_up, w_down = (
-            self.param(name, _init("expert", *axes), (E, *shape),
+            self.param(name, _init("expert", *axes), (count, *shape),
                        self.param_dtype)
             for name, axes, shape in (
                 ("experts_gate", ("embed", "mlp"), (D, F)),
@@ -123,22 +160,21 @@ class RoutedExperts(nn.Module):
             )
         )
         xf = x.reshape(B * L, D)
-        with jax.named_scope("moe_route"):
-            chosen, w = route(xf, router, bias, k, self.routed_scale)
-            flat = chosen.reshape(-1)  # [T*k], pair p = token p // k
-            order = jnp.argsort(flat, stable=True)
-            counts = jnp.bincount(flat, length=E).astype(jnp.int32)
-            if self.decode:
-                # Tokens routed to each expert by this call, every row of
-                # the grouped product counted (idle lanes and prompt
-                # padding are rows the experts compute too): a served
-                # program hands it back with the step's tokens
-                # (docs/OBSERVABILITY.md, moe_tokens_per_expert).
-                self.variable(
-                    "cache", "expert_load", jnp.zeros, (E,), jnp.int32
-                ).value = counts
-        with jax.named_scope("moe_experts"):
-            xs = xf[order // k].astype(self.dtype)  # sorted by expert
+
+        def sort_pairs(chosen):
+            """(sorted order of the [T*k] pairs, the held experts' counts
+            [count]): by expert, held pairs first (pair p = token p // k)."""
+            flat = chosen.reshape(-1)
+            if partial:
+                local = flat - first
+                flat = jnp.where((local >= 0) & (local < count), local, count)
+            return (
+                jnp.argsort(flat, stable=True),
+                jnp.bincount(flat, length=count).astype(jnp.int32),
+            )
+
+        def experts(xc, chosen, w, order, counts):
+            xs = xc[order // k].astype(self.dtype)  # sorted by expert
             grouped = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
                 a, b.astype(self.dtype), counts
             )
@@ -149,15 +185,54 @@ class RoutedExperts(nn.Module):
             back = jnp.zeros_like(order).at[order].set(
                 jnp.arange(order.size, dtype=order.dtype)
             )
-            y = ys[back].reshape(B * L, k, D)
-            y = jnp.einsum(
+            y = ys[back].reshape(-1, k, D)
+            if partial:
+                # Rows past the held groups are no group's: whatever the
+                # kernel left there is not a number of this layer's.
+                here = (chosen >= first) & (chosen < first + count)
+                y = jnp.where(here[..., None], y, 0)
+            return jnp.einsum(
                 "tkd,tk->td", y.astype(jnp.float32), w
             ).astype(self.dtype)
+
+        with jax.named_scope("moe_route"):
+            chosen, w = route(xf, router, bias, k, self.routed_scale)
+            chunks = -(-B * L * k // _PAIR_CHUNK)
+            while (B * L) % chunks:  # whole chunks of tokens
+                chunks += 1
+            if chunks == 1:
+                order, counts = sort_pairs(chosen)
+            if self.decode:
+                # Tokens routed to each expert by this call, every row of
+                # the grouped product counted (idle lanes and prompt
+                # padding are rows the experts compute too): a served
+                # program hands it back with the step's tokens
+                # (docs/OBSERVABILITY.md, moe_tokens_per_expert).
+                self.variable(
+                    "cache", "expert_load", jnp.zeros, (E,), jnp.int32
+                ).value = counts if chunks == 1 and not partial else (
+                    jnp.bincount(chosen.reshape(-1), length=E).astype(
+                        jnp.int32
+                    )
+                )
+        with jax.named_scope("moe_experts"):
+            if chunks == 1:
+                y = experts(xf, chosen, w, order, counts)
+            else:
+                split = lambda a: a.reshape(  # noqa: E731
+                    chunks, B * L // chunks, *a.shape[1:]
+                )
+                y = jax.lax.map(
+                    lambda c: experts(*c, *sort_pairs(c[1])),
+                    (split(xf), split(chosen), split(w)),
+                ).reshape(B * L, D)
         with jax.named_scope("moe_shared"):
             shared = SwiGLU(
                 F * self.num_shared, self.dtype, self.param_dtype,
                 name="shared",
             )(x)
+            if self.shared_combine == "average":
+                shared = shared / self.num_shared
         return y.reshape(B, L, D) + shared
 
 

@@ -127,10 +127,19 @@ def _cache_attend(q, ck, cv, visible, num_rep: int, dtype):
     )
 
 
+def window_ring_blocks(window: int, block_size: int) -> int:
+    """Blocks a window layer keeps of one lane: ``window`` consecutive
+    positions touch at most ``ceil(window / block_size) + 1`` blocks (the
+    oldest and the newest both partly)."""
+    return -(-int(window) // int(block_size)) + 1
+
+
 def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
                            num_rep: int = 1, lens_var=None,
                            kernel: str = "reference",
-                           kv_quant: str = "off"):
+                           kv_quant: str = "off",
+                           window: int | None = None,
+                           window_blocks: int = 0):
     """Decode/prefill attention against a PAGED KV cache (serving engine).
 
     Instead of one contiguous [B, max_len] cache per sequence, k/v live in a
@@ -216,7 +225,53 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     each program still re-lays out (that 6%% only): with the block index
     first, no shape is tile-dense for block_size*kv_heads scales a block
     (PERF.md §7).
+
+    ``window`` (None: a global layer, everything above) makes this a
+    **window layer**: query t sees keys j with ``t - window < j <= t``, in
+    prefill and decode alike, and the layer keeps no more of a lane than
+    its window. Its state is a second kind, beside the global layers':
+
+    - ``pool_key_window`` / ``pool_value_window``: [``window_blocks`` (0: as
+      many as ``num_blocks``), block_size, kv_heads*D], a pool of its own
+      with its own null block 0, and ``page_table_window`` [B,
+      pages_per_seq]: the lane's LOGICAL blocks, as ``page_table``, of
+      which the host maps only the ``R = window_ring_blocks(window,
+      block_size)`` that end at the cursor's block and points every other
+      entry at the null block. The physical blocks behind them are a ring
+      the host turns (``serving/engine.py``: logical block b of a lane
+      that reserved n <= R blocks lives in its ``b % n``-th): a slot holds
+      position p until the host maps the block of p + n * block_size onto
+      it, which it does only once p has left every later query's window.
+    - what is written where: this call's tokens scatter through the same
+      ``_page_writes`` as a global layer's. A prompt position whose block
+      has already left the window at the prompt's end, and a bucket's pad
+      position past the prompt's last block, find the null block in the
+      table, so neither can land on a live slot of the ring (two writes to
+      one slot in one scatter have no order); pad positions inside the
+      prompt's last block land in their own slots and are overwritten in
+      place by decode before any query can see them, as in a global layer.
+    - what is read: L == 1 gathers the R blocks that end at the cursor's
+      (``window + block_size`` tokens a lane at most, whatever the lane's
+      length) under the band; L > 1 reads nothing from the pool: the
+      call's own tokens attend among themselves under the band, a chunk of
+      queries against the ``window + chunk`` keys it can see. That is
+      right at cursor 0 only: bulk prefill. What would start an L > 1 call
+      at a cursor (prefix cache, speculation) is refused for a model with
+      window layers at config time (``engine._check_window_cache``), and
+      such a row's output is poisoned to NaN here.
+    - ``kernel='pallas'`` and ``kv_quant='int8'`` are not built for it.
+
+    Queries run ``_in_query_chunks`` on every gather path, so a bucket of P
+    prompt tokens holds [heads, chunk, keys] float32 scores, not
+    [heads, P, keys].
     """
+    if window is not None and (kernel != "reference" or kv_quant != "off"):
+        raise NotImplementedError(
+            f"window layer x paged_kernel={kernel!r} / kv_quant="
+            f"{kv_quant!r}: ops/paged_attention.py has no window in its "
+            "mask and the int8 pool no ring — only the gather read path on "
+            "an unquantized pool is built for window layers"
+        )
     if kernel not in ("reference", "pallas"):
         raise ValueError(
             f"paged kernel must be 'reference' or 'pallas', got {kernel!r}"
@@ -227,13 +282,16 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
         )
     quantized = kv_quant == "int8"
     num_blocks, bs, pages = kv_pages
+    kind = ""
+    if window is not None:
+        kind, num_blocks = "_window", int(window_blocks) or num_blocks
     B, L, Hkv, D = k.shape
     pk = module.variable(
-        "cache", "pool_key", jnp.zeros, (num_blocks, bs, Hkv * D),
+        "cache", "pool_key" + kind, jnp.zeros, (num_blocks, bs, Hkv * D),
         jnp.int8 if quantized else k.dtype,
     )
     pv = module.variable(
-        "cache", "pool_value", jnp.zeros, (num_blocks, bs, Hkv * D),
+        "cache", "pool_value" + kind, jnp.zeros, (num_blocks, bs, Hkv * D),
         jnp.int8 if quantized else v.dtype,
     )
     sk = sv = None
@@ -247,7 +305,8 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
             (num_blocks, bs, Hkv), jnp.float32,
         )
     table = module.variable(
-        "cache", "page_table", lambda: jnp.zeros((B, pages), jnp.int32)
+        "cache", "page_table" + kind,
+        lambda: jnp.zeros((B, pages), jnp.int32),
     )
     lens = lens_var if lens_var is not None else module.variable(
         "cache", "seq_lens", lambda: jnp.zeros((B,), jnp.int32)
@@ -279,13 +338,26 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
         sv.value = sv.value.reshape(num_blocks * bs, Hkv).at[flat].set(
             v_scale.reshape(B * L, Hkv)
         ).reshape(sv.value.shape)
-    pk.value = pk.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
-        k_w.reshape(B * L, Hkv * D)
-    ).reshape(pk.value.shape)
-    pv.value = pv.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
-        v_w.reshape(B * L, Hkv * D)
-    ).reshape(pv.value.shape)
-    if kernel == "pallas" and L == 1:
+    with jax.named_scope("kv_write"):
+        pk.value = pk.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
+            k_w.reshape(B * L, Hkv * D)
+        ).reshape(pk.value.shape)
+        pv.value = pv.value.reshape(num_blocks * bs, Hkv * D).at[flat].set(
+            v_w.reshape(B * L, Hkv * D)
+        ).reshape(pv.value.shape)
+    if window is not None:
+        with jax.named_scope("attn_window"):
+            out = _window_attend(
+                q, k, v, pk.value, pv.value, table.value, pos,
+                window=window, bs=bs, num_rep=num_rep, dtype=dtype,
+            )
+        if L > 1:
+            # Bulk prefill only (docstring): a row that starts at a cursor
+            # would need what the pool holds below it.
+            out = jnp.where(
+                (lens.value != 0)[:, None, None, None], jnp.nan, out
+            )
+    elif kernel == "pallas" and L == 1:
         from ..ops.paged_attention import paged_attention
 
         out = paged_attention(
@@ -310,8 +382,15 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
                 B, pages * bs, Hkv
             )[..., None]
         cols = jnp.arange(pages * bs)
-        visible = cols[None, None, :] <= pos[:, :, None]  # causal per row
-        out = _cache_attend(q, ck, cv, visible, num_rep, dtype)
+        with jax.named_scope("attn_global"):
+            out = _in_query_chunks(
+                lambda qc, pc: _cache_attend(  # causal per row
+                    qc, ck, cv, cols[None, None, :] <= pc[:, :, None],
+                    num_rep, dtype,
+                ),
+                L, q, pos,
+                chunk=_query_chunk(q.shape[2], pages * bs),
+            )
     if jax.config.jax_enable_checks:
         # Debug-mode OOB tripwire (train.debug_checks): XLA clamps OOB
         # gather/scatter indices SILENTLY, so a corrupt page table reads —
@@ -328,6 +407,50 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     return out
 
 
+def _window_attend(q, k, v, pool_k, pool_v, table, pos, *, window: int,
+                   bs: int, num_rep: int, dtype):
+    """A window layer's read (``paged_decode_attention``'s docstring): one
+    decode token against the R blocks of the ring that end at its own, or
+    a prompt's tokens among themselves; both under the band ``t - window <
+    j <= t``."""
+    B, L, H, D = q.shape
+    Hkv = k.shape[2]
+    if L == 1:
+        R = window_ring_blocks(window, bs)
+        # Logical blocks cur - R + 1 .. cur of every row; the ones before
+        # the lane's first are masked below, whatever the clip reads.
+        logical = pos // bs - (R - 1) + jnp.arange(R)[None, :]  # [B, R]
+        phys = jnp.take_along_axis(
+            table, jnp.clip(logical, 0, table.shape[1] - 1), axis=1
+        )
+        ck = pool_k[phys].reshape(B, R * bs, Hkv, D)
+        cv = pool_v[phys].reshape(B, R * bs, Hkv, D)
+        cols = (
+            logical[:, :, None] * bs + jnp.arange(bs)[None, None, :]
+        ).reshape(B, 1, R * bs)
+        t = pos[:, :, None]
+        visible = (cols <= t) & (cols > t - window) & (cols >= 0)
+        return _cache_attend(q, ck, cv, visible, num_rep, dtype)
+    chunk = _query_chunk(H, min(L, window + _LATENT_QUERY_CHUNK))
+    keys = min(L, window + chunk)  # what one chunk of queries can see
+
+    def attend(qc, rel):
+        # rel [B, C]: the queries' places in this call, the same in every
+        # row; their keys lie in [last - keys + 1, last].
+        start = jnp.clip(rel[0, -1] + 1 - keys, 0, L - keys)
+        kc = jax.lax.dynamic_slice_in_dim(k, start, keys, axis=1)
+        vc = jax.lax.dynamic_slice_in_dim(v, start, keys, axis=1)
+        cols = (start + jnp.arange(keys))[None, None, :]
+        t = rel[:, :, None]
+        return _cache_attend(
+            qc, kc, vc, (cols <= t) & (cols > t - window), num_rep, dtype
+        )
+
+    return _in_query_chunks(
+        attend, L, q, jnp.broadcast_to(jnp.arange(L), (B, L)), chunk=chunk
+    )
+
+
 def _page_writes(table, lens, L: int, bs: int):
     """Where this call's L tokens of every row land: their absolute
     positions [B, L] and flat slot indices ``block * bs + offset`` [B*L]
@@ -337,24 +460,41 @@ def _page_writes(table, lens, L: int, bs: int):
     return pos, (blk * bs + pos % bs).reshape(-1)
 
 
-# Queries attended at once by the latent paths below: a bucket of P
-# prompt tokens never holds more than [heads, chunk, keys] float32 scores.
+# Queries attended at once by the gather paths: a bucket of P prompt
+# tokens never holds more than [heads, chunk, keys] float32 scores.
 _LATENT_QUERY_CHUNK = 512
+_SCORE_BYTES = 1 << 30
 
 
-def _in_query_chunks(fn, L: int, *per_query):
-    """``fn`` over ``per_query`` arrays ([B, L, ...]) a chunk of queries
-    at a time where L is long (results concatenated along L); one call
-    where it is short."""
+def _query_chunk(heads: int, keys: int) -> int:
+    """The largest power of two from 64 to ``_LATENT_QUERY_CHUNK`` whose
+    [heads, chunk, keys] float32 scores stay within ``_SCORE_BYTES`` (128
+    at 128 heads over 13,312 keys; 512 at every GPT-2 and GLM shape)."""
     chunk = _LATENT_QUERY_CHUNK
-    if L <= chunk or L % chunk:
+    while chunk > 64 and heads * chunk * keys * 4 > _SCORE_BYTES:
+        chunk //= 2
+    return chunk
+
+
+def _in_query_chunks(fn, L: int, *per_query, chunk: int = _LATENT_QUERY_CHUNK):
+    """``fn`` over ``per_query`` arrays ([B, L, ...]) a chunk of queries
+    at a time where L is long (results concatenated along L; what is left
+    over after whole chunks is one more call); one call where it is
+    short."""
+    if L <= chunk:
         return fn(*per_query)
+    n = L // chunk
     split = lambda a: jnp.moveaxis(  # noqa: E731
-        a.reshape(a.shape[0], L // chunk, chunk, *a.shape[2:]), 1, 0
+        a[:, :n * chunk].reshape(a.shape[0], n, chunk, *a.shape[2:]), 1, 0
     )
     out = jax.lax.map(lambda xs: fn(*xs), tuple(split(a) for a in per_query))
     out = jnp.moveaxis(out, 0, 1)
-    return out.reshape(out.shape[0], L, *out.shape[3:])
+    out = out.reshape(out.shape[0], n * chunk, *out.shape[3:])
+    if L % chunk:
+        out = jnp.concatenate(
+            [out, fn(*(a[:, n * chunk:] for a in per_query))], axis=1
+        )
+    return out
 
 
 def latent_paged_attention(module, q_nope, q_rope, c_kv, k_rope, w_uk, w_uv,

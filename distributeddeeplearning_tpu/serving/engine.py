@@ -28,6 +28,16 @@ page tables and sequence lengths — they are rebuilt from scheduler state
 and injected by leaf name into the cache pytree before every call, so the
 device-side cursor copies are write-only.
 
+A model with WINDOW layers (``models/cohere2_moe.py``) keeps two kinds of
+layer state side by side (docs/SERVING.md): the global layers' pool above
+on ``page_table`` / ``scheduler.pool``, and the window layers'
+``pool_key_window`` / ``pool_value_window`` on ``page_table_window`` /
+``scheduler.window_pool``, of which a lane reserves a ring of
+``window / block_size + 1`` blocks however long it grows. Both tables hold
+a lane's logical blocks; the host maps only the ring that ends at the
+cursor's block (``_map_window``, span ``window_pages``) and points the rest
+at the null block. ``hbm_budget_mb`` buys both kinds.
+
 Sampling is per-REQUEST inside the compiled graphs: temperature / top_k /
 top_p ride as [B] operands (0 = off / greedy), and each lane carries its
 own PRNG key chain (``fold_in(seed, request_id)``), so one decode batch
@@ -112,6 +122,7 @@ from ..generate import (
     _filter_logits, logits_at, prefill, decode_step, verify_step,
 )
 from ..metrics import serving_event, serving_gauges
+from ..models.transformer import window_ring_blocks
 from ..telemetry import NULL_TELEMETRY, SPEC_ACCEPT_HIST
 from .quant import dequantize_params, quantization_error, quantize_params
 from .scheduler import (
@@ -128,11 +139,15 @@ from .scheduler import (
 # model allocates ONE leaf a layer instead of the K/V pair
 # (transformer.latent_paged_attention): a name here, not a second manager.
 _LATENT_LEAF = "pool_latent"
+# A window layer's K and V (transformer.paged_decode_attention, window=):
+# a second KIND of layer state, in a pool of its own with its own page
+# table, found by these names as the latent leaf is by its.
+_WINDOW_LEAVES = ("pool_key_window", "pool_value_window")
 _POOL_LEAVES = (
     "pool_key", "pool_value", "pool_key_scale", "pool_value_scale",
-    _LATENT_LEAF,
+    _LATENT_LEAF, *_WINDOW_LEAVES,
 )
-_HOST_LEAVES = ("page_table", "seq_lens")
+_HOST_LEAVES = ("page_table", "page_table_window", "seq_lens")
 # Per-layer counters a served program hands back with its tokens: tokens
 # routed to each expert by that call (models/glm4_moe_lite.RoutedExperts).
 _LOAD_LEAF = "expert_load"
@@ -168,7 +183,7 @@ _SPILL_QBLOCK = 256
 # a trace: capacity-MoE decode routes through expert capacity (one-token
 # streams and batched prefills disagree — generate.uses_bulk_prefill),
 # and pipelined models own their own step program.
-SERVABLE_MODELS = ("gpt2", "llama", "glm4_moe_lite")
+SERVABLE_MODELS = ("gpt2", "llama", "glm4_moe_lite", "cohere2_moe")
 # Families whose cache is the latent leaf: what the K/V pool has and the
 # latent pool does not is refused by name (_check_latent_cache).
 LATENT_CACHE_MODELS = ("glm4_moe_lite",)
@@ -354,6 +369,80 @@ def _check_latent_cache(name, kv_quant, attn_kernel, spill_codec) -> None:
             f"serving.spill_codec={spill_codec!r} x latent paged cache "
             f"({name!r}): the int8 spill codec is not validated on the "
             "latent leaf; keep spill_codec='fp' (bitwise)"
+        )
+
+
+def _has_window_layers(cfg) -> bool:
+    """Whether the model that ``cfg`` builds has window layers, asked of
+    the model itself (its ``layer_types``, preset and overrides applied; a
+    module is built without parameters, so this costs nothing): no list of
+    families to keep. The engine asks the cache's leaves the same."""
+    from .. import models
+
+    model = models.get_model(cfg.model.name, **cfg.model.kwargs)
+    return "sliding_attention" in getattr(model, "layer_types", ())
+
+
+def _check_window_cache(name, s) -> None:
+    """What is not built for a model with window layers (by name: at
+    config time for a model whose ``layer_types`` hold a window layer, and
+    again by the engine for any model whose cache holds window leaves;
+    ``s`` is the ServingConfig). A window layer keeps its window and
+    no more, so everything that reads a lane's OLD blocks, or starts an
+    L > 1 call at a cursor, would be silently wrong there:
+
+    - the prefix cache (and with it suffix-only prefill, the host spill
+      tier and the prefill/decode handoff, which all ride the trie): a
+      trie node stands for every layer's K and V of its block, and the
+      window layers have dropped theirs — a trie that knows what the
+      window layers kept is not built (ROADMAP R2);
+    - speculation: the verify forward is L > 1 at each lane's cursor, and a
+      window layer's L > 1 read attends the call's own tokens only;
+    - ``kv_quant='int8'`` and ``attn_kernel='pallas'``: the int8 pool has
+      no ring and ``ops/paged_attention.py`` no window in its mask."""
+    what = f"window layers ({name!r})"
+    if getattr(s, "prefix_cache", False) or tuple(
+        getattr(s, "suffix_buckets", ())
+    ):
+        raise NotImplementedError(
+            f"serving.prefix_cache / suffix_buckets x {what}: a cached "
+            "block stands for every layer's K and V and a window layer "
+            "keeps its window only — suffix-only prefill would attend to "
+            "keys that are gone; a trie over window layers is not built "
+            "(keep prefix_cache=false)"
+        )
+    if int(getattr(s, "spill_blocks", 0)):
+        raise NotImplementedError(
+            f"serving.spill_blocks={s.spill_blocks} x {what}: the host "
+            "tier stores evicted trie nodes, and the prefix trie is not "
+            "built over window layers (keep spill_blocks=0)"
+        )
+    if str(getattr(s, "role", "unified") or "unified") != "unified" or int(
+        getattr(s, "prefill_replicas", 0)
+    ):
+        raise NotImplementedError(
+            f"serving.role={getattr(s, 'role', 'unified')!r} / "
+            f"prefill_replicas x {what}: the handoff ships a prompt's "
+            "blocks through the prefix trie, which is not built over "
+            "window layers (keep role='unified')"
+        )
+    if str(getattr(s, "kv_quant", "off") or "off") != "off":
+        raise NotImplementedError(
+            f"serving.kv_quant={s.kv_quant!r} x {what}: the int8 pool is "
+            "not built for the window layers' ring (keep kv_quant='off')"
+        )
+    if str(getattr(s, "speculation", "off") or "off") != "off":
+        raise NotImplementedError(
+            f"serving.speculation={s.speculation!r} x {what}: the verify "
+            "forward scores K+1 positions at each lane's cursor, and a "
+            "window layer's multi-token read sees the call's own tokens "
+            "only (keep speculation='off')"
+        )
+    if str(getattr(s, "attn_kernel", "reference")) != "reference":
+        raise NotImplementedError(
+            f"serving.attn_kernel={s.attn_kernel!r} x {what}: "
+            "ops/paged_attention.py has no window in its mask (keep "
+            "attn_kernel='reference')"
         )
 
 
@@ -565,6 +654,8 @@ def check_serving_composition(cfg, *, fleet: int = 0) -> None:
         name, getattr(s, "kv_quant", "off"), kernel,
         getattr(s, "spill_codec", "fp"),
     )
+    if _has_window_layers(cfg):
+        _check_window_cache(name, s)
     if policy == "prefix_affinity" and not prefix_on:
         raise ValueError(
             "serving.router_policy='prefix_affinity' x prefix_cache=False: "
@@ -753,19 +844,48 @@ class ServingEngine:
                 shapes["cache"]
             )[0]
         ]
-        block_bytes = sum(n for k, n in leaf_bytes if k in _POOL_LEAVES)
+        window_bytes = sum(n for k, n in leaf_bytes if k in _WINDOW_LEAVES)
+        block_bytes = sum(
+            n for k, n in leaf_bytes if k in _POOL_LEAVES
+        ) - window_bytes
         latent_bytes = sum(n for k, n in leaf_bytes if k == _LATENT_LEAF)
         budget = int(cfg.hbm_budget_mb) * (1 << 20)
-        self.num_blocks = budget // block_bytes
-        min_blocks = 1 + blocks_for(self.max_seq_len, bs)  # null + 1 request
-        if self.num_blocks < min_blocks:
-            raise ValueError(
-                f"serving.hbm_budget_mb={cfg.hbm_budget_mb} holds "
-                f"{self.num_blocks} KV blocks of {block_bytes} B but one "
-                f"max_seq_len={self.max_seq_len} request needs "
-                f"{min_blocks} — raise the budget or lower max_seq_len"
+        # One request at max_seq_len, of each kind of layer state: every
+        # one of its blocks of the global kind, the ring of the window kind.
+        lane_blocks = blocks_for(self.max_seq_len, bs)
+        self.window_ring = self.window_blocks = 0
+        if window_bytes:
+            # The budget buys both kinds. A lane at max_seq_len costs
+            # ``lane_blocks`` global blocks and its ring of window blocks;
+            # the budget is split so that both pools hold the same number
+            # of such lanes, and what the window kind cannot use (it never
+            # needs more than slots x ring) goes to the global kind.
+            _check_window_cache(type(model).__name__, cfg)
+            self.window_ring = min(
+                lane_blocks, window_ring_blocks(int(model.window), bs)
             )
+            lanes = budget / (
+                lane_blocks * block_bytes + self.window_ring * window_bytes
+            )
+            self.window_blocks = 1 + min(
+                S * self.window_ring, int(lanes * self.window_ring)
+            )
+            budget -= self.window_blocks * window_bytes
+        self.num_blocks = budget // block_bytes
+        for kind, have, need, size in (
+            ("KV", self.num_blocks, 1 + lane_blocks, block_bytes),
+            ("window-layer KV", self.window_blocks,
+             1 + self.window_ring if window_bytes else 0, window_bytes),
+        ):
+            if have < need:  # null + 1 request, per kind of layer state
+                raise ValueError(
+                    f"serving.hbm_budget_mb={cfg.hbm_budget_mb} holds "
+                    f"{have} {kind} blocks of {size} B but one "
+                    f"max_seq_len={self.max_seq_len} request needs "
+                    f"{need} — raise the budget or lower max_seq_len"
+                )
         self.block_bytes = block_bytes
+        self.window_block_bytes = window_bytes
         self.kv_pages = (self.num_blocks, bs, self.pages)
         # Paged read path (docs/SERVING.md hot path): 'reference' gathers
         # every row's pages per layer per step; 'pallas' reads the pool in
@@ -780,6 +900,7 @@ class ServingEngine:
         self.model = model.clone(
             decode=True, kv_pages=self.kv_pages,
             paged_kernel=self.attn_kernel, kv_quant=self.kv_quant,
+            **({"window_blocks": self.window_blocks} if window_bytes else {}),
         )
         # Prefill/decode priority: cap admissions (each costs one prefill)
         # per engine step so a queue burst cannot stall the running decode
@@ -835,6 +956,10 @@ class ServingEngine:
             latent_bytes_per_token=(latent_bytes // bs) or None,
             kv_quant=self.kv_quant,
             role=self.role,
+            window_pool=(
+                KVBlockPool(self.window_blocks, bs) if window_bytes else None
+            ),
+            window_ring=self.window_ring,
         )
         # Handoff queue (role='prefill'): export records awaiting pickup
         # by the worker/router — each is the request plus its chain
@@ -850,6 +975,12 @@ class ServingEngine:
         # stages them, _apply_promotions scatters them.
         self._staged_promotes: dict[int, tuple] = {}
         self._table = np.zeros((S, self.pages), np.int32)
+        # The window layers' table (all null where the model has none): a
+        # lane's LOGICAL blocks, of which only the ring that ends at the
+        # cursor's block is mapped (_map_window); _window_at is the block
+        # each lane's row was last mapped for.
+        self._table_window = np.zeros((S, self.pages), np.int32)
+        self._window_at = np.full((S,), -1, np.int64)
         self._lens = np.zeros((S,), np.int32)
         self._tok = np.zeros((S,), np.int32)
         self._temp = np.zeros((S,), np.float32)
@@ -882,22 +1013,31 @@ class ServingEngine:
     # cache plumbing: host arrays in, pool arrays shared across programs
     # ------------------------------------------------------------------
 
-    def _inject(self, cache, table, lens):
+    def _inject(self, cache, table, lens, window_table=None):
         """Swap every ``page_table``/``seq_lens`` leaf (by NAME, at any
         depth — per-layer attention cursors and gpt2's position cursor
-        alike) for host-built arrays of the target batch size."""
-        t = np.asarray(table)
-        if t.size and (int(t.min()) < 0 or int(t.max()) >= self.num_blocks):
-            # XLA clamps OOB gather/scatter indices SILENTLY — a corrupt
-            # table would read (and write) the wrong physical block. The
-            # host is the source of truth for tables, so range-check every
-            # injection; the traced guard in paged_decode_attention covers
-            # device-built tables under train.debug_checks.
-            raise ValueError(
-                f"page table entry out of range [0, {self.num_blocks}): "
-                f"min={int(t.min())} max={int(t.max())} — the XLA gather "
-                "would clamp this silently and corrupt another request's KV"
-            )
+        alike) for host-built arrays of the target batch size;
+        ``window_table`` (default: all null) goes to the window layers'
+        ``page_table_window`` leaves."""
+        tables = [(np.asarray(table), self.num_blocks)]
+        if self.window_blocks:
+            if window_table is None:
+                window_table = np.zeros_like(tables[0][0])
+            tables.append((np.asarray(window_table), self.window_blocks))
+        for t, limit in tables:
+            if t.size and (int(t.min()) < 0 or int(t.max()) >= limit):
+                # XLA clamps OOB gather/scatter indices SILENTLY — a
+                # corrupt table would read (and write) the wrong physical
+                # block. The host is the source of truth for tables, so
+                # range-check every injection; the traced guard in
+                # paged_decode_attention covers device-built tables under
+                # train.debug_checks.
+                raise ValueError(
+                    f"page table entry out of range [0, {limit}): "
+                    f"min={int(t.min())} max={int(t.max())} — the XLA "
+                    "gather would clamp this silently and corrupt another "
+                    "request's KV"
+                )
         table = np.asarray(table, np.int32)
         lens = np.asarray(lens, np.int32)
 
@@ -908,6 +1048,8 @@ class ServingEngine:
             # per-layer cursor leaves must not alias.
             if name == "page_table":
                 return jnp.asarray(np.array(table))
+            if name == "page_table_window":
+                return jnp.asarray(np.array(window_table, np.int32))
             if name == "seq_lens":
                 return jnp.asarray(np.array(lens))
             return leaf
@@ -923,6 +1065,24 @@ class ServingEngine:
             ),
             self._cache, updated,
         )
+
+    def _map_window(self, state: RequestState, cur: int) -> None:
+        """Point the lane's window-layer row at the ring of blocks that
+        ends at logical block ``cur`` (the cursor's): block b lives in the
+        ``b % n``-th of the n blocks the lane reserved, the blocks behind
+        the ring and ahead of ``cur`` at the null block, so that nothing
+        written there can land on a live slot
+        (transformer.paged_decode_attention, the window's contract)."""
+        blocks, slot = state.window_blocks, state.slot
+        row = self._table_window[slot]
+        row[:] = 0
+        lo = max(0, cur - self.window_ring + 1)
+        logical = np.arange(lo, cur + 1)
+        row[logical] = np.asarray(blocks, np.int32)[logical % len(blocks)]
+        # A slot handed to a newer block of the lane: a turn of the ring.
+        before = max(self._window_at[slot], len(blocks) - 1)
+        self.scheduler.window_ring_wraps += max(0, int(cur - before))
+        self._window_at[slot] = cur
 
     # ------------------------------------------------------------------
     # host spill tier (KV memory hierarchy, module docstring)
@@ -1376,7 +1536,9 @@ class ServingEngine:
         into the scheduler's running counts)."""
         tok, load = jax.device_get(out)
         if load is not None:
-            self.scheduler.note_expert_load(load)
+            self.scheduler.note_expert_load(
+                load, getattr(self.model, "held_experts", None)
+            )
         return tok
 
     def _compile(self, fn, *args, name: str | None = None,
@@ -1576,6 +1738,8 @@ class ServingEngine:
             self._temp[slot] = 0.0
             self._lens[slot] = 0
             self._table[slot] = 0  # park the lane on the null block
+            self._table_window[slot] = 0
+            self._window_at[slot] = -1
             self._event(
                 "request_completed", state,
                 new_tokens=len(state.generated),
@@ -1626,6 +1790,14 @@ class ServingEngine:
             # the same fold_in(seed, request_id) on every admission path, so
             # tokens are independent of the cache state that admitted them.
             self._table[slot] = row
+            if state.window_blocks:
+                # The ring as the prompt's END sees it: the prompt's blocks
+                # that have left the window by then, and the bucket's
+                # padding past its last block, write to the null block.
+                with tel.span("window_pages"):
+                    self._map_window(
+                        state, (len(req.prompt) - 1) // self.block_size
+                    )
             self._temp[slot] = req.temperature
             self._top_k[slot] = req.top_k
             self._top_p[slot] = req.top_p
@@ -1665,7 +1837,10 @@ class ServingEngine:
             # seq_lens leaf, so seq_lens=off shifts everything at once —
             # writes land in the request's own blocks (row[off//bs:]), and the
             # suffix attends to cached prefix KV through the shared table.
-            cache1 = self._inject(self._cache, row[None], np.int32([off]))
+            cache1 = self._inject(
+                self._cache, row[None], np.int32([off]),
+                self._table_window[slot][None],
+            )
         out, rng_out, cache1 = exe(
             self._params, cache1, tokens, pos, rng, temp, tk, tp
         )
@@ -1805,7 +1980,17 @@ class ServingEngine:
         the arguments of the ``decode`` span that follows."""
         tel = self._tel
         with tel.span("decode_prepare", step=self.step_count):
-            cacheS = self._inject(self._cache, self._table, self._lens)
+            if self.window_blocks:
+                # What the window layers add to a step on the host: turn
+                # the ring of every lane whose cursor entered a new block.
+                with tel.span("window_pages"):
+                    for state in active:
+                        cur = int(self._lens[state.slot]) // self.block_size
+                        if cur != self._window_at[state.slot]:
+                            self._map_window(state, cur)
+            cacheS = self._inject(
+                self._cache, self._table, self._lens, self._table_window
+            )
             decode_args = {
                 "step": self.step_count, "batch": len(active), **span_args
             }
@@ -1960,6 +2145,8 @@ class ServingEngine:
             "quant": self.quant_report,
             "kv_quant": self.kv_quant,
             "kv_bytes_per_token": self.block_bytes // self.block_size,
+            **({"window_block_bytes": self.window_block_bytes}
+               if self.window_blocks else {}),
             **self.scheduler.latent_and_expert_gauges(),
             "attn_kernel": self.attn_kernel,
             "max_prefills_per_step": self.max_prefills,

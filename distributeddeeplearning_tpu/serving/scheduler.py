@@ -917,6 +917,9 @@ class RequestState:
         default_factory=list
     )
     decode_route: bool = False
+    # Blocks of the window layers' pool (a model with window layers only):
+    # the lane's ring, reserved whole at admission, freed at retirement.
+    window_blocks: list[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     generated: list[int] = dataclasses.field(default_factory=list)
     admit_s: float | None = None
@@ -967,11 +970,21 @@ class Scheduler:
     def __init__(self, slots: int, pool: KVBlockPool, max_seq_len: int, *,
                  kv_bytes_per_token: int | None = None,
                  latent_bytes_per_token: int | None = None,
-                 kv_quant: str | None = None, role: str | None = None):
+                 kv_quant: str | None = None, role: str | None = None,
+                 window_pool: KVBlockPool | None = None,
+                 window_ring: int = 0):
         if slots < 1:
             raise ValueError(f"serving.slots must be >= 1, got {slots}")
         self.slots: list[RequestState | None] = [None] * slots
+        # ``pool`` counts the GLOBAL layers' blocks (every token of a lane,
+        # the only kind most models have). A model with window layers has
+        # a second kind of state beside it: ``window_pool`` (a free list of
+        # its own, no trie), of which a lane reserves at most
+        # ``window_ring`` blocks however long it grows (docs/SERVING.md).
         self.pool = pool
+        self.window_pool = window_pool
+        self.window_ring = int(window_ring)
+        self.window_ring_wraps = 0  # the engine counts (engine._map_window)
         self.max_seq_len = max_seq_len
         # Capacity labels (engine-provided, None = omit from gauges()):
         # the fleet gauge merge compares replicas' KV capacity in BYTES,
@@ -985,6 +998,9 @@ class Scheduler:
         # every row the experts computed counted (idle lanes and prompt
         # padding too); None until a model with experts has run a call.
         self.expert_load: list[list[int]] | None = None
+        # Of those pairs, the ones routed to experts held here; None for a
+        # model that holds every expert (note_expert_load).
+        self.expert_pairs_held: int | None = None
         self.kv_quant = kv_quant
         # Disaggregation phase role (None = omit from gauges(), the
         # pre-role gauge shape). The engine keeps the two handoff
@@ -1116,6 +1132,19 @@ class Scheduler:
             need = blocks_for(
                 max(cover, plen + req.max_new_tokens), bs
             ) - (len(cached) - n_host)
+            # Each kind of layer state is reserved by its own count: every
+            # token for the global layers (``need``), the ring and no more
+            # for the window layers. (Bucket padding past the prompt's last
+            # block is never written there: the engine maps it to the null
+            # block, so the ring need not cover the bucket.)
+            window_need = 0
+            if self.window_pool is not None:
+                window_need = min(
+                    blocks_for(plen + req.max_new_tokens, bs),
+                    self.window_ring,
+                )
+                if not self.window_pool.can_alloc(window_need):
+                    break
             # Acquire BEFORE alloc: alloc may evict refcount-0 trie nodes,
             # and the matched chain must survive it. Acquiring host nodes
             # also pins them (refcount > 0) against final eviction while
@@ -1125,6 +1154,8 @@ class Scheduler:
             if blocks is None:
                 self.pool.release(cached)
                 break
+            if window_need:
+                state.window_blocks = self.window_pool.alloc(window_need)
             promoted: list[tuple[int, bytes]] = []
             if n_host:
                 host_ids = cached[len(cached) - n_host:]
@@ -1244,6 +1275,9 @@ class Scheduler:
         else:
             self.pool.free(state.blocks)
         state.blocks = []
+        if state.window_blocks:
+            self.window_pool.free(state.window_blocks)
+            state.window_blocks = []
         self.slots[slot] = None
         return state
 
@@ -1273,6 +1307,13 @@ class Scheduler:
             "used_blocks": self.pool.used_blocks,
             "block_high_water": self.pool.high_water,
         }
+        if self.window_pool is not None:
+            out["window"] = {
+                "ring_blocks": self.window_ring,
+                "num_blocks": self.window_pool.num_blocks,
+                "block_high_water": self.window_pool.high_water,
+                **self.layer_kind_gauges(),
+            }
         if self.role is not None:
             out["role"] = self.role
             out["handed_off"] = len(self.handed_off)
@@ -1302,11 +1343,17 @@ class Scheduler:
             }
         return out
 
-    def note_expert_load(self, load) -> None:
+    def note_expert_load(self, load, held: tuple | None = None) -> None:
         """Fold one call's per-layer per-expert token counts (rows of
         ints, as the served program returned them) into the running
-        totals."""
+        totals; ``held`` is the ``(first, count)`` of the experts the
+        model holds here, None where it holds all."""
         rows = [[int(n) for n in row] for row in load]
+        if held is not None:
+            first, count = held
+            self.expert_pairs_held = (self.expert_pairs_held or 0) + sum(
+                sum(row[first:first + count]) for row in rows
+            )
         if self.expert_load is None:
             self.expert_load = rows
         else:
@@ -1315,13 +1362,44 @@ class Scheduler:
                 for old, new in zip(self.expert_load, rows)
             ]
 
+    def layer_kind_gauges(self) -> dict:
+        """Blocks per kind of layer state, for a model that has two
+        (empty otherwise): ``*_blocks_reserved`` is what admission took
+        from each pool and holds until the lane retires
+        (``global_blocks_reserved`` is ``used_blocks``);
+        ``*_blocks_live`` is what the lanes' tokens fill of it now (a lane
+        of T tokens: ``blocks_for(T)`` global blocks and at most the ring
+        of window blocks); ``window_ring_wraps`` counts the times a ring
+        slot was handed to a newer block of its lane."""
+        if self.window_pool is None:
+            return {}
+        lanes = self.active
+        live = [
+            blocks_for(len(s.request.prompt) + len(s.generated),
+                       self.pool.block_size)
+            for s in lanes
+        ]
+        return {
+            "global_blocks_reserved": self.pool.used_blocks,
+            "global_blocks_live": sum(live),
+            "window_blocks_reserved": self.window_pool.used_blocks,
+            "window_blocks_free": self.window_pool.free_blocks,
+            "window_blocks_live": sum(
+                min(n, len(s.window_blocks)) for n, s in zip(live, lanes)
+            ),
+            "window_ring_wraps": self.window_ring_wraps,
+        }
+
     def latent_and_expert_gauges(self) -> dict:
         """``latent_bytes_per_token`` for a latent pool and, once a model
         with experts has run, its load: ``moe_tokens_per_expert`` (the
         running counts, a row a layer), ``moe_load_max_over_mean`` (the
-        busiest expert of any layer over its layer's mean: 1.0 is even)
-        and ``moe_experts_hit_share`` (experts that got a token so far).
-        Empty for a model with neither."""
+        busiest expert of any layer over its layer's mean: 1.0 is even),
+        ``moe_experts_hit_share`` (experts that got a token so far) and,
+        where the chip holds a share of the experts,
+        ``moe_pairs_held_share`` (the (token, choice) pairs routed to the
+        held ones over all pairs: count / published where routing is
+        even). Empty for a model with neither."""
         g = {}
         if self.latent_bytes_per_token is not None:
             g["latent_bytes_per_token"] = self.latent_bytes_per_token
@@ -1335,6 +1413,10 @@ class Scheduler:
                 sum(n > 0 for row in load for n in row)
                 / sum(len(row) for row in load), 4
             )
+            if self.expert_pairs_held is not None:
+                g["moe_pairs_held_share"] = round(
+                    self.expert_pairs_held / sum(sum(row) for row in load), 4
+                )
         return g
 
     def gauges(self, now: float | None = None) -> dict:
@@ -1367,6 +1449,7 @@ class Scheduler:
             # across replicas with different kv_quant settings.
             g["kv_bytes_per_token"] = self.kv_bytes_per_token
         g.update(self.latent_and_expert_gauges())
+        g.update(self.layer_kind_gauges())
         if self.kv_quant is not None:
             g["kv_quant"] = self.kv_quant
         if self.role is not None:

@@ -214,13 +214,9 @@ def _glm_engine_case():
 
 
 @pytest.fixture(scope="module")
-def engine_program(one_chip):
-    """``(case, program) -> (engine, compiled text)``: the engine's OWN
-    programs, with the engine's own operands and donation, compiled for
-    the chip once a module however many tests read the text."""
-    from distributeddeeplearning_tpu.config import ServingConfig
-    from distributeddeeplearning_tpu.serving import ServingEngine
-
+def compile_for_chip(one_chip):
+    """What stands in for ``ServingEngine._compile``: the same function,
+    operands and donation, compiled for the described chip."""
     def compile_for_chip(fn, *args, name=None, donate_argnums=()):
         abstract = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
@@ -229,6 +225,17 @@ def engine_program(one_chip):
         return jax.jit(fn, donate_argnums=donate_argnums).lower(
             *abstract
         ).compile()
+
+    return compile_for_chip
+
+
+@pytest.fixture(scope="module")
+def engine_program(compile_for_chip):
+    """``(case, program) -> (engine, compiled text)``: the engine's OWN
+    programs, with the engine's own operands and donation, compiled for
+    the chip once a module however many tests read the text."""
+    from distributeddeeplearning_tpu.config import ServingConfig
+    from distributeddeeplearning_tpu.serving import ServingEngine
 
     built = {}
 
@@ -305,6 +312,65 @@ def test_engine_programs_take_the_pool_as_it_lies(
         for scope in ("mla_project", "mla_attend", "latent_write",
                       "moe_route", "moe_experts", "moe_shared"):
             assert any(f"/{scope}/" in o for o in ops), scope
+
+
+def test_window_and_global_layers_decode_on_both_pools_as_they_lie(
+        compile_for_chip):
+    # The Command A+ family at the served cell's widths
+    # (benchmarks/configs/command_a_plus.json: 128 query heads on 8 KV
+    # heads of 128, so K and V are 1,024 wide; a window layer and a global
+    # one; 2 of 128 experts held; the parameters stay shapes). Its decode
+    # program takes both kinds of pool as they lie: every leaf of either
+    # kind a donated parameter in its declared order, no pool-shaped copy;
+    # a window layer gathers its ring (5 blocks of 16 at window 64) and
+    # the global layer every page; the held experts run as the compiler's
+    # grouped product under the scopes the trace is read by.
+    import flax
+
+    from distributeddeeplearning_tpu.config import ServingConfig
+    from distributeddeeplearning_tpu.serving import ServingEngine
+
+    model = models.get_model(
+        "cohere2_moe", layer_types=("sliding_attention", "full_attention"),
+        held_experts=(0, 2), vocab_size=512, max_len=256, window=64,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    params = flax.core.meta.unbox(jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)
+    )["params"])
+    eng = ServingEngine(model, params, ServingConfig(
+        slots=8, block_size=16, hbm_budget_mb=19, max_seq_len=256,
+        prompt_buckets=(32,),
+    ))
+    assert (eng.num_blocks, eng.window_blocks, eng.window_ring) == (263, 41, 5)
+    eng._compile = compile_for_chip
+    text = eng._decode_exe_or_compile().as_text()
+    assert text.startswith("HloModule jit__decode_fn")
+    pool_params = {}
+    for name, rows in (("pool_(?:key|value)_window", 41),
+                       ("pool_(?:key|value)", 263)):
+        shape = rf"bf16\[{rows},16,1024\]"
+        found = dict(re.findall(
+            rf"%(\S*{name}\S*) = {shape}\S* parameter\((\d+)\)", text
+        ))
+        assert len(found) == 2, (name, found)
+        pool_params.update(found)
+        entry = re.findall(rf"{shape}\{{([\d,]+)", text.split("\n")[0])
+        assert entry and set(entry) == {"2,1,0"}, entry
+        assert not re.findall(rf"= {shape}\S* copy\(", text)
+    aliased = set(re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?\)) \}", text).group(1),
+    ))
+    assert set(pool_params.values()) <= aliased, (pool_params, aliased)
+    # what each kind gathers of a lane: the ring, and every page
+    assert re.search(r"bf16\[8,5,16,1024\]", text)
+    assert re.search(r"bf16\[8,16,16,1024\]", text)
+    assert "ragged-dot" in text
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("attn_window", "attn_global", "kv_write", "moe_route",
+                  "moe_experts", "moe_shared"):
+        assert any(f"/{scope}/" in o for o in ops), scope
 
 
 def _hlo_computations(text: str) -> dict:
