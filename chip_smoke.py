@@ -292,11 +292,21 @@ def phase_serve(config: str, overrides: list[str], *, prompt_lens,
         rec = compare_with_full_forward(
             model, params, prompts, generated, tol=tol
         )
-        if kernel == "pallas" and expect_kernels:
+        # Each path is demanded by name (undemanded, the engine would
+        # choose the kernel on the chip and be compared with itself).
+        want = "in_place" if kernel == "pallas" else "gather"
+        path = engine.stats()["read_path"]
+        check(
+            path == want,
+            f"attn_kernel={kernel}: the engine reads {path}, not {want}",
+        )
+        if expect_kernels:
             check(
-                "tpu_custom_call" in engine._decode_exe_or_compile().as_text(),
-                "attn_kernel=pallas decode executable holds no "
-                "tpu_custom_call",
+                ("tpu_custom_call"
+                 in engine._decode_exe_or_compile().as_text())
+                == (kernel == "pallas"),
+                f"attn_kernel={kernel}: the decode executable's "
+                "tpu_custom_call does not match the path asked for",
             )
         rec["executables"] = engine.num_compiles
         rec["compile_seconds"] = round(sum(d for _, d in cold), 2)
@@ -698,7 +708,7 @@ def main(argv=None) -> int:
             ["model.kwargs.attn_impl=xla", "data.batch_size=1",
              "serving.hbm_budget_mb=2048", *seed],
             prompt_lens=(7, 33, 120, 300), max_new_tokens=16,
-            kernels=("reference", "pallas"), tol=SERVE_LOGIT_TOL,
+            kernels=("gather", "pallas"), tol=SERVE_LOGIT_TOL,
             seed=args.seed, expect_kernels=True, compiles=compiles,
         )
         phase_kernels(

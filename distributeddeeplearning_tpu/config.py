@@ -342,12 +342,20 @@ class ServingConfig:
     # Emit queue-depth / free-block gauges (metrics.serving_gauges) every
     # this many engine steps through the engine's event stream. 0 = off.
     gauge_every: int = 0
-    # Paged-attention read path for the decode hot loop: 'reference'
-    # (gather each row's pages per layer per step) or 'pallas' (the fused
-    # ops/paged_attention.py kernel reads the pool in place via
-    # scalar-prefetch page-table indirection; interpret mode off-TPU, so
-    # both paths run everywhere). Requires block_size % 8 == 0 (sublane
-    # tile) — fenced at config time.
+    # A demand on the decode hot loop's read path, not the switch: the
+    # engine chooses by serving.engine.read_path from what it observes
+    # (in place on a TPU over unquantized per-head K/V pools, no window
+    # layer, no speculation, block_size % 8 == 0, kv_heads * head_dim a
+    # multiple of 128; else the gather).
+    # 'reference' demands nothing, and keeps its name only because the
+    # benchmark's files spell it (ROADMAP D3 drops the field once the
+    # latent and windowed reads are in place too). 'pallas' demands the
+    # in-place read (ops/paged_attention.py reads a lane's live pages
+    # through the scalar-prefetched page table; interpret mode off-TPU, so
+    # the parity tests run it everywhere; block_size % 8 == 0 and the
+    # other fences by name at config time); 'gather' demands the copy of
+    # every page of every lane (the oracle chip_smoke.py sets the kernel
+    # against).
     attn_kernel: str = "reference"
     # Prefill/decode priority: cap request admissions (one prefill each)
     # per engine step so queue bursts interleave between decode steps
@@ -361,8 +369,8 @@ class ServingConfig:
     # paged cache, and accepts the longest greedy-matching prefix —
     # token-for-token identical to non-speculative greedy. Greedy-only
     # (sampled requests are fenced at submit); requires K >= 1,
-    # K < block_size, and attn_kernel='reference' (the Pallas kernel is
-    # single-token for now) — all fenced by name at config time.
+    # K < block_size, and no demanded attn_kernel='pallas' (the Pallas
+    # kernel is single-token for now) — all fenced by name at config time.
     speculation: str = "off"
     # Shared-prefix KV reuse (docs/SERVING.md prefix-cache section): full
     # KV blocks become immutable and content-addressed in a hash-chained

@@ -45,7 +45,7 @@ class GPT2(nn.Module):
     # (transformer.paged_decode_attention). Requires decode=True.
     kv_pages: tuple | None = None
     # Paged read path: 'reference' (gather) or 'pallas' (fused in-place
-    # kernel, ops/paged_attention.py) — serving.attn_kernel.
+    # kernel, ops/paged_attention.py), as serving.engine.read_path chose.
     paged_kernel: str = "reference"
     # Paged pool storage: 'off' or 'int8' (quantize at scatter, dequant
     # on read) — serving.kv_quant (transformer.paged_decode_attention).

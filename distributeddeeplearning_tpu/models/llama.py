@@ -109,7 +109,7 @@ class LlamaAttention(nn.Module):
     # cursors + block-pool KV storage. Requires decode=True.
     kv_pages: tuple | None = None
     # Paged read path: 'reference' (gather) or 'pallas' (fused in-place
-    # kernel, ops/paged_attention.py) — serving.attn_kernel.
+    # kernel, ops/paged_attention.py), as serving.engine.read_path chose.
     paged_kernel: str = "reference"
     # Paged pool storage: 'off' or 'int8' (quantize at scatter, dequant
     # on read) — serving.kv_quant (transformer.paged_decode_attention).
@@ -348,7 +348,7 @@ class Llama(nn.Module):
     # KV storage (transformer.paged_decode_attention). Requires decode=True.
     kv_pages: tuple | None = None
     # Paged read path: 'reference' (gather) or 'pallas' (fused in-place
-    # kernel, ops/paged_attention.py) — serving.attn_kernel.
+    # kernel, ops/paged_attention.py), as serving.engine.read_path chose.
     paged_kernel: str = "reference"
     # Paged pool storage: 'off' or 'int8' — serving.kv_quant.
     kv_quant: str = "off"

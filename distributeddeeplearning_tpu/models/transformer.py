@@ -191,7 +191,8 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
     same cursor positions before any query can attend them; causal
     masking hides them within the step that wrote them.
 
-    ``kernel`` selects the read path (``serving.attn_kernel``):
+    ``kernel`` selects the read path (the engine passes what
+    ``serving.engine.read_path`` chose: ``pallas`` for ``in_place``):
     - ``reference``: gather each row's pages into a contiguous
       [B, pages*bs] view and run ``_cache_attend`` — materializes the
       gathered cache per layer per step (the CPU-sim reference lowering);
@@ -201,8 +202,9 @@ def paged_decode_attention(module, q, k, v, *, dtype, kv_pages,
       only: bulk prefill runs once per request and keeps the gather —
       the hot loop is the per-step decode. The speculative verify
       forward is L > 1 every step, so it would silently fall back to the
-      gather here — ``speculation x attn_kernel='pallas'`` is therefore
-      fenced by name at config time until a multi-token kernel lands.
+      gather here — the rule takes the gather for a speculating engine
+      and a demanded ``speculation x attn_kernel='pallas'`` is fenced by
+      name at config time until a multi-token kernel lands.
 
     The pool WRITE (scatter at the cursor) is the same XLA
     scatter-at-indices in both modes; only the read side differs.
@@ -746,7 +748,7 @@ class SelfAttention(nn.Module):
     # instead of the contiguous per-sequence cache.
     kv_pages: tuple | None = None
     # Paged read path: 'reference' (gather) or 'pallas' (in-place fused
-    # kernel, ops/paged_attention.py) — serving.attn_kernel.
+    # kernel, ops/paged_attention.py), as serving.engine.read_path chose.
     paged_kernel: str = "reference"
     # Paged pool storage: 'off' (model dtype) or 'int8' (quantize at
     # scatter, dequant on read; scale pools ride in the cache) —

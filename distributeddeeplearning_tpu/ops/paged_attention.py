@@ -1,69 +1,72 @@
 """Paged-attention decode kernel — Pallas Mosaic, for the serving engine.
 
 The serving hot loop (``serving/engine.py``) decodes ONE token per row
-against a block-pooled KV cache. The reference lowering
-(``transformer.paged_decode_attention``) gathers each row's pages into a
-contiguous ``[B, pages*block_size]`` view per layer per step — correct,
-but it materializes the whole gathered cache in HBM every decode step.
-This kernel reads the pool IN PLACE: the page table rides in as a
-scalar-prefetch operand, so each grid step's BlockSpec index_map resolves
-``page_table[b, j]`` and the DMA engine fetches exactly that physical
-block — no gathered copy exists at any point.
+against a block-pooled KV cache. The gather lowering
+(``transformer.paged_decode_attention``) copies every page of every row's
+table into a contiguous ``[B, pages*block_size]`` view per layer per step,
+whatever the rows hold. This kernel reads a row's LIVE pages where they
+lie: the page table and the cursors ride in as scalar-prefetch operands,
+the pools stay in HBM, and the kernel copies ``table[b, j]`` for the pages
+up to the row's cursor and no other. The engine takes it by its own rule
+(``serving.engine.read_path``).
 
 Layout (see pallas_guide.md and ops/flash_attention.py, the idiom seed):
-- grid is ``(batch, pages_per_seq)`` — pages innermost, which is
-  sequential on TPU, so the online-softmax carries (m, l, acc) live in
-  VMEM scratch across a row's pages;
-- ``pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=2)``: the page
-  table and the per-row cursors are scalar operands available to BOTH the
-  index_maps (physical block selection) and the kernel body (causal
-  masking at the row's cursor);
-- one grid step takes a WHOLE page as it is stored: the block
-  ``(1, block_size, kv_heads*D)`` over the ``[NB, BS, G*D]`` pool has its
-  last two dims equal to the array's, and they fill the (8, 128) tile with
-  no padding (G*D is 768, 1024 or 512 here; 16 rows are one bf16 tile), so
-  the DMA is one contiguous lane-dense run — 24 KB at GPT-2 124M. The
-  pool keeps heads folded into lanes because a ``(G, D) = (12, 64)`` minor
-  pair pads 2.7x and makes the runtime store the block index minor-most
-  (``transformer.paged_decode_attention`` says what that cost);
-- heads are split on the page in VMEM by static lane slices (Mosaic
-  refuses the ``(BS, G*D) -> (BS, G, D)`` shape cast at D=64): one
-  lane-dense multiply ``k * q`` per rep, then per head a lane reduce of
-  its D-wide slice. The softmax state is then head-vectorised, scores
-  ``(BS, G)`` and carries ``(1, G)``. No MXU: a one-token decode is an
-  M=1 matmul per head, DMA-bound either way;
-- GQA: q arrives group-major (query head ``g*num_rep + r`` reads kv
-  group ``g``, matching ``transformer._cache_attend``) and is handed to
-  the kernel as ``[B, num_rep, kv_heads*D]`` — rep ``r``'s row lines up
-  with the page's lanes, so the pool is never repeated to the query head
-  count;
-- pages entirely beyond a row's cursor are skipped with ``pl.when`` (no
-  VPU work on the accumulate path); the cursor page is masked per slot
-  with ``broadcasted_iota``;
-- all accumulation is fp32 regardless of pool dtype; on CPU backends the
-  kernel runs in interpret mode, which is how the parity tests exercise
-  it without a TPU (the TPU lowering is compiled devicelessly in
-  ``tests/test_tpu_compile.py`` and run by ``chip_smoke.py``).
+- the grid is ``(batch,)``, one row (lane) a grid step, sequential; inside
+  it a loop over GROUPS of G pages (``group_pages``: 256 tokens), only as
+  many as the row's ``seq_lens[b] // block_size + 1`` live pages need. A
+  grid of ``(batch, pages)`` with one 24 KB page a step was bound by its
+  8,192 grid steps a call, two thirds of them past a cursor (PERF.md §6,
+  PR 32: 2.83 ms a call at the GPT-2 cell's shapes against 0.34 here);
+- a row's pages are not contiguous, so no ``BlockSpec`` can fetch a
+  group: each live page is one ``pltpu.make_async_copy`` of the block
+  ``[block_size, kv_heads*D]`` as it is stored (lane-dense, contiguous:
+  ``transformer.paged_decode_attention`` says why the pool folds heads
+  into lanes) into one of two VMEM slots. While a group is attended the
+  next one is in flight in the other slot, and behind a row's last group
+  the next row's first: the slot a row starts in is carried in SMEM;
+- scores and weighted sum run on the MXU against the pages AS STORED,
+  heads never split: the query is laid block-diagonal, row ``r*G + g``
+  holding rep r's q on head g's D lanes and 0 elsewhere, so
+  ``q_diag [rows, G*D] . K^T [G*D, tokens]`` is every head's scores with
+  the tokens on the lanes, and ``P [rows, tokens] . V [tokens, G*D]`` is
+  every head's output on its own lanes of its own row (the other lanes of
+  a row, a head's weights on another head's V, are dropped at the end).
+  GQA: the ``num_rep`` query heads of a group are more rows against the
+  same un-repeated page (q arrives group-major, query head
+  ``g*num_rep + r`` reads kv group ``g``, as ``transformer._cache_attend``);
+- bf16 q against bf16 (or int8) pages multiplies exactly into f32 sums in
+  one MXU pass; the probabilities go in as two bf16 terms (16 bits of
+  mantissa) stacked on the rows, so V passes the MXU once. f32 pools or
+  queries take the six-pass f32 product. m, l and acc are f32 loop
+  carries;
+- a page past the cursor inside a row's last group is not copied: its
+  slot keeps an earlier group's page (pool data; zeros before any), and
+  the cursor mask (``broadcasted_iota`` over the tokens) gives every
+  column past the cursor weight 0;
+- on CPU backends the kernel runs in interpret mode, which is how the
+  parity tests exercise it without a TPU (the TPU lowering is compiled
+  devicelessly in ``tests/test_tpu_compile.py`` and run by
+  ``chip_smoke.py``).
 
-Semantics match the reference gather exactly: the caller has already
-scattered this step's k/v into the pool at position ``seq_lens[b]``, and
-row b attends columns ``0 .. seq_lens[b]`` inclusive. Idle rows (cursor
-0, page table parked on the null block) attend exactly position 0 of the
-null block — same as the reference; the engine discards their output.
+Semantics match the gather exactly: the caller has already scattered this
+step's k/v into the pool at position ``seq_lens[b]``, and row b attends
+columns ``0 .. seq_lens[b]`` inclusive. Idle rows (cursor 0, page table
+parked on the null block) attend exactly position 0 of the null block —
+same as the gather; the engine discards their output.
 
 Quantized pools (``serving.kv_quant='int8'``): the pool arrives as int8
 with one f32 scale per (page slot, kv head) D-vector in parallel scale
 pools ``[num_blocks, block_size, kv_heads]`` (written at scatter time by
-``transformer.paged_decode_attention``). The quantized kernel variant
-adds two ``(1, block_size, kv_heads)`` BlockSpec operands whose index_maps
-follow the SAME ``page_table[b, j]`` indirection — the per-page DMA pulls
-the int8 page AND its scale rows into VMEM together, and the dequant
-(``values.astype(f32) * scale``, the ``comms_quant`` codec inverse) is
-fused inline: a scale is constant over its head's D lanes, so it
-multiplies the (slot, head) score and probability instead of the page
-(``sum_d(k*s*q) = s*sum_d(k*q)``). The fp32 carries (m, l, acc) are
-unchanged, so the only numerics delta vs the fp kernel is the
-quantization grid itself.
+``transformer.paged_decode_attention``). An int8 page goes to the MXU as
+bf16 (exact) and the dequant (``values.astype(f32) * scale``, the
+``comms_quant`` codec inverse) is applied where it is cheapest: a scale is
+constant over its head's D lanes, so it multiplies the (head, token) score
+and probability instead of the page (``sum_d(k*s*q) = s*sum_d(k*q)``). The
+scale pools are head-minor, which no copy on the chip can slice a page out
+of (a minor dimension under 128), so the rows' scales, a sixteenth of the
+pages' bytes, are gathered outside the kernel and handed over as the
+scores lie. The only numerics delta vs the fp kernel is the quantization
+grid itself.
 """
 
 from __future__ import annotations
@@ -83,79 +86,170 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# Tokens a loop step of the kernel attends: two lane tiles of scores. On
+# the chip (PERF.md §6, PR 32) 64 tokens a step took 1.7 times as long as
+# 128 over full tables, and 256 a fifth less than 128; equal where rows
+# are short, which pay by the row and not by the token.
+_GROUP_TOKENS = 256
+
+
+def group_pages(block_size: int, num_pages: int) -> int:
+    """Pages the kernel fetches and attends in one loop step (G): as many
+    as make :data:`_GROUP_TOKENS` tokens, whole lane tiles of scores; at
+    least one page, at most the table. One rule from the shapes, as
+    ``flash_attention.tiles`` is."""
+    return max(1, min(int(num_pages), _GROUP_TOKENS // int(block_size)))
+
+
+def _head_selector(num_rep: int, kv_heads: int, head_dim: int):
+    """The kernel's block-diagonal layout as two constant masks over its
+    rows ``i = r * kv_heads + g`` (padded to a multiple of 16, a bf16
+    tile): ``heads[i, lane]`` is 1 where ``lane`` is one of head g's D
+    lanes, ``reps[r, i, 0]`` where row i belongs to rep r."""
+    rows = -(-num_rep * kv_heads // 16) * 16
+    i = np.arange(rows)[:, None]
+    live = i < num_rep * kv_heads
+    lane_head = np.arange(kv_heads * head_dim)[None, :] // head_dim
+    heads = live & (i % kv_heads == lane_head)
+    reps = np.stack([live & (i // kv_heads == r) for r in range(num_rep)])
+    return heads.astype(np.float32), reps.astype(np.float32)
+
+
 def _decode_kernel(
-    table_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-    sm_scale, block_size, num_pages, kv_heads, quantized,
+    table_ref, lens_ref, q_ref, heads_ref, reps_ref, *rest,
+    sm_scale, block_size, num_pages, group, quantized,
 ):
-    """One (row, page) grid step. ``q_ref`` (1, R, G*D); ``k_ref`` /
-    ``v_ref`` (1, BS, G*D), the page as stored; quantized pools add
-    ``sk_ref`` / ``sv_ref`` (1, BS, G) — the page's scale rows, fetched by
-    the same ``tbl[b, j]`` index_map as the page. Carries per rep: m, l
-    (1, G), acc (1, G*D)."""
+    """One lane a grid step. ``q_ref`` (1, R, G*D); ``heads_ref``
+    (rows, G*D) and ``reps_ref`` (R, rows, 1), :func:`_head_selector`;
+    quantized pools add ``sk_ref``
+    / ``sv_ref`` (1, groups, rows, tokens), the lane's scales with tokens
+    on the lanes; ``k_hbm`` / ``v_hbm`` the pools where they lie; ``kbuf``
+    / ``vbuf`` (2, group, BS, G*D): two slots of pages as stored; ``sems``
+    (2, 2): slot x (K, V); ``slot_ref``: the slot that holds this lane's
+    first group, carried from lane to lane."""
     if quantized:
-        sk_ref, sv_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        sk_ref, sv_ref, *rest = rest
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, slot_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_rep = q_ref.shape[1]
-    head_dim = q_ref.shape[2] // kv_heads
+    lanes = pl.num_programs(0)
+    num_rep, width = q_ref.shape[1], q_ref.shape[2]
+    rows = heads_ref.shape[0]
+    tokens = group * block_size
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    # bf16 q against bf16 or int8 pages multiplies exactly into the MXU's
+    # f32 sums; anything else (f32 pools, f32 q) takes the f32 product.
+    exact = q_ref.dtype == bf16 and kbuf.dtype in (bf16, jnp.int8)
+    precision = None if exact else jax.lax.Precision.HIGHEST
 
-    def head(x, g):  # head g's D lanes of a [.., G*D] value
-        return x[:, g * head_dim:(g + 1) * head_dim]
+    def live_pages(lane):
+        return jnp.minimum(lens_ref[lane] // block_size + 1, num_pages)
 
-    def per_head(fn):  # [.., G*n] from fn(g) -> [.., n]
-        return jnp.concatenate([fn(g) for g in range(kv_heads)], axis=1)
+    def each_live_page(lane, j, slot, do):
+        # Only the lane's live pages move: a slot's other pages keep what
+        # an earlier group left there (pool data, or the zeros of _first),
+        # and the cursor mask gives them weight 0.
+        n = live_pages(lane)
+        for i in range(group):
+            @pl.when(j * group + i < n)
+            def _():
+                at = table_ref[lane, j * group + i]
+                do(pltpu.make_async_copy(
+                    k_hbm.at[at], kbuf.at[slot, i], sems.at[slot, 0]))
+                do(pltpu.make_async_copy(
+                    v_hbm.at[at], vbuf.at[slot, i], sems.at[slot, 1]))
 
-    def over_lanes(x):  # (1, G) -> (1, G*D): head g's value on its lanes
-        return per_head(
-            lambda g: jnp.broadcast_to(x[:, g:g + 1], (1, head_dim))
-        )
+    def start(lane, j, slot):
+        each_live_page(lane, j, slot, lambda dma: dma.start())
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def wait(lane, j, slot):
+        each_live_page(lane, j, slot, lambda dma: dma.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
 
     pos = lens_ref[b]  # this row's query position (cursor, pre-advance)
+    num_groups = (live_pages(b) + group - 1) // group
+    slot0 = slot_ref[0]
+    # The block-diagonal query: row r*G+g holds rep r's q on head g's
+    # lanes and 0 elsewhere, so one product against a page as it is stored
+    # gives every head's scores, [rows, tokens], tokens on the lanes.
+    q = q_ref[0].astype(f32)
+    qd = heads_ref[...] * sum(
+        q[r:r + 1] * reps_ref[r] for r in range(num_rep)
+    )
+    if exact:
+        qd = qd.astype(bf16)
 
-    # Pages strictly beyond the cursor hold no visible columns — skip.
-    @pl.when(j * block_size <= pos)
-    def _page():
-        k = k_ref[0].astype(jnp.float32)  # (BS, G*D)
-        v = v_ref[0].astype(jnp.float32)
-        col = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_size, 1), 0
-        )
-        for r in range(num_rep):
-            q = q_ref[0, r:r + 1].astype(jnp.float32) * sm_scale  # (1, G*D)
-            kq = k * q
-            s = per_head(  # (BS, G)
-                lambda g: jnp.sum(head(kq, g), axis=-1, keepdims=True)
-            )
-            if quantized:
-                s = s * sk_ref[0]
-            s = jnp.where(col <= pos, s, _NEG_INF)
-            m_prev = m_scr[r]  # (1, G)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=0, keepdims=True)
-            m_scr[r] = m_new
-            pw = p * sv_ref[0] if quantized else p
-            pv = per_head(  # (1, G*D)
-                lambda g: jnp.sum(
-                    pw[:, g:g + 1] * head(v, g), axis=0, keepdims=True
-                )
-            )
-            acc_scr[r] = acc_scr[r] * over_lanes(alpha) + pv
+    def stored(buf, slot):  # (group, BS, G*D) -> (tokens, G*D)
+        x = buf[slot]
+        if exact and x.dtype == bf16 and block_size % 16 == 0:
+            return x.reshape(tokens, width)  # whole bf16 tiles: no cast
+        x = x.astype(f32).reshape(tokens, width)
+        return x.astype(bf16) if exact else x
 
-    @pl.when(j == num_pages - 1)
-    def _finalize():
-        for r in range(num_rep):
-            l = over_lanes(jnp.maximum(l_scr[r], 1e-30))
-            o_ref[0, r:r + 1] = (acc_scr[r] / l).astype(o_ref.dtype)
+    def body(j, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + j) % 2
+        # Fetch ahead into the other slot: this lane's next group, or,
+        # behind its last, the next lane's first.
+        @pl.when(j + 1 < num_groups)
+        def _():
+            start(b, j + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(j + 1 == num_groups, b + 1 < lanes))
+        def _():
+            start(b + 1, 0, 1 - slot)
+
+        wait(b, j, slot)
+        s = jax.lax.dot_general(
+            qd, stored(kbuf, slot), (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=f32,
+        ) * sm_scale  # (rows, tokens)
+        if quantized:
+            # A scale is constant over its head's D lanes, so it
+            # multiplies the score and the probability, not the page.
+            s = s * sk_ref[0, j]
+        col = j * tokens + jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+        s = jnp.where(col <= pos, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if quantized:
+            p = p * sv_ref[0, j]
+        v = stored(vbuf, slot)
+        if exact:
+            # p as two bf16 terms in one product: v passes the MXU once
+            # and p keeps 16 bits of its f32 mantissa.
+            hi = p.astype(bf16)
+            lo = (p - hi.astype(f32)).astype(bf16)
+            pv = jnp.dot(
+                jnp.concatenate([hi, lo], axis=0), v,
+                preferred_element_type=f32,
+            )
+            pv = pv[:rows] + pv[rows:]
+        else:
+            pv = jnp.dot(
+                p, v, precision=precision, preferred_element_type=f32
+            )
+        return m_new, l_new, acc * alpha + pv  # acc (rows, G*D)
+
+    _, l, acc = jax.lax.fori_loop(0, num_groups, body, (
+        jnp.full((rows, 1), _NEG_INF, f32), jnp.zeros((rows, 1), f32),
+        jnp.zeros((rows, width), f32),
+    ))
+    slot_ref[0] = (slot0 + num_groups) % 2
+    # Row r*G+g's own lanes are head g's output; the rest of the row, a
+    # head's weights on another head's V, goes.
+    out = acc / jnp.maximum(l, 1e-30) * heads_ref[...]
+    for r in range(num_rep):
+        o_ref[0, r:r + 1] = jnp.sum(
+            out * reps_ref[r], axis=0, keepdims=True
+        ).astype(o_ref.dtype)
 
 
 def _check_scales(pool_k, scale_k, scale_v, kv_heads):
@@ -241,55 +335,98 @@ def paged_attention(
         sm_scale = float(1.0 / np.sqrt(D))
     if interpret is None:
         interpret = _default_interpret()
-    quantized = _check_scales(pool_k, scale_k, scale_v, kv_heads)
+    _check_scales(pool_k, scale_k, scale_v, kv_heads)
+    if not interpret and width % 128:
+        raise NotImplementedError(
+            f"paged_attention x pool width {width} (kv_heads*head_dim): "
+            "the chip copies a page out of the pool only in whole 128-lane "
+            "rows — the gather read path serves such a (toy) width"
+        )
+    return _paged_call(
+        q, pool_k, pool_v, jnp.asarray(page_table, jnp.int32),
+        jnp.asarray(seq_lens, jnp.int32), scale_k, scale_v,
+        int(num_rep), float(sm_scale), bool(interpret),
+    )
+
+
+# jit here: a model calls paged_attention once a layer with the same shapes,
+# and the jit's cache hands every call after the first the same jaxpr, so
+# the kernel is traced, and lowered to Mosaic, once a program and not once
+# a layer (flash_attention._fwd says what that cost a start).
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _paged_call(q, pool_k, pool_v, page_table, seq_lens, scale_k, scale_v,
+                num_rep, sm_scale, interpret):
+    B, H, D = q.shape
+    _, block_size, width = pool_k.shape
+    kv_heads = H // num_rep
+    num_pages = page_table.shape[-1]
+    quantized = scale_k is not None
 
     # Group-major head fold: head g*num_rep+r -> (group g, rep r), then
     # rep-major so each rep's G*D row matches the page's lanes.
     q3 = q.reshape(B, kv_heads, num_rep, D).transpose(0, 2, 1, 3).reshape(
         B, num_rep, width
     )
+    group = group_pages(block_size, num_pages)
+    groups = -(-num_pages // group)
+    heads, reps = _head_selector(num_rep, kv_heads, D)
+    rows = heads.shape[0]
     kernel = functools.partial(
         _decode_kernel, sm_scale=sm_scale, block_size=block_size,
-        num_pages=num_pages, kv_heads=kv_heads, quantized=quantized,
+        num_pages=num_pages, group=group, quantized=quantized,
     )
-    q_spec = pl.BlockSpec(
-        (1, num_rep, width), lambda b, j, tbl, lens: (b, 0, 0)
-    )
-    # The paged reads: physical block (and, quantized, its scale rows)
-    # straight off the scalar-prefetched table.
-    page_spec = pl.BlockSpec(
-        (1, block_size, width), lambda b, j, tbl, lens: (tbl[b, j], 0, 0),
-    )
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [q3, pool_k, pool_v]
+    q_spec = pl.BlockSpec((1, num_rep, width), lambda b, tbl, lens: (b, 0, 0))
+    in_specs = [
+        q_spec,
+        pl.BlockSpec(heads.shape, lambda b, tbl, lens: (0, 0)),
+        pl.BlockSpec(reps.shape, lambda b, tbl, lens: (0, 0, 0)),
+    ]
+    operands = [q3, jnp.asarray(heads), jnp.asarray(reps)]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, block_size, kv_heads),
-            lambda b, j, tbl, lens: (tbl[b, j], 0, 0),
-        )
-        in_specs += [scale_spec, scale_spec]
-        operands += [scale_k, scale_v]
+        # The scales, a sixteenth of the pages' bytes and stored
+        # kv_heads-minor (no slice of that pool is a copy the chip can
+        # make), are gathered here and handed over a lane at a time as the
+        # kernel's scores lie: [B, groups, rows, tokens].
+        def lane_scales(scale):
+            x = scale[page_table]  # [B, pages, BS, G]
+            x = jnp.pad(x, (
+                (0, 0), (0, groups * group - num_pages), (0, 0), (0, 0),
+            )).reshape(B, groups, group * block_size, kv_heads)
+            return x.transpose(0, 1, 3, 2)[:, :, np.arange(rows) % kv_heads]
+
+        in_specs += [pl.BlockSpec(
+            (1, groups, rows, group * block_size),
+            lambda b, tbl, lens: (b, 0, 0, 0),
+        )] * 2
+        operands += [lane_scales(scale_k), lane_scales(scale_v)]
+    # The pools stay where they lie: the kernel copies a lane's live pages
+    # through the prefetched table.
+    in_specs += [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+    operands += [pool_k, pool_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, num_pages),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((num_rep, 1, kv_heads), jnp.float32),
-            pltpu.VMEM((num_rep, 1, kv_heads), jnp.float32),
-            pltpu.VMEM((num_rep, 1, width), jnp.float32),
+            pltpu.VMEM((2, group, block_size, width), pool_k.dtype),
+            pltpu.VMEM((2, group, block_size, width), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, num_rep, width), q.dtype),
+        # One lane after another: a lane's last step fetches the next
+        # lane's first pages.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         name="paged_decode",
         interpret=interpret,
-    )(
-        jnp.asarray(page_table, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
-        *operands,
-    )
+    )(page_table, seq_lens, *operands)
     return out.reshape(B, num_rep, kv_heads, D).transpose(
         0, 2, 1, 3
     ).reshape(B, H, D)

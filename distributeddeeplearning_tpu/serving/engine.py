@@ -170,6 +170,63 @@ def sampler_arm(temp, top_k, top_p):
     return sampling.any().astype(np.int32) + filtering.any().astype(np.int32)
 
 
+# serving.attn_kernel domain. 'reference' demands nothing: read_path
+# decides. 'pallas' and 'gather' demand a decode read path by name (the
+# parity tests and chip_smoke.py set one against the other).
+ATTN_KERNELS = ("reference", "pallas", "gather")
+# How a decode step (L == 1) reads a lane's K and V: every page of the
+# lane's table copied out of the pool and attended whole, or the lane's
+# live pages read where they lie (ops/paged_attention.py).
+READ_PATHS = ("gather", "in_place")
+
+
+def read_path(attn_kernel: str, *, platform: str, latent: bool,
+              window: bool, width: int, kv_quant: str, speculation: str,
+              block_size: int) -> str:
+    """The decode read path (one of :data:`READ_PATHS`) of an engine, from
+    what it can observe. A demanded path is taken as asked (the fences
+    have refused by name what is not built). Undemanded
+    (``attn_kernel='reference'``), the in-place read wherever it is built
+    and compiled: on a TPU (elsewhere the kernel exists in interpret mode
+    only, a test device), over per-head K/V pools (``latent``: the cache
+    holds ``pool_latent`` leaves), in a model without window layers (no
+    window in the kernel's mask), unquantized (the int8 variant has no
+    reading on the chip yet: PERF.md §7), with speculation off (the verify
+    forward is L > 1), whole sublane tiles a block and whole 128-lane
+    rows a token (``width``, the K/V leaves' minor dimension: the chip
+    cannot copy a page out of a narrower, padded pool; a toy model's). One
+    rule for ``check_serving_composition`` and ``ServingEngine``, both of
+    which read ``latent``, ``window`` and ``width`` off the cache's leaves
+    (:func:`_cache_facts`); ``platform`` is an argument so that a CPU
+    process can ask about ``'tpu'``."""
+    if attn_kernel != "reference":
+        return "in_place" if attn_kernel == "pallas" else "gather"
+    built = (
+        platform == "tpu" and not latent and not window
+        and str(kv_quant or "off") == "off"
+        and speculation_k(speculation or "off") == 0
+        and int(block_size) % 8 == 0 and int(width) % 128 == 0
+    )
+    return "in_place" if built else "gather"
+
+
+def _cache_facts(cache_shapes) -> dict:
+    """What :func:`read_path` asks of a model's cache, read off the leaves
+    of a shape-only init: whether it holds the latent leaf, whether it
+    holds window leaves, and the K/V leaves' minor dimension (0: none)."""
+    shapes = {
+        path[-1].key: leaf.shape
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            cache_shapes
+        )[0]
+    }
+    return dict(
+        latent=_LATENT_LEAF in shapes,
+        window=any(k in shapes for k in _WINDOW_LEAVES),
+        width=shapes.get("pool_key", (0,))[-1],
+    )
+
+
 # serving.kv_quant domain: device pool storage codecs.
 KV_QUANT_MODES = ("off", "int8")
 
@@ -357,7 +414,7 @@ def _check_latent_cache(name, kv_quant, attn_kernel, spill_codec) -> None:
             "head) K/V vector and the latent leaf has no heads — an int8 "
             "latent pool is not built; keep kv_quant='off'"
         )
-    if str(attn_kernel or "reference") != "reference":
+    if str(attn_kernel or "reference") == "pallas":
         raise NotImplementedError(
             f"serving.attn_kernel={attn_kernel!r} x latent paged cache "
             f"({name!r}): ops/paged_attention.py reads per-head K and V "
@@ -381,6 +438,23 @@ def _has_window_layers(cfg) -> bool:
 
     model = models.get_model(cfg.model.name, **cfg.model.kwargs)
     return "sliding_attention" in getattr(model, "layer_types", ())
+
+
+def _probe_cache(cfg):
+    """The cache leaves (shapes only) of the model that ``cfg`` builds in
+    paged-decode mode: what the engine's own sizing probe sees, traced
+    abstractly, with no parameters and no backend."""
+    from .. import models
+
+    s = cfg.serving
+    model = models.get_model(cfg.model.name, **cfg.model.kwargs).clone(
+        decode=True, kv_pages=(1, int(s.block_size), 1),
+        kv_quant=str(getattr(s, "kv_quant", "off") or "off"),
+    )
+    return jax.eval_shape(
+        model.init, jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((1, 1), jnp.int32),
+    )["cache"]
 
 
 def _check_window_cache(name, s) -> None:
@@ -438,7 +512,7 @@ def _check_window_cache(name, s) -> None:
             "window layer's multi-token read sees the call's own tokens "
             "only (keep speculation='off')"
         )
-    if str(getattr(s, "attn_kernel", "reference")) != "reference":
+    if str(getattr(s, "attn_kernel", "reference")) == "pallas":
         raise NotImplementedError(
             f"serving.attn_kernel={s.attn_kernel!r} x {what}: "
             "ops/paged_attention.py has no window in its mask (keep "
@@ -553,11 +627,15 @@ def _check_fleet_healing(s, fleet: int) -> None:
         )
 
 
-def check_serving_composition(cfg, *, fleet: int = 0) -> None:
+def check_serving_composition(cfg, *, fleet: int = 0,
+                              platform: str | None = None) -> str | None:
     """Config-time composition fences for ``serve`` (PR-5 style: fail BY
     NAME before any compile). ``cfg`` is the full Config. ``fleet`` is
     the ``--fleet N`` worker count (0 = in-process serve) — some knobs
-    are only legal when real worker processes exist."""
+    are only legal when real worker processes exist. Given a ``platform``
+    (``jax.default_backend()``'s name; not asked here, because a fleet's
+    parent must not take the chip), returns the decode read path an engine
+    built from ``cfg`` on it takes (:func:`read_path`)."""
     name = cfg.model.name
     if name.endswith("_pp"):
         raise NotImplementedError(
@@ -603,9 +681,9 @@ def check_serving_composition(cfg, *, fleet: int = 0) -> None:
             f"lengths, got {s.prompt_buckets!r}"
         )
     kernel = getattr(s, "attn_kernel", "reference")
-    if kernel not in ("reference", "pallas"):
+    if kernel not in ATTN_KERNELS:
         raise ValueError(
-            "serving.attn_kernel must be 'reference' or 'pallas', got "
+            f"serving.attn_kernel must be one of {ATTN_KERNELS}, got "
             f"{kernel!r}"
         )
     if kernel == "pallas" and s.block_size % 8:
@@ -721,6 +799,14 @@ def check_serving_composition(cfg, *, fleet: int = 0) -> None:
     # Fleet self-healing fences (restart budget / backoff / checkpoint
     # cadence / fault-injection DSL).
     _check_fleet_healing(s, fleet)
+    if platform is None:
+        return None
+    return read_path(
+        kernel, platform=platform, **_cache_facts(_probe_cache(cfg)),
+        kv_quant=getattr(s, "kv_quant", "off"),
+        speculation=getattr(s, "speculation", "off"),
+        block_size=s.block_size,
+    )
 
 
 class ServingEngine:
@@ -738,7 +824,8 @@ class ServingEngine:
     """
 
     def __init__(self, model, params, cfg, *, emit=None,
-                 clock=time.monotonic, seed: int = 0, telemetry=None):
+                 clock=time.monotonic, seed: int = 0, telemetry=None,
+                 platform: str | None = None):
         if getattr(model, "attn_impl", "xla") != "xla":
             raise NotImplementedError(
                 f"serving x attn_impl={model.attn_impl!r} (see "
@@ -887,19 +974,30 @@ class ServingEngine:
         self.block_bytes = block_bytes
         self.window_block_bytes = window_bytes
         self.kv_pages = (self.num_blocks, bs, self.pages)
-        # Paged read path (docs/SERVING.md hot path): 'reference' gathers
-        # every row's pages per layer per step; 'pallas' reads the pool in
-        # place (ops/paged_attention.py — interpret mode off-TPU, so both
-        # modes run and parity-test everywhere).
+        # Decode read path (docs/SERVING.md hot path): every page of a
+        # lane's table gathered per layer per step, or the lane's live
+        # pages read in place (ops/paged_attention.py; interpret mode
+        # off-TPU, so both run and parity-test everywhere). attn_kernel is
+        # what was asked, read_path what read_path() made of it and of
+        # what this engine observes; ``platform`` is for a deviceless
+        # compile, which builds the chip's engine in a CPU process.
         self.attn_kernel = str(getattr(cfg, "attn_kernel", "reference"))
-        if self.attn_kernel not in ("reference", "pallas"):
+        if self.attn_kernel not in ATTN_KERNELS:
             raise ValueError(
-                "serving.attn_kernel must be 'reference' or 'pallas', got "
+                f"serving.attn_kernel must be one of {ATTN_KERNELS}, got "
                 f"{self.attn_kernel!r}"
             )
+        self.read_path = read_path(
+            self.attn_kernel, platform=platform or jax.default_backend(),
+            **_cache_facts(shapes["cache"]), kv_quant=self.kv_quant,
+            speculation=getattr(cfg, "speculation", "off"), block_size=bs,
+        )
         self.model = model.clone(
             decode=True, kv_pages=self.kv_pages,
-            paged_kernel=self.attn_kernel, kv_quant=self.kv_quant,
+            paged_kernel=(
+                "pallas" if self.read_path == "in_place" else "reference"
+            ),
+            kv_quant=self.kv_quant,
             **({"window_blocks": self.window_blocks} if window_bytes else {}),
         )
         # Prefill/decode priority: cap admissions (each costs one prefill)
@@ -955,6 +1053,7 @@ class ServingEngine:
             kv_bytes_per_token=self.block_bytes // bs,
             latent_bytes_per_token=(latent_bytes // bs) or None,
             kv_quant=self.kv_quant,
+            read_path=self.read_path,
             role=self.role,
             window_pool=(
                 KVBlockPool(self.window_blocks, bs) if window_bytes else None
@@ -2010,7 +2109,9 @@ class ServingEngine:
         arm = SAMPLER_ARMS[
             int(sampler_arm(self._temp, self._top_k, self._top_p))
         ]
-        cacheS, decode_args = self._decode_operands(active, sampler=arm)
+        cacheS, decode_args = self._decode_operands(
+            active, sampler=arm, read_path=self.read_path
+        )
         with tel.span("decode", **decode_args):
             out, rng, cacheS = self._decode_exe_or_compile()(
                 self._params, cacheS, self._tok[:, None], self._rng,
@@ -2149,6 +2250,7 @@ class ServingEngine:
                if self.window_blocks else {}),
             **self.scheduler.latent_and_expert_gauges(),
             "attn_kernel": self.attn_kernel,
+            "read_path": self.read_path,
             "max_prefills_per_step": self.max_prefills,
             "draining": self.draining,
             "speculation": None if not self.spec_k else {

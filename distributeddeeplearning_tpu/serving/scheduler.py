@@ -971,6 +971,7 @@ class Scheduler:
                  kv_bytes_per_token: int | None = None,
                  latent_bytes_per_token: int | None = None,
                  kv_quant: str | None = None, role: str | None = None,
+                 read_path: str | None = None,
                  window_pool: KVBlockPool | None = None,
                  window_ring: int = 0):
         if slots < 1:
@@ -1002,6 +1003,9 @@ class Scheduler:
         # model that holds every expert (note_expert_load).
         self.expert_pairs_held: int | None = None
         self.kv_quant = kv_quant
+        # The engine's decode read path (engine.read_path): 'gather' or
+        # 'in_place'; None = omit from gauges().
+        self.read_path = read_path
         # Disaggregation phase role (None = omit from gauges(), the
         # pre-role gauge shape). The engine keeps the two handoff
         # counters current: queue depth (export records not yet shipped)
@@ -1452,6 +1456,8 @@ class Scheduler:
         g.update(self.layer_kind_gauges())
         if self.kv_quant is not None:
             g["kv_quant"] = self.kv_quant
+        if self.read_path is not None:
+            g["read_path"] = self.read_path
         if self.role is not None:
             # Phase-split visibility: which phase this engine serves and
             # how much handoff work is queued/has moved — cli report and

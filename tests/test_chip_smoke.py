@@ -77,11 +77,11 @@ def test_serve_phase_tiny(compiles, capsys):
          "serving.hbm_budget_mb=8",
          "serving.prompt_buckets=[8,16,32]", "serving.block_size=4"],
         prompt_lens=(3, 9, 14, 20), max_new_tokens=16,
-        kernels=("reference", "pallas"), tol=chip_smoke.SERVE_LOGIT_TOL,
+        kernels=("gather", "pallas"), tol=chip_smoke.SERVE_LOGIT_TOL,
         seed=0, expect_kernels=False, compiles=compiles,
     )
     assert rec == _last_json(capsys)
-    for kernel in ("reference", "pallas"):
+    for kernel in ("gather", "pallas"):
         assert rec["kernels"][kernel]["tokens"] == 4 * 16
         assert rec["kernels"][kernel]["worst_logit_gap"] <= rec[
             "kernels"][kernel]["logit_tol"]

@@ -19,6 +19,7 @@ from distributeddeeplearning_tpu.ops import (
     paged_attention,
     paged_attention_reference,
 )
+from distributeddeeplearning_tpu.ops.paged_attention import group_pages
 
 pytestmark = pytest.mark.interpret
 
@@ -262,3 +263,69 @@ def test_scale_buffer_validation_fails_loudly():
     # the reference oracle enforces the same contract
     with pytest.raises(ValueError, match="scale"):
         paged_attention_reference(q, qk, qv, table, lens)
+
+
+# ---------------------------------------------------------------------------
+# Groups of pages: ragged cursors against the loop's every edge
+# ---------------------------------------------------------------------------
+
+# 16-token pages, 16 a group (256 tokens), a table of 40: two whole groups
+# and a half. In lane order, so that a long lane's pages are what a short
+# lane finds left in its slots: the full table; idle (cursor 0, null
+# block); a page's last slot; 21 live pages (not a multiple of 16); a
+# page's first slot; one page past a whole group; a whole group; idle.
+_RAGGED = [639, 0, 15, 330, 16, 256, 255, 0]
+_RAGGED_CASE = dict(B=len(_RAGGED), kv_heads=2, D=16, num_blocks=120,
+                    block_size=16, pages=40, lens=_RAGGED)
+
+
+def test_group_rule():
+    assert group_pages(16, 64) == 16     # the served cells
+    assert group_pages(8, 128) == 32
+    assert group_pages(16, 3) == 3       # never past the table
+    assert group_pages(512, 4) == 1      # never under a page
+    assert group_pages(16, 40) == 16 and 40 % 16  # _RAGGED ends mid-group
+
+
+@pytest.mark.parametrize("num_rep", [1, 4])
+@pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+def test_ragged_cursors_match_reference(pool, num_rep):
+    kw = dict(_RAGGED_CASE, num_rep=num_rep)
+    key = jax.random.PRNGKey(11)
+    if pool == "int8":
+        q, pk, pv, table, lens, sk, sv = _quant_case(key, **kw)
+        scales, atol = dict(scale_k=sk, scale_v=sv), 2e-5
+    else:
+        dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+        q, pk, pv, table, lens = _pool_case(key, dtype=dtype, **kw)
+        # bf16: the kernel's own sums are f32 (K, V and q multiply exactly
+        # and p keeps 16 bits); what is left is the output's rounding.
+        scales, atol = {}, 1e-2 if pool == "bf16" else 2e-5
+    out = paged_attention(
+        q, pk, pv, table, lens, num_rep=num_rep, **scales)
+    assert out.dtype == q.dtype and bool(jnp.isfinite(out).all())
+    ref = paged_attention_reference(
+        q.astype(jnp.float32),
+        pk if pool == "int8" else pk.astype(jnp.float32),
+        pv if pool == "int8" else pv.astype(jnp.float32),
+        table, lens, num_rep=num_rep, **scales)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=atol, rtol=atol)
+
+
+def test_pages_past_the_cursor_are_never_read():
+    # A lane reads its live pages and no other: poison (NaN) in every
+    # block that no lane's live pages name must not reach any output,
+    # though the table's dead entries point at it.
+    q, pk, pv, table, lens = _pool_case(
+        jax.random.PRNGKey(12), num_rep=1, **_RAGGED_CASE)
+    clean = paged_attention(q, pk, pv, table, lens)
+    live = np.zeros(120, bool)
+    for row, ln in zip(np.asarray(table), _RAGGED):
+        live[row[:ln // 16 + 1]] = True
+    dead = jnp.asarray(np.flatnonzero(~live))
+    table = jnp.where(
+        jnp.arange(40)[None, :] > (lens // 16)[:, None], dead[0], table)
+    out = paged_attention(
+        q, pk.at[dead].set(jnp.nan), pv.at[dead].set(jnp.nan), table, lens)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))

@@ -16,6 +16,7 @@ compiles: a deviceless entry can be written but not read back.
 """
 
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -185,6 +186,47 @@ def test_paged_attention_compiles(one_chip, layout, quantized):
     _assert_kernel(_compiled_text(fn, *args))
 
 
+# (kv_heads, num_rep, head_dim, block_size, pool dtype, q dtype): what
+# engine.read_path calls built beside the served cells' shapes, and so what
+# a default user on a TPU gets: any dtype, any block of whole sublane
+# tiles, any width of whole 128-lane rows, wide GQA.
+@pytest.mark.parametrize("case", [
+    (12, 1, 64, 16, jnp.float32, jnp.float32),
+    (12, 1, 64, 8, jnp.bfloat16, jnp.bfloat16),
+    (12, 1, 64, 128, jnp.bfloat16, jnp.bfloat16),
+    (8, 16, 128, 16, jnp.bfloat16, jnp.bfloat16),
+    (12, 1, 64, 16, jnp.bfloat16, jnp.float32),
+    (2, 1, 64, 16, jnp.bfloat16, jnp.bfloat16),
+], ids=["f32", "block8", "block128", "gqa16", "f32-q-bf16-pool", "width128"])
+def test_paged_attention_compiles_wherever_the_rule_takes_it(one_chip, case):
+    G, R, head_dim, BS, pool_dtype, q_dtype = case
+    B, NB, pages = 8, 256, 1024 // BS
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = S((NB, BS, G * head_dim), pool_dtype)
+    _assert_kernel(_compiled_text(
+        lambda q, pk, pv, table, lens: paged_attention(
+            q, pk, pv, table, lens, num_rep=R, interpret=False),
+        S((B, G * R, head_dim), q_dtype), pool, pool,
+        S((B, pages), jnp.int32), S((B,), jnp.int32),
+    ))
+
+
+def test_paged_attention_refuses_a_width_the_chip_cannot_copy():
+    # kv_heads * head_dim under 128 lanes is a padded pool: no copy on the
+    # chip slices a page out of it (Mosaic: "must be aligned to tiling").
+    # The rule sends such a model to the gather; a demand fails by name.
+    pool = jnp.zeros((8, 16, 64), jnp.bfloat16)
+    with pytest.raises(NotImplementedError, match="pool width 64"):
+        paged_attention(
+            jnp.zeros((2, 1, 64), jnp.bfloat16), pool, pool,
+            jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32),
+            interpret=False,
+        )
+
+
 def _gpt2_engine_case():
     model = models.get_model(
         "gpt2", size="124m", num_layers=2, vocab_size=512, max_len=256,
@@ -242,14 +284,27 @@ def engine_program(compile_for_chip):
     def build(case, program):
         if (case, program) not in built:
             model, params, budget_mb = case()[:3]
+            # The chip's engine, as the benchmark's files build it: it
+            # demands nothing (attn_kernel="reference") and is told the
+            # platform it is compiled for, so its rule reads what it
+            # would on the chip.
             eng = ServingEngine(model, params, ServingConfig(
                 slots=8, block_size=16, hbm_budget_mb=budget_mb,
                 max_seq_len=256, prompt_buckets=(32,),
-            ))
+                attn_kernel="reference",
+            ), platform="tpu")
             assert eng.num_blocks == 256
             eng._compile = compile_for_chip
-            exe = (eng._decode_exe_or_compile() if program == "decode"
-                   else eng._prefill_exe_for(32))
+            # The kernel asks jax.default_backend(), the CPU here: steered
+            # to Mosaic from outside for the length of this compile.
+            kernel_mod = sys.modules[paged_attention.__module__]
+            interpret = kernel_mod._default_interpret
+            kernel_mod._default_interpret = lambda: False
+            try:
+                exe = (eng._decode_exe_or_compile() if program == "decode"
+                       else eng._prefill_exe_for(32))
+            finally:
+                kernel_mod._default_interpret = interpret
             text = exe.as_text()
             assert text.startswith(f"HloModule jit__{program}_fn")
             built[case, program] = eng, text
@@ -293,8 +348,17 @@ def test_engine_programs_take_the_pool_as_it_lies(
     entry = re.findall(rf"{shape}\{{([\d,]+)", text.split("\n")[0])
     assert entry and set(entry) == {"2,1,0"}, entry
     copies = re.findall(rf"= {shape}\S* copy\(", text)
+    # What a lane's whole table gathers to: [slots, pages, block, width].
+    gathered = re.findall(rf"bf16\[8,16,16,{width}\]", text)
+    in_place = leaf_name != "pool_latent"
+    assert eng.stats()["read_path"] == ("in_place" if in_place else "gather")
     if program == "decode":
         assert not copies, copies
+        # The K/V pools' live pages are read where they lie, by the
+        # engine's own rule: the kernel's call and no gather of the pool;
+        # the latent leaf keeps the gather (no in-place latent read yet).
+        assert ("paged_decode" in _kernel_names(text)) == in_place
+        assert bool(gathered) == (not in_place), gathered
         aliased = set(re.findall(
             r"\((\d+), \{\}, (?:may|must)-alias\)",
             re.search(r"input_output_alias=\{(.*?\)) \}", text).group(1),
@@ -303,7 +367,9 @@ def test_engine_programs_take_the_pool_as_it_lies(
     else:
         # Prefill is not donated (engine._prefill_exe_for says why): the
         # one plain copy of a leaf into its output may remain, no more.
+        # It is L > 1 and keeps the gather whatever the decode path.
         assert len(copies) <= len(leaves), copies
+        assert "paged_decode" not in _kernel_names(text)
     if leaf_name == "pool_latent":
         # The grouped expert product is the compiler's own kernel: no
         # dense dispatch, and no Pallas kernel of this repo to name.
@@ -341,11 +407,14 @@ def test_window_and_global_layers_decode_on_both_pools_as_they_lie(
     eng = ServingEngine(model, params, ServingConfig(
         slots=8, block_size=16, hbm_budget_mb=19, max_seq_len=256,
         prompt_buckets=(32,),
-    ))
+    ), platform="tpu")
     assert (eng.num_blocks, eng.window_blocks, eng.window_ring) == (263, 41, 5)
     eng._compile = compile_for_chip
     text = eng._decode_exe_or_compile().as_text()
     assert text.startswith("HloModule jit__decode_fn")
+    # Window layers: the rule takes the gather for the whole model.
+    assert eng.stats()["read_path"] == "gather"
+    assert "paged_decode" not in _kernel_names(text)
     pool_params = {}
     for name, rows in (("pool_(?:key|value)_window", 41),
                        ("pool_(?:key|value)", 263)):
